@@ -13,14 +13,11 @@ from . import observability
 from .nn.compile_cache import (persistent_cache_status,
                                wire_persistent_cache)
 
-# opt-in persistent XLA compile cache: DL4J_TPU_COMPILE_CACHE=<dir> makes
-# process restarts reload compiled executables from disk instead of
-# recompiling (no env var -> no-op).  Best-effort: a jax version without
-# the cache flags must not break package import.
-try:
-    wire_persistent_cache()
-except Exception:  # noqa: BLE001 - import must survive any cache failure
-    pass
+# persistent XLA compile cache: process restarts reload compiled
+# executables from disk instead of recompiling.  The directory is
+# $JAX_COMPILATION_CACHE_DIR where set, else one fixed git-ignored path in
+# the checkout (nn/compile_cache.DEFAULT_CACHE_DIR).
+wire_persistent_cache()
 
 from .nn.conf.input_type import InputType
 from .nn.conf.multi_layer import (MultiLayerConfiguration,

@@ -21,10 +21,11 @@ Three mechanisms make that the framework default:
    plus an ``xla.compile`` tracer span, so recompile storms show up in
    /metrics instead of as mystery latency.
 
-3. **Persistent compile cache** (`wire_persistent_cache`): opt-in
-   ``DL4J_TPU_COMPILE_CACHE=<dir>`` wires JAX's on-disk compilation cache at
-   package init, so a restarted process reloads executables instead of
-   recompiling the world.
+3. **Persistent compile cache** (`wire_persistent_cache`): JAX's on-disk
+   compilation cache, wired at package init, so a restarted process
+   reloads executables instead of recompiling the world.  The directory is
+   ``$JAX_COMPILATION_CACHE_DIR`` where that is set (then no code sets
+   one), else one fixed git-ignored path inside the checkout.
 """
 from __future__ import annotations
 
@@ -44,6 +45,7 @@ from ..observability.tracer import get_tracer
 
 __all__ = ["topology_signature", "shared_jit", "InstrumentedJit",
            "wire_persistent_cache", "persistent_cache_status",
+           "DEFAULT_CACHE_DIR",
            "trace_cache_size", "clear_trace_cache",
            "iter_trace_cache", "set_audit_capture", "audit_capture_mode"]
 
@@ -451,7 +453,20 @@ def clear_trace_cache() -> None:
 
 
 # -------------------------------------------------------- persistent cache
+#: Where the on-disk compile cache lives when ``JAX_COMPILATION_CACHE_DIR``
+#: does not place it from outside: ONE fixed, git-ignored path inside the
+#: checkout, next to the native build.  Never a temp name, a pid or a
+#: timestamp — the path is part of the cache key, so a directory that
+#: moves never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "_compile_cache")
+
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
 _PERSISTENT_STATUS: Dict[str, Any] = {"enabled": False}
+_PERSISTENT_COUNTS = {"hits": 0, "misses": 0}
+_PERSISTENT_LISTENING = False
 _PERSISTENT_LOCK = threading.Lock()
 
 
@@ -462,50 +477,66 @@ def _cache_entries(path: str) -> int:
         return 0
 
 
-def wire_persistent_cache(path: Optional[str] = None) -> Dict[str, Any]:
-    """Wire JAX's persistent (on-disk) compilation cache.
-
-    ``path`` defaults to ``$DL4J_TPU_COMPILE_CACHE``; with neither set this
-    is a no-op returning ``{"enabled": False}``.  Thresholds are lowered so
-    every entry persists (the min-compile-time default would skip the small
-    programs CPU tests produce).  Each config flag is applied best-effort —
-    older jax versions missing a flag degrade gracefully rather than
-    breaking package import.  Returns a status dict including how many
-    cache entries a previous process left behind (``existing_entries`` > 0
-    on a warm restart means the first compile of each program is a disk
-    load, not an XLA compile)."""
-    global _PERSISTENT_STATUS
-    if path is None:
-        path = os.environ.get("DL4J_TPU_COMPILE_CACHE", "")
-    if not path:
+def _on_cache_event(event: str, **_kw) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
         with _PERSISTENT_LOCK:
-            _PERSISTENT_STATUS = {"enabled": False}
-            return dict(_PERSISTENT_STATUS)
-    os.makedirs(path, exist_ok=True)
-    existing = _cache_entries(path)
-    applied = []
-    for flag, value in (
-            ("jax_compilation_cache_dir", path),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0)):
+            _PERSISTENT_COUNTS[key] += 1
+
+
+def wire_persistent_cache() -> Dict[str, Any]:
+    """Wire JAX's persistent (on-disk) compilation cache; runs at package
+    import, so a restarted process reloads executables instead of
+    recompiling the world.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the directory is placed
+    from outside: JAX reads the variable itself and this code sets no
+    directory at all.  Where it is not, the cache goes to
+    ``DEFAULT_CACHE_DIR``.  Thresholds are lowered so every entry persists
+    (the min-compile-time default would skip small programs).  A checkout
+    that cannot be written (read-only install) leaves the cache off and
+    says so in the returned status; nothing else is caught.  Returns the
+    status dict, including how many entries a previous process left behind
+    (``existing_entries``)."""
+    global _PERSISTENT_STATUS, _PERSISTENT_LISTENING
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    placed_by = "JAX_COMPILATION_CACHE_DIR" if path else "checkout"
+    if not path:
+        path = DEFAULT_CACHE_DIR
         try:
-            jax.config.update(flag, value)
-            applied.append(flag)
-        except (AttributeError, ValueError, TypeError):
-            continue
-    status = {"enabled": "jax_compilation_cache_dir" in applied,
-              "dir": path, "existing_entries": existing,
-              "applied": applied}
+            os.makedirs(path, exist_ok=True)
+        except OSError as e:
+            with _PERSISTENT_LOCK:
+                _PERSISTENT_STATUS = {"enabled": False, "dir": path,
+                                      "error": f"{type(e).__name__}: {e}"}
+                return dict(_PERSISTENT_STATUS)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    existing = _cache_entries(path)
     reg = default_registry()
     if reg.enabled:
         reg.gauge("training_persistent_cache_entries",
                   "Entries found in the persistent XLA compile cache dir "
                   "at wiring time").set(existing)
     with _PERSISTENT_LOCK:
-        _PERSISTENT_STATUS = status
-        return dict(status)
+        if not _PERSISTENT_LISTENING:
+            jax.monitoring.register_event_listener(_on_cache_event)
+            _PERSISTENT_LISTENING = True
+        _PERSISTENT_STATUS = {"enabled": True, "dir": path,
+                              "placed_by": placed_by,
+                              "existing_entries": existing}
+        return dict(_PERSISTENT_STATUS)
 
 
 def persistent_cache_status() -> Dict[str, Any]:
+    """The wiring status plus what has happened since: ``entries`` now in
+    the directory, and the ``hits``/``misses`` JAX has reported for this
+    process's compiles (a hit is an executable loaded from disk instead
+    of compiled)."""
     with _PERSISTENT_LOCK:
-        return dict(_PERSISTENT_STATUS)
+        status = dict(_PERSISTENT_STATUS, **_PERSISTENT_COUNTS)
+    if status.get("enabled"):
+        status["enabled"] = bool(jax.config.jax_enable_compilation_cache)
+        status["entries"] = _cache_entries(status["dir"])
+    return status

@@ -11,9 +11,10 @@ Attention impl tiers (select with ``attn_impl``):
   'flash'     — pallas tiled kernel (``ops.flash_attention``), O(t) memory.
   'ring'      — ring attention over the mesh 'seq' axis (inside shard_map).
   'ulysses'   — all-to-all sequence parallelism (inside shard_map).
-  'auto'      — selects by the measured crossover: reference below
-                ``DEFAULT_FLASH_MIN_SEQ`` tokens (or a masked input),
-                flash at/above it — the ``CudnnAlgoMode`` role.
+  'auto'      — ``auto_attention_impl``: flash on a TPU for unmasked
+                sequences of at least ``DEFAULT_FLASH_MIN_SEQ`` tokens the
+                kernel can tile, reference otherwise — the
+                ``CudnnAlgoMode`` role.
 """
 from __future__ import annotations
 
@@ -62,29 +63,44 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
 
 _ATTN_IMPLS = ("auto", "reference", "flash", "ring", "ulysses")
 
-# Measured crossover on the TPU v5e chip (BENCH_NOTES.md "transformer
-# campaign"): with the r3 128x128 kernel blocks, reference SDPA won the
-# full train step up to s=2048; with the swept block sizes
-# (ops/flash_attention._auto_blocks) flash wins at EVERY kernel-supported
-# length — full-model step ms flash/ref: 37/42 @s=128, 36/46 @s=512,
-# 64/75 @s=2048, 84/2642 @s=8192.  The default therefore sits at the
-# kernel's minimum tile (128); the env/field override remains for chips
-# where the crossover differs.  Role mirror: the reference's shape-based
-# algorithm selection (``ConvolutionLayer.java:349`` CudnnAlgoMode) —
-# "auto" selects the measured-faster algorithm by shape.
+# 'auto' crossover: flash from the kernel's minimum tile (128) upward.
+# The full-step comparison behind it (flash ahead of reference SDPA at
+# every kernel-supported length once the block sizes were swept, see
+# ops/flash_attention._auto_blocks) predates today's code and compiler;
+# on today's stack the crossover is not measured.  The env/field override
+# remains for chips where it differs.  Role mirror: the reference's
+# shape-based algorithm selection (``ConvolutionLayer.java:349``
+# CudnnAlgoMode).
 DEFAULT_FLASH_MIN_SEQ = int(os.environ.get("DL4J_TPU_FLASH_MIN_SEQ", 128))
 
 
+def auto_attention_impl(t_q: int, t_k: int, d: int, *, masked: bool,
+                        flash_min_seq: Optional[int] = None) -> str:
+    """What ``attn_impl='auto'`` runs for these shapes: ``'flash'`` when
+    the backend is a TPU, the input is unmasked, the sequence is at or
+    above the crossover (``flash_min_seq``, default
+    ``DEFAULT_FLASH_MIN_SEQ``) and the kernel can tile it; else
+    ``'reference'``.  The one place the choice is made, so a caller can
+    ask what was chosen instead of guessing."""
+    threshold = (DEFAULT_FLASH_MIN_SEQ if flash_min_seq is None
+                 else flash_min_seq)
+    if masked or t_q < threshold or jax.default_backend() != "tpu":
+        return "reference"
+    from ...ops.flash_attention import flash_blocks
+    try:
+        flash_blocks(t_q, t_k, d)
+    except ValueError:
+        return "reference"
+    return "flash"
+
+
 def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
-                   interpret: bool = False,
                    flash_min_seq: Optional[int] = None):
     """Dispatch [b,h,t,d] q/k/v to the selected attention implementation.
 
-    ``impl='auto'`` picks by the measured crossover: reference SDPA for
-    sequences shorter than ``flash_min_seq`` (default
-    ``DEFAULT_FLASH_MIN_SEQ``, env ``DL4J_TPU_FLASH_MIN_SEQ``), flash at or
-    above it.  Masked inputs always take the reference path (the kernel has
-    no key-padding support)."""
+    ``impl='auto'`` resolves through :func:`auto_attention_impl`.  An
+    explicit ``impl='flash'`` never falls back: a mask, shapes the kernel
+    cannot tile, or a backend that cannot run it all raise."""
     from ...ops.attention import sdpa_reference
     if impl not in _ATTN_IMPLS:
         raise ValueError(f"unknown attn_impl '{impl}'; expected one of "
@@ -96,19 +112,16 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
                              "key-padding masks (pad to shard boundary)")
         fn = ring_self_attention if impl == "ring" else ulysses_attention
         return fn(q, k, v, axis_name=seq_axis, causal=causal)
+    if impl == "auto":
+        impl = auto_attention_impl(q.shape[2], k.shape[2], q.shape[3],
+                                   masked=mask is not None,
+                                   flash_min_seq=flash_min_seq)
     if impl == "flash":
         if mask is not None:
             raise ValueError("attn_impl='flash' does not take key-padding "
                              "masks; use 'reference'/'auto' or pre-mask inputs")
         from ...ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, interpret=interpret)
-    if impl == "auto" and mask is None:
-        threshold = (DEFAULT_FLASH_MIN_SEQ if flash_min_seq is None
-                     else flash_min_seq)
-        if q.shape[2] >= threshold:
-            from ...ops.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=causal,
-                                   interpret=interpret)
+        return flash_attention(q, k, v, causal=causal)
     return sdpa_reference(q, k, v, mask=mask, causal=causal)
 
 

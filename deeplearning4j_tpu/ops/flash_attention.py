@@ -3,8 +3,9 @@
 Role-parity with the reference's cuDNN helpers (``deeplearning4j-cuda/.../
 CudnnConvolutionHelper.java:54`` pattern: optional per-layer fast path,
 numerics-validated against the builtin fallback, cf. ``ValidateCudnnLSTM``).
-Here the fallback is ``ops.attention.sdpa_reference`` and the fast path is a
-tiled online-softmax kernel: O(t) memory instead of the O(t^2) score matrix,
+Here the builtin is ``ops.attention.sdpa_reference`` (chosen by the caller,
+never fallen back to from here) and the fast path is a tiled online-softmax
+kernel: O(t) memory instead of the O(t^2) score matrix,
 with [block_q × d] @ [d × block_k] matmuls shaped for the MXU and softmax
 statistics kept in VMEM scratch across the key-block grid dimension.
 
@@ -22,25 +23,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType, PartitionSpec as P
 
-from .attention import NEG_INF, sdpa_reference
+from .attention import NEG_INF
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-# Chip-swept caps (BENCH_NOTES "transformer campaign", TPU v5e, d=64):
-# 128x128 ran the s=8192 fwd+bwd in 35.4 ms; 2048x512 in 13.3 ms (2.7x) —
-# bigger q-blocks amortize DMA and feed the MXU [block_q,d]@[d,block_k]
-# matmuls at useful sizes.  Caps scale down with head_dim to stay inside
-# VMEM (2048x1024 at d=64 already fails to compile).
+# Block caps from a sweep on a TPU v5e at d=64 that predates today's code
+# and compiler (bigger q-blocks amortize DMA and feed the MXU
+# [block_q,d]@[d,block_k] matmuls at useful sizes; 2048x1024 at d=64 did
+# not compile then).  Caps scale down with head_dim to stay inside VMEM.
+# tests/test_chip_compile.py compiles the largest auto blocks (t=8192)
+# for the v5e on every run; the speed of these blocks on today's stack is
+# not measured.
 _BLOCK_Q_CAP = 2048 * 64
 _BLOCK_K_CAP = 512 * 64
 
 
 def _auto_blocks(t_q: int, t_k: int, d: int):
     """Largest power-of-two divisors of the sequence lengths under the
-    VMEM-scaled caps — the measured-fastest tiling, the cuDNN algo-search
-    role (``ConvolutionLayer.java:349``) resolved by sweep instead of
-    per-call search."""
+    VMEM-scaled caps — the cuDNN algo-search role
+    (``ConvolutionLayer.java:349``) resolved by sweep instead of per-call
+    search."""
     def pick(t, cap):
         if t <= 128:
             return t          # sub-tile sequences run as one block
@@ -116,13 +120,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             m_ref[:] + jnp.log(l))[:, 0]
 
 
-def _flash_fwd_call(qr, kr, vr, scale, causal, block_q, block_k, interpret):
+def _like(x, shape=None, dtype=None):
+    """Output type for a kernel launched on ``x``: under ``shard_map`` it
+    varies over the same mesh axes as the operand."""
+    return jax.ShapeDtypeStruct(x.shape if shape is None else shape,
+                                dtype or x.dtype, vma=jax.typeof(x).vma)
+
+
+def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
     grid = (bh, t_q // block_q, t_k // block_k)
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k)
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -134,17 +145,17 @@ def _flash_fwd_call(qr, kr, vr, scale, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, 1, t_q), lambda bh, qi, ki: (bh, 0, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), qr.dtype),
-            jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32),
-        ],
+        out_shape=[_like(qr, (bh, t_q, d)),
+                   _like(qr, (bh, 1, t_q), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
+    return out, lse
 
 
 def _replay_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, qi, ki, *,
@@ -223,25 +234,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(qr, kr, vr, scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_fwd_call(qr, kr, vr, scale, causal, block_q, block_k,
+    out, _ = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
                              interpret)
     return out
 
 
 def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
-    out, lse = _flash_fwd_call(qr, kr, vr, scale, causal, block_q, block_k,
+    out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
                                interpret)
     return out, (qr, kr, vr, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
-    qr, kr, vr, out, lse = res
+def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
+                       block_k, interpret):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
-    # D = rowsum(dO ∘ O): one elementwise+reduce pass, XLA-fused
-    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                 axis=-1)[:, None, :]               # (bh, 1, t_q) row form
-
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
     row_spec = pl.BlockSpec((1, 1, t_q), lambda bh, qi, ki: (bh, 0, 0))
@@ -251,9 +258,10 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
         grid=(bh, t_q // block_q, t_k // block_k),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        out_shape=_like(qr),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, do, lse, dd)
 
     # swapped grid: k outer, q inner (sequential) so dk/dv carry in scratch
@@ -266,16 +274,71 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
         grid=(bh, t_k // block_k, t_q // block_q),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[k_spec2, k_spec2],
-        out_shape=[jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                   jax.ShapeDtypeStruct(vr.shape, vr.dtype)],
+        out_shape=[_like(kr), _like(vr)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, do, lse, dd)
     return dq, dk, dv
 
 
+def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
+    qr, kr, vr, out, lse = res
+    # D = rowsum(dO ∘ O): one elementwise+reduce pass, XLA-fused
+    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                 axis=-1)[:, None, :]               # (bh, 1, t_q) row form
+    return _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
+                       block_k, interpret)
+
+
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+#: kernel names as they appear in the lowered program's ``tpu_custom_call``
+#: ops — what a caller greps ``as_text()`` for to prove the kernels are in
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def flash_blocks(t_q: int, t_k: int, d: int,
+                 block_q: Optional[int] = None,
+                 block_k: Optional[int] = None):
+    """``(block_q, block_k)`` the kernel runs these shapes with.  Raises
+    ``ValueError`` naming the reason when it cannot tile them — the one
+    support check, shared by ``flash_attention`` (which raises) and
+    ``attn_impl='auto'`` (which then chooses the reference path)."""
+    auto_q, auto_k = _auto_blocks(t_q, t_k, d)
+    block_q = min(block_q, t_q) if block_q else auto_q
+    block_k = min(block_k, t_k) if block_k else auto_k
+    if d % 64:
+        # head_dim must fill whole MXU lanes for the kernel's tiling
+        raise ValueError(f"flash attention needs head_dim % 64 == 0, "
+                         f"got {d}")
+    if t_q % block_q or t_k % block_k:
+        raise ValueError(
+            f"flash attention needs sequence lengths divisible by its "
+            f"blocks: t_q={t_q} % {block_q}, t_k={t_k} % {block_k}")
+    return block_q, block_k
+
+
+def _kernel_partitioning(q):
+    """``(mesh, spec)`` to run the kernels under ``shard_map`` with, or
+    ``(None, None)`` on one device.  Mosaic kernels cannot be partitioned
+    automatically: a jit over arguments sharded on a mesh
+    (``ShardedTrainer``, ``ParallelWrapper``) refuses to lower them bare.
+    The mesh is read off the operand's type — an array placed with a
+    ``NamedSharding`` carries its abstract mesh into the trace — so no
+    caller has to pass or set one.  The batch rides the mesh's ``data``
+    axis (where ``parallel/mesh.batch_spec`` puts it) when it divides;
+    every other axis, and a batch that does not divide, runs replicated."""
+    mesh = jax.typeof(q).sharding.mesh
+    auto = {name: size for name, size, kind
+            in zip(mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
+            if kind == AxisType.Auto and size > 1}
+    if not auto:
+        return None, None
+    data = auto.get("data")
+    return mesh, (P("data") if data and q.shape[0] % data == 0 else P())
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
@@ -287,27 +350,27 @@ def flash_attention(q, k, v, *, causal: bool = False,
     FlashAttention-2 style backward (saved logsumexp, softmax replayed per
     block, separate dq and dk/dv kernels) keeps training memory O(t).
 
-    Falls back to ``sdpa_reference`` when shapes don't tile (t or d too small
-    or not block-divisible) — same "checkSupported else fallback" contract as
-    ``CudnnLSTMHelper.checkSupported`` (``CudnnLSTMHelper.java:174-183``).
-    Key-padding masks are not supported here; masked batches use the fallback.
+    Never falls back: shapes the kernel cannot tile raise ``ValueError``
+    (``flash_blocks``), and off a TPU backend the Pallas lowering itself
+    refuses unless ``interpret=True``.  Key-padding masks are not
+    supported here.  ``attn_impl='auto'`` is the caller that chooses
+    between this and ``sdpa_reference``.
     """
-    b, h, t_q, d = q.shape
+    _, h, t_q, d = q.shape
     t_k = k.shape[2]
-    auto_q, auto_k = _auto_blocks(t_q, t_k, d)
-    block_q = min(block_q, t_q) if block_q else auto_q
-    block_k = min(block_k, t_k) if block_k else auto_k
-    supported = (t_q % block_q == 0 and t_k % block_k == 0
-                 # head_dim must fill whole MXU lanes for the kernel's tiling
-                 and d % 64 == 0
-                 and (interpret or jax.default_backend() == "tpu"))
-    if not supported:
-        return sdpa_reference(q, k, v, causal=causal, scale=scale)
+    block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k)
     if scale is None:
         scale = d ** -0.5
 
-    qr = q.reshape(b * h, t_q, d)
-    kr = k.reshape(b * h, t_k, d)
-    vr = v.reshape(b * h, t_k, d)
-    out = _flash(qr, kr, vr, scale, causal, block_q, block_k, interpret)
-    return out.reshape(b, h, t_q, d)
+    def run(q, k, v):
+        rows = q.shape[0] * h
+        out = _flash(q.reshape(rows, t_q, d), k.reshape(rows, t_k, d),
+                     v.reshape(rows, t_k, d), scale, causal, block_q,
+                     block_k, interpret)
+        return out.reshape(q.shape)
+
+    mesh, spec = _kernel_partitioning(q)
+    if mesh is not None:
+        run = jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec)
+    return run(q, k, v)

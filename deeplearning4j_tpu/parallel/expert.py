@@ -1,7 +1,7 @@
 """Expert parallelism: mixture-of-experts FFN with all-to-all dispatch.
 
 No reference equivalent (pre-transformer era) — this completes the
-TPU-first parallelism taxonomy (dp/tp/pp/sp/ep) alongside ``pipeline.py``
+TPU-first parallelism classes (dp/tp/pp/sp/ep) alongside ``pipeline.py``
 and ``sequence.py``.  Design follows the GShard/Switch dense-dispatch
 formulation: top-1 routing, fixed expert capacity (static shapes for XLA),
 dispatch/combine as einsums on the MXU, and two tiled ``lax.all_to_all``

@@ -19,46 +19,11 @@ runs inside ``shard_map`` where ``stacked_params`` has specs
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-# varying-manual-axes machinery (jax >= 0.6): shard_map values carry a vma
-# type and replication changes go through pcast; absent both, every
-# shard_map value is untyped-varying and the compat paths below apply
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _broadcast_from_last(axis_name, x):
-    """Replicate the last pipe stage's value to every device, counting its
-    cotangent ONCE (owner-only) on the backward pass.  Plain ``psum`` is
-    correct forward, but on jax versions without varying-manual-axes typing
-    its shard_map transpose psums the (replicated, identical) downstream
-    cotangents — inflating stage grads by the pipe-axis size when the loss
-    is computed redundantly on every device, the normal replicated-loss
-    pattern."""
-    n = lax.psum(1, axis_name)
-    idx = lax.axis_index(axis_name)
-    return lax.psum(jnp.where(idx == n - 1, x, jnp.zeros_like(x)), axis_name)
-
-
-def _broadcast_from_last_fwd(axis_name, x):
-    return _broadcast_from_last(axis_name, x), None
-
-
-def _broadcast_from_last_bwd(axis_name, _, ct):
-    n = lax.psum(1, axis_name)
-    idx = lax.axis_index(axis_name)
-    return (jnp.where(idx == n - 1, ct, jnp.zeros_like(ct)),)
-
-
-_broadcast_from_last.defvjp(_broadcast_from_last_fwd,
-                            _broadcast_from_last_bwd)
-
 
 def gpipe(stage_fn: Callable, stage_params, xs, *, axis_name: str = "pipe"):
     """Run microbatches [n_micro, mb, ...] through the stage pipeline.
@@ -78,19 +43,14 @@ def gpipe(stage_fn: Callable, stage_params, xs, *, axis_name: str = "pipe"):
 
     # The loop carry must be typed as device-varying over every mesh axis the
     # stage computation touches (e.g. 'seq' when the stage runs ring
-    # attention), not just 'pipe' — collect them from the inputs.  Without
-    # the vma machinery, vary() is the identity.
-    if _HAS_VMA:
-        vma = {axis_name} | set(jax.typeof(xs).vma)
-        for leaf in jax.tree.leaves(local):
-            vma |= set(jax.typeof(leaf).vma)
+    # attention), not just 'pipe' — collect them from the inputs.
+    vma = {axis_name} | set(jax.typeof(xs).vma)
+    for leaf in jax.tree.leaves(local):
+        vma |= set(jax.typeof(leaf).vma)
 
-        def vary(a):
-            missing = tuple(vma - set(jax.typeof(a).vma))
-            return lax.pcast(a, missing, to="varying") if missing else a
-    else:
-        def vary(a):
-            return a
+    def vary(a):
+        missing = tuple(vma - set(jax.typeof(a).vma))
+        return lax.pcast(a, missing, to="varying") if missing else a
 
     # Probe the stage output shape (stages are shape-uniform by contract).
     out_shape = jax.eval_shape(stage_fn, local, xs[0])
@@ -123,12 +83,8 @@ def gpipe(stage_fn: Callable, stage_params, xs, *, axis_name: str = "pipe"):
     _, outs = lax.fori_loop(0, total, tick, (buf, outs))
     # Broadcast stage-N results to every pipe device (callers typically take
     # the loss psum over 'data' afterwards; replicating keeps specs simple).
-    if _HAS_VMA:
-        outs = lax.psum(jnp.where(idx == n - 1, outs, jnp.zeros_like(outs)),
-                        axis_name)
-    else:
-        outs = _broadcast_from_last(axis_name, outs)
-    return outs
+    return lax.psum(jnp.where(idx == n - 1, outs, jnp.zeros_like(outs)),
+                    axis_name)
 
 
 def stack_stage_params(param_list):

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pipeline import _HAS_VMA
 from ..ops.attention import (attn_block, combine_blocks, finalize_blocks,
                              init_blocks)
 
@@ -44,15 +43,10 @@ def ring_self_attention(q, k, v, *, axis_name: str, causal: bool = False,
     idx = lax.axis_index(axis_name)
     b, h, t_blk, d = q.shape
     # Initial partials must be marked as device-varying over the seq axis for
-    # shard_map's carry typing (they combine with axis-varying blocks).  On
-    # jax versions without the varying-manual-axes machinery (pcast,
-    # jax >= 0.6) shard_map values are untyped-varying already.
-    if _HAS_VMA:
-        acc, m, l = jax.tree.map(
-            lambda a: lax.pcast(a, (axis_name,), to="varying"),
-            init_blocks(b, h, t_blk, d, q.dtype))
-    else:
-        acc, m, l = init_blocks(b, h, t_blk, d, q.dtype)
+    # shard_map's carry typing (they combine with axis-varying blocks).
+    acc, m, l = jax.tree.map(
+        lambda a: lax.pcast(a, (axis_name,), to="varying"),
+        init_blocks(b, h, t_blk, d, q.dtype))
     q_off = idx * t_blk
     perm = [(j, (j + 1) % n) for j in range(n)]
 

@@ -71,24 +71,16 @@ trace, and checkpoints reshard through the same
 is just a big leaf; dp=4 → dp=2 restores digest-exact, pinned in
 tests/test_sparse_embedding.py).
 
-Gather/compute overlap (the second half of the arXiv:2004.13336 win):
-because the forward all-gather of each layer's shard is emitted at its
-USE SITE — the step folds over layers consuming ``params[name]`` one
-at a time, so GSPMD materializes layer k+1's gather as a separate
-collective from layer k's matmul rather than one up-front blob — XLA's
-latency-hiding scheduler may legally start layer k+1's all-gather
-while layer k computes.  On TPU that overlap is armed by
-:func:`enable_gather_compute_overlap` (async all-gather thunks + the
-latency-hiding scheduler; a no-op on rigs without a TPU runtime, where
-the flags don't exist), which :class:`ShardedTrainer` applies
-best-effort at construction.  Two invariants make this a pure
-scheduling change: the collective CENSUS is untouched (the dp=2/dp=4
-golden pins in tests/test_audit.py hold exactly — same ops, same
-bytes, different start times), so the proof instrument is stepprof's
-per-step ``device`` slice medians, not census drift; and the bounded
-dispatch window the inherited fit loop runs (``nn/dispatch``) keeps
-the HOST a step ahead, so the dispatch of step N+1 overlaps step N's
-gather+compute chain end-to-end.
+Gather/compute overlap: the forward all-gather of each layer's shard is
+emitted at its USE SITE — the step folds over layers consuming
+``params[name]`` one at a time, so GSPMD materializes layer k+1's gather
+as a separate collective from layer k's matmul rather than one up-front
+blob — which leaves XLA's scheduler free to start layer k+1's all-gather
+while layer k computes.  Whether it does is the TPU compiler's default
+behaviour: nothing here sets compiler flags (an earlier constructor
+appended ``--xla_tpu_*`` flags to ``XLA_FLAGS``, which this jaxlib's flag
+parser rejects with a fatal error).  Exposed all-gather time on four
+chips: not measured.
 
 The derived collective layout is GUARDED at the IR level: graftaudit
 (``tools/graftaudit``, rule AX003) compiles the canonical dp=2/dp=4
@@ -103,7 +95,6 @@ collective carrying O(vocab·dim) bytes.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -114,78 +105,7 @@ from .mesh import DEFAULT_MIN_SHARD_SIZE, place_sharded, shard_params
 from .wrapper import ParallelWrapper
 
 __all__ = ["ShardedTrainer", "per_device_param_bytes", "param_bytes",
-           "enable_gather_compute_overlap", "OVERLAP_XLA_FLAGS",
            "DEFAULT_MIN_SHARD_SIZE"]
-
-#: TPU compiler flags that turn the use-site forward all-gathers into
-#: async thunks and let the latency-hiding scheduler start layer k+1's
-#: gather while layer k computes.  Scheduling-only: the collective
-#: census (ops, bytes, golden dp=2/dp=4 pins) is identical with or
-#: without them.
-OVERLAP_XLA_FLAGS = (
-    "--xla_tpu_enable_async_all_gather=true",
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
-
-
-def _tpu_platform_selected() -> bool:
-    """True unless this process has PINNED its jax platform set to one
-    that excludes TPU (``JAX_PLATFORMS=cpu`` and friends) — in that
-    case the TPU client will never be built here, and any TPU-only
-    ``XLA_FLAGS`` we write would outlive us in ``os.environ``, get
-    inherited by child processes, and fatally abort their CPU-only
-    XLA flag parse."""
-    sel = None
-    try:
-        sel = jax.config.jax_platforms  # mirrors JAX_PLATFORMS
-    except Exception:
-        pass
-    if not sel:
-        sel = (os.environ.get("JAX_PLATFORMS")
-               or os.environ.get("JAX_PLATFORM_NAME"))
-    if not sel:
-        return True  # unpinned: TPU may still be selected at init
-    return "tpu" in [p.strip() for p in sel.lower().split(",")]
-
-
-def enable_gather_compute_overlap() -> bool:
-    """Arm the TPU gather/compute-overlap flags (``OVERLAP_XLA_FLAGS``)
-    by appending them to ``XLA_FLAGS``.  Returns True when the flags
-    were applied (or already present) in time to matter.
-
-    No-op (False) when no TPU runtime is installed OR the process has
-    pinned a non-TPU platform (``JAX_PLATFORMS=cpu``) — these are
-    TPU-runtime flag definitions, and XLA aborts on unknown
-    ``XLA_FLAGS`` entries, so they must never leak onto a CPU-only rig
-    (nor into its CHILD processes, which inherit the mutated environ;
-    a libtpu wheel can be installed on a box that still runs CPU-only)
-    — or when the TPU backend already initialized (XLA snapshots the
-    flags at backend init; late edits are silently dead, so report the
-    truth rather than pretend).
-    """
-    if not _tpu_platform_selected():
-        return False
-    try:
-        import importlib.util
-        if importlib.util.find_spec("libtpu") is None:
-            return False
-    except Exception:
-        return False
-    flags = os.environ.get("XLA_FLAGS", "")
-    missing = [f for f in OVERLAP_XLA_FLAGS if f.split("=")[0] not in flags]
-    if not missing:
-        return True
-    try:
-        # jax's backend table is lazy per-platform: flags still land if
-        # the TPU client hasn't been built yet, even when CPU is up
-        from jax._src import xla_bridge
-        if "tpu" in getattr(xla_bridge, "_backends", {}):
-            return False
-    except Exception:
-        pass
-    os.environ["XLA_FLAGS"] = (flags + " " + " ".join(missing)).strip()
-    return True
-
 
 def param_bytes(params) -> int:
     """Global (unsharded) parameter bytes of a pytree."""
@@ -221,19 +141,11 @@ class ShardedTrainer(ParallelWrapper):
 
     ``min_shard_size``: leaves with fewer elements replicate (the
     collective latency would exceed the memory saved).
-
-    ``gather_compute_overlap``: arm the TPU async-all-gather +
-    latency-hiding-scheduler flags (module docstring) so the forward
-    gathers overlap layer compute; ``overlap_armed`` records whether
-    the flags actually landed (always False on a CPU rig).
     """
 
     def __init__(self, model, mesh: Optional[Mesh] = None, *,
-                 min_shard_size: int = DEFAULT_MIN_SHARD_SIZE,
-                 gather_compute_overlap: bool = True):
+                 min_shard_size: int = DEFAULT_MIN_SHARD_SIZE):
         self.min_shard_size = int(min_shard_size)
-        self.overlap_armed = (enable_gather_compute_overlap()
-                              if gather_compute_overlap else False)
         super().__init__(model, mesh)
 
     # ------------------------------------------------------------------

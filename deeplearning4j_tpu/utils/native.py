@@ -10,6 +10,7 @@ plays for the reference.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +19,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-__all__ = ["available", "threshold_encode_native", "threshold_decode_native",
+__all__ = ["available", "library_path", "threshold_encode_native", "threshold_decode_native",
            "bitmap_encode_native", "bitmap_decode_native", "decode_cifar",
            "u8_to_f32", "parse_csv", "index_corpus"]
 
@@ -33,7 +34,20 @@ _SRC = Path(__file__).resolve().parents[1] / "native_src.cpp"
 _BUILD_DIR = Path(
     os.environ.get("DL4J_TPU_NATIVE_BUILD_DIR",
                    str(_SRC.parent / "_native_build")))
-_SO = _BUILD_DIR / "libdl4j_tpu_native.so"
+
+
+def _so_path() -> Optional[Path]:
+    """The library's path, named by a digest of ``native_src.cpp`` as it
+    is on disk: a library built from any other source has another name,
+    so whatever is loaded was built from the source beside it (a stale
+    build left in the git-ignored directory is never trusted).  None when
+    the source is absent (stripped install)."""
+    try:
+        digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    except OSError:
+        return None
+    return _BUILD_DIR / f"libdl4j_tpu_native-{digest}.so"
+
 
 _i8 = np.ctypeslib.ndpointer(np.int8, flags="C_CONTIGUOUS")
 _u8 = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
@@ -47,20 +61,20 @@ def _compile() -> Optional[Path]:
     # processes must never dlopen a half-written .so.  ANY filesystem issue
     # (source tree absent in a stripped install, read-only dir, no g++) must
     # fall back to pure Python, never crash the caller.
+    so = _so_path()
+    if so is None:
+        return None
     tmp = None
     try:
-        if _SO.exists() and (not _SRC.exists()
-                             or _SO.stat().st_mtime >= _SRC.stat().st_mtime):
-            return _SO
-        if not _SRC.exists():
-            return None
+        if so.exists():
+            return so
         _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-        tmp = _SO.with_suffix(f".tmp{os.getpid()}.so")
+        tmp = so.with_suffix(f".tmp{os.getpid()}.so")
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
                "-o", str(tmp), str(_SRC)]
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError):
         return None
     finally:
@@ -124,6 +138,13 @@ def _bind(lib: ctypes.CDLL) -> None:
 def available() -> bool:
     """True when the compiled native library is loadable."""
     return _load() is not None
+
+
+def library_path() -> Optional[str]:
+    """Path of the native library in use (built from ``native_src.cpp`` on
+    first use), or None when the numpy fallbacks are live."""
+    lib = _load()
+    return None if lib is None else lib._name
 
 
 # ---------------------------------------------------------------- wrappers

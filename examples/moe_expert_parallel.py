@@ -1,5 +1,5 @@
 """Expert-parallel MoE training over a (data x expert) mesh — beyond the
-reference's parallelism taxonomy (SURVEY §2.4 table).
+reference's parallelism classes (SURVEY §2.4 table).
 
 Run on 8 virtual devices:
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -9,10 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu import (InputType, MultiLayerNetwork,
                                 NeuralNetConfiguration)
@@ -79,9 +76,7 @@ def test_flash_attention_matches_reference(causal):
 def test_flash_attention_gradients_match_reference(causal):
     """The FlashAttention-2 style backward (saved logsumexp, per-block
     softmax replay, separate dq and dk/dv kernels) must produce the
-    reference VJP — the contract that makes attn_impl='flash' trainable.
-    Measured on chip: 10x faster training step than reference at seq 8192
-    (BENCH_NOTES round 3)."""
+    reference VJP — the contract that makes attn_impl='flash' trainable."""
     import jax
     q, k, v = _qkv(b=2, h=2, t=256, d=64, seed=5)
     do = jnp.asarray(
@@ -102,11 +97,49 @@ def test_flash_attention_gradients_match_reference(causal):
                                    err_msg=f"d{name}")
 
 
-def test_flash_attention_fallback_on_odd_shapes():
-    q, k, v = _qkv(t=7, d=5)
-    out = flash_attention(q, k, v)  # 7 not divisible -> reference path
-    ref = sdpa_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+@pytest.mark.parametrize("t,d,why", [(7, 5, "head_dim"),
+                                     (192, 64, "divisible")])
+def test_flash_attention_refuses_shapes_it_cannot_tile(t, d, why):
+    """No silent fall to the reference path: the kernel entry point names
+    why it cannot run, and only attn_impl='auto' may choose."""
+    q, k, v = _qkv(t=t, d=d)
+    with pytest.raises(ValueError, match=why):
+        flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
+
+
+@pytest.mark.parametrize("rows,spec", [(4, P("data")), (3, P())])
+def test_flash_attention_under_a_mesh_matches_one_device(rows, spec):
+    """Mosaic kernels cannot be partitioned automatically, so under a jit
+    over mesh-sharded arguments the kernels run inside a shard_map over
+    the mesh read off the operand: batch over ``data`` when it divides
+    (no all-gather), replicated when it does not.  Gradients equal the
+    one-device run bit for bit (the loss sum reassociates across shards)."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    q, k, v = _qkv(b=rows, h=2, t=256, d=64, seed=9)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=128,
+                                       block_k=128, interpret=True) ** 2)
+    f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    want_l, want_g = f(q, k, v)
+    placed = [jax.device_put(a, NamedSharding(mesh, spec))
+              for a in (q, k, v)]
+    got_l, got_g = f(*placed)
+    assert float(got_l) == pytest.approx(float(want_l), rel=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if spec == P("data"):
+        assert "all-gather" not in f.lower(*placed).compile().as_text()
+
+
+def test_explicit_flash_impl_raises_off_tpu():
+    """attn_impl='flash' on a backend that cannot run the kernel raises
+    (the Pallas lowering refuses) instead of quietly running SDPA."""
+    from deeplearning4j_tpu.nn.layers import attention as L
+    q, k, v = _qkv(b=1, h=1, t=128, d=64)
+    with pytest.raises(Exception, match="(?i)interpret|tpu|backend"):
+        jax.block_until_ready(L._run_attention(
+            q, k, v, impl="flash", causal=True, mask=None, seq_axis="seq"))
 
 
 # ----------------------------------------------------- sequence parallelism
@@ -255,15 +288,17 @@ def test_cached_attention_honors_mask_and_causal_flag():
 
 
 def test_auto_dispatch_follows_measured_crossover(monkeypatch):
-    """VERDICT r3 item 1a: attn_impl='auto' selects by the measured
-    crossover (the CudnnAlgoMode role, ConvolutionLayer.java:349) —
-    reference SDPA below flash_min_seq, flash at/above, reference always
-    when masked.  The threshold is overridable per layer and by env."""
+    """attn_impl='auto' selects by the crossover (the CudnnAlgoMode role,
+    ConvolutionLayer.java:349) — reference SDPA below flash_min_seq,
+    flash at/above, reference always when masked, when the kernel cannot
+    tile the shapes, and off a TPU.  The threshold is overridable per
+    layer and by env."""
     import deeplearning4j_tpu.ops.attention as A
     import deeplearning4j_tpu.ops.flash_attention as F
     from deeplearning4j_tpu.nn.layers import attention as L
 
     calls = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(F, "flash_attention",
                         lambda q, k, v, **kw: calls.append("flash") or q)
     monkeypatch.setattr(A, "sdpa_reference",
@@ -278,7 +313,13 @@ def test_auto_dispatch_follows_measured_crossover(monkeypatch):
     run(short, mask=None, flash_min_seq=32)    # per-layer override -> flash
     run(long, mask=None, flash_min_seq=1 << 20)  # raised override -> ref
     run(long, mask=jnp.ones((1, long.shape[2])))  # masked -> always ref
-    assert calls == ["ref", "flash", "flash", "ref", "ref"]
+    run(jnp.zeros((1, 2, 192, 64)), mask=None)  # 192 % 128: cannot tile
+    run(jnp.zeros((1, 2, 256, 32)), mask=None)  # head_dim 32: cannot tile
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    run(long, mask=None)                       # not a TPU -> reference
+    assert calls == ["ref", "flash", "flash", "ref", "ref", "ref", "ref",
+                     "ref"]
+    assert L.auto_attention_impl(1024, 1024, 64, masked=False) == "reference"
 
 
 # ------------------------------------------- carry-primitive parity (ISSUE 11)

@@ -1,57 +1,72 @@
-"""Probe-bracketed capture protocol (VERDICT r4 item 4): a BENCH_SIDE row
-must only publish from a healthy before+after probe bracket; exhausted
-retries tag rows ``invalid`` rather than shipping degraded-window numbers."""
+"""Row-shape tests for the bench line sets (tiny CPU configs), and the
+contract of ``bench.py``'s row runner: a row that raises is printed with a
+null value and fails the run."""
+import json
 import sys
 from pathlib import Path
 
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from bench import probe_bracketed_capture  # noqa: E402
+import bench  # noqa: E402
 
 
-def _probes(seq):
-    it = iter(seq)
-    return lambda: {"healthy": next(it)}
+def test_run_row_prints_rows_and_reports_success(monkeypatch, capsys):
+    from deeplearning4j_tpu.utils import benchmarks as B
+    monkeypatch.setattr(B, "lint_time_ms",
+                        lambda: [{"metric": "a", "value": 1},
+                                 {"metric": "b", "value": 2}])
+    assert bench.run_row("lint_time_ms", "ms") is True
+    rows = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    assert [r["metric"] for r in rows] == ["a", "b"]
+    assert all("env" in r for r in rows)
 
 
-def test_healthy_bracket_single_pass():
-    calls = []
-    rows = probe_bracketed_capture(
-        lambda: calls.append(1) or {"metric": "m", "value": 1},
-        _probes([True, True]), sleep=lambda s: None)
-    assert len(calls) == 1
-    assert "invalid" not in rows[0]
-    assert rows[0]["tunnel_probe"]["healthy"]
+def test_run_row_failure_prints_null_value_and_reports_it(monkeypatch,
+                                                          capsys):
+    from deeplearning4j_tpu.utils import benchmarks as B
+
+    def boom():
+        raise RuntimeError("measured nothing")
+    monkeypatch.setattr(B, "lint_time_ms", boom)
+    assert bench.run_row("lint_time_ms", "ms") is False
+    row = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert row["value"] is None and row["unit"] == "ms"
+    assert "measured nothing" in row["error"]
 
 
-def test_sick_before_probe_backs_off_without_capturing():
-    calls = []
-    rows = probe_bracketed_capture(
-        lambda: calls.append(1) or {"metric": "m", "value": 1},
-        _probes([False, True, True]), sleep=lambda s: None)
-    assert len(calls) == 1          # no capture spent in the sick window
-    assert "invalid" not in rows[0]
+def _main_with(monkeypatch, headline, failing=()):
+    for env_var, _name, _unit in bench.SIDE_ROWS:
+        monkeypatch.delenv(env_var, raising=False)
+    monkeypatch.delenv("DL4J_TPU_BENCH_SIDE", raising=False)
+    monkeypatch.delenv("DL4J_TPU_BENCH_STRICT", raising=False)
+    monkeypatch.setattr(bench, "headline", headline)
+    ran = []
+    monkeypatch.setattr(
+        bench, "run_row",
+        lambda name, unit="", **kw: ran.append(name) or name not in failing)
+    return ran
 
 
-def test_mid_capture_degradation_voids_and_retries():
-    calls = []
-    rows = probe_bracketed_capture(
-        lambda: calls.append(1) or {"metric": "m", "value": 1},
-        _probes([True, False, True, True]), sleep=lambda s: None)
-    assert len(calls) == 2          # first capture voided, second shipped
-    assert "invalid" not in rows[0]
+def test_main_exits_nonzero_when_a_row_measured_nothing(monkeypatch):
+    """Every side row still runs after a failure, and the process then
+    exits non-zero — a run that measured nothing must not pass."""
+    ran = _main_with(monkeypatch, lambda: False, failing={"compile_reuse"})
+    assert bench.main() == 1
+    assert ran == [name for _e, name, _u in bench.SIDE_ROWS]
+    ran = _main_with(monkeypatch, lambda: False)
+    assert bench.main() == 0
 
 
-def test_exhausted_retries_tag_invalid():
-    calls = []
-    rows = probe_bracketed_capture(
-        lambda: calls.append(1) or [{"metric": "m", "value": 1}],
-        _probes([True, False, True, False, True, False]),
-        retries=2, sleep=lambda s: None)
-    assert len(calls) == 3
-    assert rows[0]["invalid"] is True
-    assert rows[0]["tunnel_probe"]["healthy"] is False
+def test_main_headline_failure_propagates(monkeypatch):
+    """No bail-and-return: a headline that cannot run ends the process
+    with its exception (exit code != 0), before any side row."""
+    def headline():
+        raise RuntimeError("no device")
+    ran = _main_with(monkeypatch, headline)
+    with pytest.raises(RuntimeError, match="no device"):
+        bench.main()
+    assert ran == []
 
 
 def test_serve_latency_ms_rows():

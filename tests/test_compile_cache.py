@@ -11,6 +11,7 @@ Acceptance criteria covered here:
     identical dropout masks).
 """
 import copy
+import os
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,8 @@ from deeplearning4j_tpu import (InputType, MultiLayerNetwork,
                                 NeuralNetConfiguration)
 from deeplearning4j_tpu.data.shapes import (ShapePolicy, default_shape_policy,
                                             next_pow2)
-from deeplearning4j_tpu.nn.compile_cache import (persistent_cache_status,
+from deeplearning4j_tpu.nn.compile_cache import (DEFAULT_CACHE_DIR,
+                                                 persistent_cache_status,
                                                  topology_signature,
                                                  wire_persistent_cache)
 from deeplearning4j_tpu.nn.conf.updaters import Adam, Sgd
@@ -270,31 +272,75 @@ def test_compile_phase_label_tracks_real_traces():
 
 
 # ----------------------------------------------------- persistent cache
-def test_persistent_cache_wiring_smoke(tmp_path):
-    """Second process-simulated init reports the entries the 'first
-    process' left behind."""
+@pytest.fixture
+def cache_config():
+    """Restore the jax cache config and the module status around a test
+    that re-wires the persistent cache (tests/conftest.py keeps it OFF for
+    the suite)."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_on = jax.config.jax_enable_compilation_cache
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+    jax.config.update("jax_enable_compilation_cache", prev_on)
+    jcc.reset_cache()
+
+
+def test_cache_dir_placed_from_outside_is_never_set_in_code(
+        tmp_path, monkeypatch, cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set the code sets NO directory (JAX
+    reads the variable itself); it reports that directory, and a second
+    wiring sees the entries the 'first process' left behind."""
     cache_dir = tmp_path / "xla-cache"
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        s1 = wire_persistent_cache(str(cache_dir))
-        assert s1["enabled"] and s1["existing_entries"] == 0
-        assert cache_dir.is_dir()
-        assert persistent_cache_status()["dir"] == str(cache_dir)
-        # exercise a compile so backends that persist on CPU write entries;
-        # simulate a prior process otherwise (the wiring contract under
-        # test is detection + reporting, not XLA's serializer)
-        jax.jit(lambda a: a * 2)(jnp.ones((4,))).block_until_ready()
-        if s1["existing_entries"] == 0 and not any(cache_dir.iterdir()):
-            (cache_dir / "jit__synthetic_entry").write_bytes(b"x")
-        s2 = wire_persistent_cache(str(cache_dir))
-        assert s2["enabled"] and s2["existing_entries"] >= 1
-        g = default_registry().get("training_persistent_cache_entries")
-        assert g is not None and g.value >= 1
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        wire_persistent_cache("")   # reset module status for other tests
+    cache_dir.mkdir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    sentinel = str(tmp_path / "whatever-jax-read-at-start-up")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    s1 = wire_persistent_cache()
+    assert jax.config.jax_compilation_cache_dir == sentinel   # untouched
+    assert s1 == {"enabled": True, "dir": str(cache_dir),
+                  "placed_by": "JAX_COMPILATION_CACHE_DIR",
+                  "existing_entries": 0}
+    # thresholds lowered so small programs persist too
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+    (cache_dir / "jit__entry_from_an_earlier_process").write_bytes(b"x")
+    assert wire_persistent_cache()["existing_entries"] == 1
+    g = default_registry().get("training_persistent_cache_entries")
+    assert g is not None and g.value == 1
 
 
-def test_wire_persistent_cache_noop_without_env(monkeypatch):
-    monkeypatch.delenv("DL4J_TPU_COMPILE_CACHE", raising=False)
-    assert wire_persistent_cache() == {"enabled": False}
+def test_default_cache_dir_is_one_fixed_path_in_the_checkout(
+        monkeypatch, cache_config):
+    """Without the variable the cache lives at ONE fixed git-ignored path
+    inside the checkout — no temp name, pid or timestamp — and a compile
+    that goes through it is counted as a miss, then a hit."""
+    import deeplearning4j_tpu
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    s1 = wire_persistent_cache()
+    pkg = os.path.dirname(os.path.abspath(deeplearning4j_tpu.__file__))
+    assert DEFAULT_CACHE_DIR == os.path.join(pkg, "_compile_cache")
+    assert s1["enabled"] and s1["placed_by"] == "checkout"
+    assert s1["dir"] == DEFAULT_CACHE_DIR == \
+        jax.config.jax_compilation_cache_dir
+    assert wire_persistent_cache()["dir"] == s1["dir"]        # stable
+    ignored = open(os.path.join(os.path.dirname(pkg), ".gitignore")).read()
+    assert "deeplearning4j_tpu/_compile_cache/" in ignored.split()
+    # the suite keeps the cache off: status says so rather than pretend
+    assert persistent_cache_status()["enabled"] is False
+    jax.config.update("jax_enable_compilation_cache", True)
+    jcc.reset_cache()
+    before = persistent_cache_status()
+
+    def probe(a):
+        return a * 3 + 41            # a program no other test compiles
+    jax.jit(probe)(jnp.ones((5,))).block_until_ready()
+    jax.clear_caches()               # drop the in-memory executable
+    jax.jit(probe)(jnp.ones((5,))).block_until_ready()
+    after = persistent_cache_status()
+    assert after["enabled"] is True
+    assert after["misses"] + after["hits"] >= \
+        before["misses"] + before["hits"] + 2
+    assert after["hits"] >= before["hits"] + 1
+    assert after["entries"] >= 1

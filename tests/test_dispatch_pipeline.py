@@ -312,42 +312,28 @@ class TestDepthGauge:
         assert gauge.value == float(depth)
 
 
-class TestOverlapGate:
-    """The ZeRO-3 gather/compute-overlap flags are TPU-runtime-only:
-    on a CPU-pinned rig they must never reach ``os.environ`` — a child
-    process inheriting them fatally aborts in XLA's flag parse
-    (``Unknown flags in XLA_FLAGS``), even when a libtpu wheel happens
-    to be installed on the box."""
+class TestNoProcessGlobalXlaFlags:
+    """An earlier ``ShardedTrainer`` constructor appended ``--xla_tpu_*``
+    overlap flags to ``os.environ["XLA_FLAGS"]``.  This jaxlib's flag
+    parser aborts on them, in this process's children and on the chip
+    alike, so the function is gone; these pin that it stays gone and
+    why."""
 
-    def test_cpu_pinned_rig_never_mutates_xla_flags(self):
-        from deeplearning4j_tpu.parallel.sharded import (
-            OVERLAP_XLA_FLAGS, enable_gather_compute_overlap)
-        before = os.environ.get("XLA_FLAGS", "")
-        # tier-1 runs under JAX_PLATFORMS=cpu: the platform is pinned
-        # away from TPU, so arming must refuse regardless of libtpu
-        assert enable_gather_compute_overlap() is False
-        assert os.environ.get("XLA_FLAGS", "") == before
-        for flag in OVERLAP_XLA_FLAGS:
-            assert flag.split("=")[0] not in \
-                os.environ.get("XLA_FLAGS", "")
+    def test_sharded_trainer_never_touches_xla_flags(self):
+        from deeplearning4j_tpu.parallel import ShardedTrainer, make_mesh
+        before = os.environ.get("XLA_FLAGS")
+        st = ShardedTrainer(dense_net(), make_mesh(dp=2))
+        assert os.environ.get("XLA_FLAGS") == before
+        assert "xla_tpu" not in (before or "")
+        assert not hasattr(st, "overlap_armed")
 
-    def test_platform_pin_parsing(self, monkeypatch):
-        from deeplearning4j_tpu.parallel import sharded
-
-        class _Cfg:
-            def __init__(self, platforms):
-                self.jax_platforms = platforms
-
-        for pinned, expected in [("cpu", False), ("tpu", True),
-                                 ("cpu,tpu", True), ("TPU", True),
-                                 ("gpu", False), ("", None)]:
-            monkeypatch.setattr(sharded.jax, "config", _Cfg(pinned))
-            if expected is None:
-                # empty config falls through to the environment pin,
-                # which tier-1 sets to cpu
-                monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-                assert sharded._tpu_platform_selected() is False
-                monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-                assert sharded._tpu_platform_selected() is True
-            else:
-                assert sharded._tpu_platform_selected() is expected
+    def test_xla_tpu_flags_in_xla_flags_abort_the_process(self):
+        import subprocess
+        import sys
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_tpu_enable_async_all_gather=true")
+        r = subprocess.run(
+            [sys.executable, "-c", "import jax; jax.devices()"],
+            env=env, capture_output=True, text=True, timeout=120)
+        assert r.returncode != 0
+        assert "Unknown flag" in r.stderr and "XLA_FLAGS" in r.stderr

@@ -1,13 +1,10 @@
 """Expert parallelism (MoE with all-to-all dispatch) on the virtual
-8-device CPU mesh — completes the dp/tp/pp/sp/ep taxonomy."""
+8-device CPU mesh — completes the dp/tp/pp/sp/ep set of parallelism classes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.expert import (init_moe_params,

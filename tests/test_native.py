@@ -18,6 +18,15 @@ def test_native_library_builds():
     assert available(), "g++ build of native/dl4j_tpu_native.cpp failed"
 
 
+def test_loaded_library_is_named_by_the_source_it_was_built_from():
+    """A stale build left in the git-ignored directory is never trusted:
+    the library's name carries the digest of native_src.cpp as on disk."""
+    import hashlib
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native.library_path() == str(
+        native._BUILD_DIR / f"libdl4j_tpu_native-{digest}.so")
+
+
 class TestThresholdCodec:
     def test_roundtrip_reconstructs(self):
         rng = np.random.default_rng(0)

@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.pipeline import gpipe, stack_stage_params
