@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import optax
 
 from .layers.base import BaseLayerConf, LayerConf
+from ..observability.tracer import get_tracer
 
 
 def hyperparam_conf(lc: Optional[LayerConf]) -> Optional[BaseLayerConf]:
@@ -342,7 +343,8 @@ def _fit_on_device_epochs(model, xs, ys, batch_size, epochs, shuffle,
     # exception-guarded: with async dispatch this float() is where deferred
     # device-side failures (OOM, runtime faults) first surface, and they
     # must raise out of fit, not become a silent nan.
-    model._score = float(model._score)
+    with get_tracer().span("dl4j.sync"):
+        model._score = float(model._score)
     return model
 
 

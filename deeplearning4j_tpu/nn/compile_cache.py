@@ -17,9 +17,10 @@ Three mechanisms make that the framework default:
    function counts its (re)traces into ``training_compile_total{fn}`` —
    incremented *at trace time* via a deliberate Python side effect inside
    the traced function, the one moment jit runs the Python body — and
-   records trace+compile wall time in ``training_compile_seconds{fn}``
-   plus an ``xla.compile`` tracer span, so recompile storms show up in
-   /metrics instead of as mystery latency.
+   records trace+compile wall time in ``training_compile_seconds{fn}``,
+   so recompile storms show up in /metrics instead of as mystery
+   latency.  Every call is the span ``dl4j.call.<name>``: a dispatch,
+   and the trace and compile when the call has to.
 
 3. **Persistent compile cache** (`wire_persistent_cache`): JAX's on-disk
    compilation cache, wired at package init, so a restarted process
@@ -246,17 +247,20 @@ class InstrumentedJit:
     The wrapped Python function body runs exactly once per trace — that is
     the hook: it bumps ``training_compile_total{fn}`` and flags the calling
     thread, so ``__call__`` can attribute the call's wall time to
-    ``training_compile_seconds{fn}`` and emit an ``xla.compile`` span.  In
-    JAX, trace+lower+compile are synchronous within the triggering call
-    (only execution is async), so that wall time is an honest compile cost.
+    ``training_compile_seconds{fn}``.  In JAX, trace+lower+compile are
+    synchronous within the triggering call (only execution is async), so
+    that wall time is an honest compile cost, and the call's span
+    ``dl4j.call.<name>`` covers it (jax's own ``backend_compile``
+    annotation nests inside).
     """
 
     __slots__ = ("name", "fn", "_tls", "_fun", "_donate", "_audit_specs",
-                 "_audit_live", "_audit_lock", "__weakref__")
+                 "_audit_live", "_audit_lock", "_span_name", "__weakref__")
 
     def __init__(self, fun: Callable, name: str,
                  donate_argnums: Tuple[int, ...] = ()):
         self.name = name
+        self._span_name = "dl4j.call." + name
         self._tls = threading.local()
         # audit surface (tools/graftaudit): the raw builder function and
         # its declared donation — re-lowering goes through a FRESH
@@ -275,6 +279,9 @@ class InstrumentedJit:
                 holder._note_trace()
             return fun(*args, **kwargs)
 
+        # the program carries the wrapper's name to the compiler (module
+        # ``jit_<name>``) and into a profile (scopes start ``jit(<name>)/``)
+        traced.__name__ = traced.__qualname__ = name
         self.fn = jax.jit(traced, donate_argnums=donate_argnums)
 
     def _note_trace(self) -> None:
@@ -289,7 +296,8 @@ class InstrumentedJit:
     def __call__(self, *args, **kwargs):
         self._tls.traced = False
         t0 = monotonic_s()
-        out = self.fn(*args, **kwargs)
+        with get_tracer().span(self._span_name):
+            out = self.fn(*args, **kwargs)
         if _AUDIT_MODE == "all" or (_AUDIT_MODE == "trace"
                                     and self._tls.traced):
             self._record_spec(args, kwargs)
@@ -302,13 +310,6 @@ class InstrumentedJit:
                     "Wall time of calls that (re)traced, i.e. trace + "
                     "compile + first dispatch", ("fn",),
                     buckets=_COMPILE_BUCKETS).labels(self.name).observe(dt)
-            tracer = get_tracer()
-            if tracer.enabled:
-                # marker span: the compile already happened inside the call
-                # above; `seconds` carries its true duration
-                with tracer.span("xla.compile", fn=self.name,
-                                 seconds=round(dt, 4)):
-                    pass
         return out
 
     @property
@@ -493,7 +494,10 @@ def wire_persistent_cache() -> Dict[str, Any]:
     from outside: JAX reads the variable itself and this code sets no
     directory at all.  Where it is not, the cache goes to
     ``DEFAULT_CACHE_DIR``.  Thresholds are lowered so every entry persists
-    (the min-compile-time default would skip small programs).  A checkout
+    (the min-compile-time default would skip small programs).  The key
+    leaves a program's metadata out (JAX's default), so a program that
+    differs from a cached one only in its name scopes is served the old
+    executable, and a profile then reads the old scopes.  A checkout
     that cannot be written (read-only install) leaves the cache off and
     says so in the returned status; nothing else is caught.  Returns the
     status dict, including how many entries a previous process left behind

@@ -32,6 +32,7 @@ from .conf.updaters import Sgd, UpdaterConf
 from .layers.base import BaseLayerConf
 from ..data.shapes import default_shape_policy
 from ..observability.clock import monotonic_s
+from ..observability.tracer import get_tracer, training_entry
 from ..train.listeners import TrainingListener
 
 Array = jax.Array
@@ -88,17 +89,20 @@ def _graph_forward(conf, params, state, inputs: List[Array], *, train: bool,
             xs = [_cast_act(x, vdt) for x in xs]
         variables = {"params": params.get(name, {}),
                      "state": state.get(name, {})}
-        if train and conf.defaults.get("cache_mode") == "remat" and \
-                isinstance(v, LayerVertex):
-            # rematerialize per-vertex activations on the backward pass
-            # (the WorkspaceMode/CacheMode role: trade FLOPs for HBM —
-            # SURVEY §7 "Workspaces → jax.checkpoint")
-            def _apply(vv, xx, kk, mm, _v=v):
-                return _v.apply(vv, xx, train=True, key=kk, masks=mm)
-            y, lstate = jax.checkpoint(_apply)(variables, xs, lkey, ms)
-        else:
-            y, lstate = v.apply(variables, xs, train=train, key=lkey,
-                                masks=ms)
+        # one scope per vertex, named by its layer conf's class (see
+        # nn/multilayer._stack_forward)
+        with jax.named_scope(type(getattr(v, "layer", None) or v).__name__):
+            if train and conf.defaults.get("cache_mode") == "remat" and \
+                    isinstance(v, LayerVertex):
+                # rematerialize per-vertex activations on the backward pass
+                # (the WorkspaceMode/CacheMode role: trade FLOPs for HBM —
+                # SURVEY §7 "Workspaces → jax.checkpoint")
+                def _apply(vv, xx, kk, mm, _v=v):
+                    return _v.apply(vv, xx, train=True, key=kk, masks=mm)
+                y, lstate = jax.checkpoint(_apply)(variables, xs, lkey, ms)
+            else:
+                y, lstate = v.apply(variables, xs, train=train, key=lkey,
+                                    masks=ms)
         acts[name] = y
         new_state[name] = lstate
         mask_of[name] = v.feed_forward_mask(ms, xs)
@@ -134,8 +138,9 @@ def _graph_loss(conf, params, state, inputs, labels, *, train: bool, key,
                 if key is not None else None)
         variables = {"params": params.get(name, {}),
                      "state": state.get(name, {})}
-        l = v.compute_loss(variables, h, labels[oi], train=train,
-                           key=lkey, mask=lm)
+        with jax.named_scope(type(v.layer).__name__):
+            l = v.compute_loss(variables, h, labels[oi], train=train,
+                               key=lkey, mask=lm)
         total = l if total is None else total + l
     if total is None:
         total = jnp.zeros((), jnp.float32)
@@ -219,6 +224,8 @@ def _build_graph_train_step(conf, tx):
             if pol is not None and pol.scaled else None
         scale = ls["scale"] if ls is not None else None
 
+        # the same scopes as nn/multilayer._build_train_step
+        @jax.named_scope("forward")
         def loss_fn(p):
             if cast_map:
                 p = {k: (_cast_floats(v, cast_map[k]) if k in cast_map
@@ -232,18 +239,20 @@ def _build_graph_train_step(conf, tx):
         (_obj, (loss, new_state)), grads = \
             jax.value_and_grad(loss_fn, has_aux=True)(params)
         finite = None
-        if scale is not None:
-            grads, finite = _precision.unscale_and_check(grads, scale)
-        grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
-        gleaves = jax.tree_util.tree_leaves(grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
-            if gleaves else jnp.zeros((), jnp.float32)
-        glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
-                                  for g in jax.tree_util.tree_leaves(v)))
-                  for k, v in grads.items() if v}
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        new_params = apply_constraints_all(new_params, confs)
+        with jax.named_scope("grad_post"):
+            if scale is not None:
+                grads, finite = _precision.unscale_and_check(grads, scale)
+            grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
+            gleaves = jax.tree_util.tree_leaves(grads)
+            gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
+                if gleaves else jnp.zeros((), jnp.float32)
+            glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
+                                      for g in jax.tree_util.tree_leaves(v)))
+                      for k, v in grads.items() if v}
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            new_params = apply_constraints_all(new_params, confs)
         if pol is not None:
             new_state = _cast_floats(new_state, jnp.float32,
                                      only=pol.compute_dtype)
@@ -467,12 +476,14 @@ class ComputationGraph:
         prof = self._stepprof
         if prof is not None:
             _t = monotonic_s()
-        xs = [jnp.asarray(x) for x in xs]
-        ys = [jnp.asarray(y) for y in ys]
-        ms = None if ms is None else [
-            None if m is None else jnp.asarray(m) for m in _as_list(ms)]
-        lms = None if lms is None else [
-            None if m is None else jnp.asarray(m) for m in _as_list(lms)]
+        with get_tracer().span("dl4j.h2d"):
+            xs = [jnp.asarray(x) for x in xs]
+            ys = [jnp.asarray(y) for y in ys]
+            ms = None if ms is None else [
+                None if m is None else jnp.asarray(m) for m in _as_list(ms)]
+            lms = None if lms is None else [
+                None if m is None else jnp.asarray(m)
+                for m in _as_list(lms)]
         if prof is not None:
             prof.mark("h2d", monotonic_s() - _t)
         self.last_batch_size = int(xs[0].shape[0])
@@ -511,6 +522,7 @@ class ComputationGraph:
             self.init()
         return float(self._fit_one(*self._normalize_batch(batch)))
 
+    @training_entry("dl4j.fit")
     def fit(self, data=None, labels=None, *, epochs: int = 1,
             masks=None, label_masks=None, checkpoint=None,
             resume_from=None) -> "ComputationGraph":
@@ -580,16 +592,22 @@ class ComputationGraph:
                              on_nan=_nan_at_drain)
         start_epoch = ckpt.start_epoch if ckpt is not None else 0
         stop = False
+        span = get_tracer().span
         try:
             for ep in range(start_epoch, epochs):
                 for lst in self.listeners:
                     lst.on_epoch_start(self)
+                batches = iter(batches_factory())
                 # resume cursor: skip already-consumed batches of the first
                 # resumed epoch without fitting (see MultiLayerNetwork.fit)
                 skip = ckpt.skip_batches \
                     if (ckpt is not None and ep == ckpt.start_epoch) else 0
                 seq = 0
-                for batch in batches_factory():
+                while True:
+                    with span("dl4j.input_wait"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        break
                     if seq < skip:
                         seq += 1
                         continue
@@ -630,7 +648,8 @@ class ComputationGraph:
                 # listeners (MetricsListener score/grad-norm) see a host
                 # float without forcing their own sync
                 win.drain()
-                self._score = float(self._score)
+                with span("dl4j.sync"):
+                    self._score = float(self._score)
                 if prof is not None:
                     prof.materialized()
                 for lst in self.listeners:
@@ -676,9 +695,11 @@ class ComputationGraph:
                 ckpt.close()
         # ONE materialization for the whole fit (async steps pipeline).
         # NOT exception-guarded: deferred device failures surface here
-        self._score = float(self._score)
+        with span("dl4j.sync"):
+            self._score = float(self._score)
         return self
 
+    @training_entry("dl4j.fit_on_device")
     def fit_on_device(self, inputs, labels, *, batch_size: int,
                       epochs: int = 1, shuffle: bool = True,
                       checkpoint=None, resume_from=None

@@ -38,6 +38,7 @@ from collections import deque
 from typing import Any, Callable, List, Optional, Tuple
 
 from ..observability.clock import monotonic_s
+from ..observability.tracer import get_tracer
 
 DEFAULT_DEPTH = 2
 ENV_VAR = "DL4J_TPU_DISPATCH_DEPTH"
@@ -128,8 +129,10 @@ class DispatchWindow:
         # finished.  Deliberately NOT jax.block_until_ready — the stepprof
         # host-sync sweep counts those to pin the profiler's fence cadence,
         # and the window's bounded backpressure is loop-owned, not
-        # profiler-owned.
-        value = float(token)
+        # profiler-owned.  The span is the host waiting for the device:
+        # the healthy state.
+        with get_tracer().span("dl4j.window_wait"):
+            value = float(token)
         if self.owner is not None:
             self.owner.last_drained_score = value
             self.owner.last_drained_iteration = iteration

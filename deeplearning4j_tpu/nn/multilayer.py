@@ -47,6 +47,7 @@ from ..data.pipeline import ETL_BUCKETS as _ETL_BUCKETS
 from ..data.shapes import _pad_time, default_shape_policy
 from ..observability.clock import monotonic_s, wall_s
 from ..observability.registry import default_registry
+from ..observability.tracer import get_tracer, training_entry
 from ..train.listeners import TrainingListener
 
 # training-step histogram bounds: sub-ms CPU steps up to multi-second
@@ -276,21 +277,25 @@ def _stack_forward(conf, params, state, x, *, train: bool, key, mask=None,
         variables = {"params": params.get(f"layer_{i}", {}),
                      "state": state.get(f"layer_{i}", {})}
         lname = f"layer_{i}"
-        if carries is not None and getattr(lc, "HAS_CARRY", False):
-            h, new_carry = lc.apply_with_carry(
-                variables, h, carries.get(lname), train=train, key=lkey,
-                mask=mask)
-            carries[lname] = new_carry
-            lstate = variables.get("state", {})
-        elif remat:
-            # rematerialize per-layer activations on the backward pass
-            # (the WorkspaceMode/CacheMode role: trade FLOPs for HBM)
-            def _apply(vv, hh, kk, mm, _lc=lc):
-                return _lc.apply(vv, hh, train=True, key=kk, mask=mm)
-            h, lstate = jax.checkpoint(_apply)(variables, h, lkey, mask)
-        else:
-            h, lstate = lc.apply(variables, h, train=train, key=lkey,
-                                 mask=mask)
+        # one scope per layer, named by its conf's class: the profile of
+        # a step splits by layer kind (metadata only; the program is the
+        # same)
+        with jax.named_scope(type(lc).__name__):
+            if carries is not None and getattr(lc, "HAS_CARRY", False):
+                h, new_carry = lc.apply_with_carry(
+                    variables, h, carries.get(lname), train=train,
+                    key=lkey, mask=mask)
+                carries[lname] = new_carry
+                lstate = variables.get("state", {})
+            elif remat:
+                # rematerialize per-layer activations on the backward pass
+                # (the WorkspaceMode/CacheMode role: trade FLOPs for HBM)
+                def _apply(vv, hh, kk, mm, _lc=lc):
+                    return _lc.apply(vv, hh, train=True, key=kk, mask=mm)
+                h, lstate = jax.checkpoint(_apply)(variables, h, lkey, mask)
+            else:
+                h, lstate = lc.apply(variables, h, train=train, key=lkey,
+                                     mask=mask)
         new_state[lname] = lstate
         if mask is not None:
             mask = lc.feed_forward_mask(mask, None)
@@ -332,8 +337,9 @@ def _stack_loss(conf, params, state, x, y, *, train: bool, key, mask=None,
     # per-timestep masking when labelsMask is absent; a LastTimeStep/
     # global-pooling layer consumes the time axis and nulls the mask)
     lm = label_mask if label_mask is not None else pmask
-    loss = out_conf.compute_loss(variables, h, y, train=train, key=lkey,
-                                 mask=lm)
+    with jax.named_scope(type(out_conf).__name__):
+        loss = out_conf.compute_loss(variables, h, y, train=train, key=lkey,
+                                     mask=lm)
     # accumulator follows the LOSS dtype: a dtype-defaulted zeros(())
     # is f64 under x64 and silently promotes the whole loss output
     # (graftaudit AX001); f64 gradient-check runs still get f64 here
@@ -519,6 +525,10 @@ def _build_train_step(conf, tx, with_carry: bool):
             if pol is not None and pol.scaled else None
         scale = ls["scale"] if ls is not None else None
 
+        # scopes are metadata: under value_and_grad the forward's
+        # operations are named jvp(forward)/<layer>/..., the backward's
+        # transpose(jvp(forward))/<layer>/...
+        @jax.named_scope("forward")
         def loss_fn(p):
             if cast_map:
                 # mixed precision: cast params per layer for the traced
@@ -555,44 +565,46 @@ def _build_train_step(conf, tx, with_carry: bool):
             grads["layer_0"] = dict(grads["layer_0"],
                                     W=ctx.wrap_grad(grads["layer_0"]["W"]))
         finite = None
-        if scale is not None:
-            grads, finite = _precision.unscale_and_check(grads, scale)
-        grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
-        # per-iteration gradient stats for listeners (reference
-        # ParamAndGradientIterationListener / StatsListener): computed
-        # inside the same program so they fuse with the update.  Float
-        # leaves only (_common.float_grad_leaves): SparseRows carries
-        # int32 indices, and coalesced values give the SAME norm the
-        # dense gradient would.
-        gleaves = float_grad_leaves(grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
-            if gleaves else jnp.zeros((), jnp.float32)
-        glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
-                                  for g in float_grad_leaves(v)))
-                  for k, v in grads.items() if v}
-        if ctx is not None:
-            # lazy row-space update: the SAME optax transform runs on
-            # [capacity, dim] views — touched rows of the table and of
-            # every param-shaped mirror leaf (mu/nu/trace) — then only
-            # those rows scatter back.  Untouched rows and mirrors keep
-            # their pre-step bytes.
-            g_upd = dict(grads)
-            g_upd["layer_0"] = dict(g_upd["layer_0"],
-                                    W=g_upd["layer_0"]["W"].values)
-            p_upd = {**params, "layer_0": dict(params["layer_0"],
-                                               W=ctx.rows)}
-            opt_upd = _sparse.gather_rows_tree(opt_state, ctx)
-        else:
-            g_upd, p_upd, opt_upd = grads, params, opt_state
-        updates, new_opt = tx.update(g_upd, opt_upd, p_upd)
-        new_params = optax.apply_updates(p_upd, updates)
-        if ctx is not None:
-            new_params = {**new_params, "layer_0": dict(
-                new_params["layer_0"],
-                W=ctx.scatter_rows(params["layer_0"]["W"],
-                                   new_params["layer_0"]["W"]))}
-            new_opt = _sparse.scatter_rows_tree(opt_state, new_opt, ctx)
-        new_params = apply_constraints_all(new_params, confs)
+        with jax.named_scope("grad_post"):
+            if scale is not None:
+                grads, finite = _precision.unscale_and_check(grads, scale)
+            grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
+            # per-iteration gradient stats for listeners (reference
+            # ParamAndGradientIterationListener / StatsListener): computed
+            # inside the same program so they fuse with the update.  Float
+            # leaves only (_common.float_grad_leaves): SparseRows carries
+            # int32 indices, and coalesced values give the SAME norm the
+            # dense gradient would.
+            gleaves = float_grad_leaves(grads)
+            gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
+                if gleaves else jnp.zeros((), jnp.float32)
+            glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
+                                      for g in float_grad_leaves(v)))
+                      for k, v in grads.items() if v}
+        with jax.named_scope("optimizer"):
+            if ctx is not None:
+                # lazy row-space update: the SAME optax transform runs on
+                # [capacity, dim] views — touched rows of the table and of
+                # every param-shaped mirror leaf (mu/nu/trace) — then only
+                # those rows scatter back.  Untouched rows and mirrors keep
+                # their pre-step bytes.
+                g_upd = dict(grads)
+                g_upd["layer_0"] = dict(g_upd["layer_0"],
+                                        W=g_upd["layer_0"]["W"].values)
+                p_upd = {**params, "layer_0": dict(params["layer_0"],
+                                                   W=ctx.rows)}
+                opt_upd = _sparse.gather_rows_tree(opt_state, ctx)
+            else:
+                g_upd, p_upd, opt_upd = grads, params, opt_state
+            updates, new_opt = tx.update(g_upd, opt_upd, p_upd)
+            new_params = optax.apply_updates(p_upd, updates)
+            if ctx is not None:
+                new_params = {**new_params, "layer_0": dict(
+                    new_params["layer_0"],
+                    W=ctx.scatter_rows(params["layer_0"]["W"],
+                                       new_params["layer_0"]["W"]))}
+                new_opt = _sparse.scatter_rows_tree(opt_state, new_opt, ctx)
+            new_params = apply_constraints_all(new_params, confs)
         if pol is not None:
             # keep running state (BN statistics) in f32 so the step's
             # input/output treedefs+dtypes stay fixed across iterations
@@ -888,6 +900,7 @@ class MultiLayerNetwork:
         (BatchNorm batch statistics)."""
         return self._pad_flags()[2]
 
+    @training_entry("dl4j.fit")
     def fit(self, data=None, labels=None, *, epochs: int = 1,
             mask=None, label_mask=None, checkpoint=None,
             resume_from=None) -> "MultiLayerNetwork":
@@ -959,6 +972,7 @@ class MultiLayerNetwork:
             from ..faulttolerance.checkpoint import FitCheckpointer
             ckpt = FitCheckpointer(self, checkpoint, resume_from)
         step_fn = self._get_jitted("train_step")
+        span = get_tracer().span
         # observability (cheap by default: plain host float math per
         # step, instruments resolved once per fit, and the step timing
         # closes on the loss sync _fit_one/_fit_tbptt already perform —
@@ -1028,7 +1042,8 @@ class MultiLayerNetwork:
                 seq = 0
                 while True:
                     t_etl = time.perf_counter()
-                    batch = next(batches, None)
+                    with span("dl4j.input_wait"):
+                        batch = next(batches, None)
                     # ETL/compute boundary timing (reference lastEtlTime,
                     # MultiLayerNetwork.java:1203-1209): time blocked on the
                     # data pipeline, visible to PerformanceListener
@@ -1095,7 +1110,8 @@ class MultiLayerNetwork:
                 # listeners (MetricsListener score/grad-norm) see a host
                 # float without forcing their own sync
                 win.drain()
-                self._score = float(self._score)
+                with span("dl4j.sync"):
+                    self._score = float(self._score)
                 if prof is not None:
                     prof.materialized()
                 for lst in self.listeners:
@@ -1149,7 +1165,8 @@ class MultiLayerNetwork:
         # as the async device scalar so steps pipeline.  NOT
         # exception-guarded: this float() is where deferred device-side
         # failures first surface, and they must propagate
-        self._score = float(self._score)
+        with span("dl4j.sync"):
+            self._score = float(self._score)
         if obs and steady_s > 0:
             # steady-state throughput: the compile-dominated first step
             # is excluded (same convention as utils/benchmarks.py)
@@ -1159,6 +1176,7 @@ class MultiLayerNetwork:
                       ).set(steady_examples / steady_s)
         return self
 
+    @training_entry("dl4j.fit_on_device")
     def fit_on_device(self, x, y, *, batch_size: int, epochs: int = 1,
                       shuffle: bool = True, checkpoint=None,
                       resume_from=None) -> "MultiLayerNetwork":
@@ -1396,8 +1414,9 @@ class MultiLayerNetwork:
         prof = self._stepprof
         if prof is not None:
             _t = monotonic_s()
-        x, y, m, lm = (_on_device(x), _on_device(y), _on_device(m),
-                       _on_device(lm))
+        with get_tracer().span("dl4j.h2d"):
+            x, y, m, lm = (_on_device(x), _on_device(y), _on_device(m),
+                           _on_device(lm))
         if prof is not None:
             prof.mark("h2d", monotonic_s() - _t)
         # fused-RNG step: the key split happens inside the program and the

@@ -147,8 +147,10 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
 
     def body(carry, per_layer):
         p, s, k = per_layer
-        y, ns = lc.apply({"params": p, "state": s}, carry, train=train,
-                         key=k, mask=mask)
+        # the layer's scope, as the unrolled walk names it
+        with jax.named_scope(type(lc).__name__):
+            y, ns = lc.apply({"params": p, "state": s}, carry, train=train,
+                             key=k, mask=mask)
         return y, ns
 
     if remat:

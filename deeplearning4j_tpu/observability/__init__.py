@@ -10,8 +10,17 @@ OpProfiler, UI stats storage — SURVEY §5):
   sets; thread-safe; process-global default + injectable instances;
 - :mod:`exposition` — Prometheus text format + JSON snapshot (served on
   ``/metrics`` by both HTTP servers in ``serving/``);
-- :mod:`tracer` — nested spans on monotonic clocks with cross-thread /
-  cross-process context propagation and optional Xprof bridging;
+- :mod:`tracer` — every span is a ``jax.profiler.TraceAnnotation``, so
+  a profiler trace holds the program's own spans (``dl4j.fit``,
+  ``dl4j.input_wait``, ``dl4j.h2d``, ``dl4j.call.<name>``,
+  ``dl4j.window_wait``, ``dl4j.profiler_fence``, ``dl4j.sync``,
+  ``dl4j.gc``; the table is in the README) beside the device's
+  operations, which the step program's name scopes (``forward``,
+  ``grad_post``, ``optimizer``, one per layer class) name; an enabled
+  tracer also records nested spans on monotonic clocks with
+  cross-thread / cross-process context propagation.  The collector's
+  callback of the training entries keeps
+  ``host_gc_pause_seconds_total``;
 - :mod:`events` — structured JSONL event log for offline analysis;
 - :mod:`listener` — ``MetricsListener`` publishing score/throughput/
   grad-norm/device-memory from the ``TrainingListener`` hook points;
@@ -38,9 +47,13 @@ OpProfiler, UI stats storage — SURVEY §5):
 Cost model: METRICS are on by default (the registry is plain host
 arithmetic — serving ``/metrics`` and the training counters work out of
 the box) and ``default_registry().disable()`` short-circuits every
-instrument write to one bool check; TRACING is off by default (enable
-via ``DL4J_TPU_TRACE=1|xprof`` or an injected ``Tracer``).  Nothing in
-this package ever forces a device sync.
+instrument write to one bool check; RECORDING spans is off by default
+(enable via ``DL4J_TPU_TRACE=1`` or an injected ``Tracer``), while the
+annotation a span writes into a profiler trace is always there and
+costs one flag check when nobody records.  ``Tracer(bridge_xprof=True)``
+and ``DL4J_TPU_TRACE=xprof`` are gone: bridging is what a span is.
+Nothing in this package forces a device sync, the step profiler's
+sampled fence apart (default on, every 16th step of a ``fit``).
 """
 from __future__ import annotations
 

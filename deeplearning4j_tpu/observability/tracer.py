@@ -1,8 +1,17 @@
-"""Span-based tracer: nested spans with monotonic timing, cross-thread /
-cross-process context propagation, and optional bridging into
-``jax.profiler.TraceAnnotation`` so spans land on Xprof timelines.
+"""Span-based tracer: every span is a ``jax.profiler.TraceAnnotation``, and
+an enabled tracer also records it (nested spans with monotonic timing,
+cross-thread / cross-process context propagation).
 
-The model is deliberately small (a working subset of OpenTelemetry's):
+One rule: ``get_tracer().span(name)`` always writes into the profiler's
+trace.  Whoever records one (``jax.profiler.start_trace``,
+``utils/profiling.ProfilerListener``, ``benchmark/run.py --trace 1``) gets
+the program's spans on the profiler's clock beside the device's
+operations; while nobody records, an annotation is one flag check.  The
+training entries' spans (prefix ``dl4j.``, listed in the README's
+"Observability" section) sit at the places every fit loop passes through.
+
+The recorded model is deliberately small (a working subset of
+OpenTelemetry's):
 
 - a **Span** is a named interval with attributes, a ``trace_id`` shared
   by everything descending from one root, and a ``parent_id``;
@@ -14,26 +23,29 @@ The model is deliberately small (a working subset of OpenTelemetry's):
   spec); ``attach(ctx)`` re-roots the local stack under the remote
   parent.
 
-Tracing is OFF by default (unlike the metrics registry, which stays on
-— spans allocate objects and read clocks, counters are plain float
-adds).  A disabled tracer short-circuits ``span()`` to a shared no-op
-context manager: no object allocation, no clock reads, no device syncs
-ever.
+Recording is OFF by default (unlike the metrics registry, which stays on
+— recorded spans allocate objects and read clocks, counters are plain
+float adds).  A disabled tracer's ``span()`` hands back the bare
+annotation: no ``Span``, no clock reads, no device syncs ever.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
+import gc
 import os
 import threading
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from .clock import monotonic_s, wall_s
 from .registry import MetricsRegistry, default_registry
 
 __all__ = ["Span", "SpanContext", "Tracer", "get_tracer",
-           "set_default_tracer"]
+           "set_default_tracer", "training_entry"]
 
 # span-duration histogram bounds: phase timings range from sub-ms host
 # work to multi-second aggregation rounds
@@ -102,28 +114,66 @@ def _noop_cm():
     yield None
 
 
+class _RecordedSpan:
+    """What an enabled tracer's ``span()`` returns: the profiler's
+    annotation and the recorded :class:`Span`, opened and closed
+    together."""
+
+    __slots__ = ("_tracer", "_annotation", "_name", "_attributes", "_span")
+
+    def __init__(self, tracer, annotation, name, attributes):
+        self._tracer = tracer
+        self._annotation = annotation
+        self._name = name
+        self._attributes = attributes
+        self._span = None
+
+    def __enter__(self) -> "Span":
+        st = self._tracer._stack()
+        parent = st[-1] if st else None
+        sp = self._span = Span(
+            name=self._name,
+            trace_id=parent.trace_id if parent else _new_id(),
+            span_id=_new_id(),
+            parent_id=parent.span_id if parent else None,
+            attributes=dict(self._attributes),
+            start_wall_s=wall_s(),
+            _start_mono=monotonic_s())
+        st.append(sp)
+        self._annotation.__enter__()
+        return sp
+
+    def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        sp, st = self._span, self._tracer._stack()
+        sp.duration_s = monotonic_s() - sp._start_mono
+        if st and st[-1] is sp:
+            st.pop()
+        else:  # tolerate out-of-order exits from generator teardown
+            try:
+                st.remove(sp)
+            except ValueError:
+                pass
+        self._tracer._record(sp)
+        return False
+
+
 class Tracer:
     """Create with ``enabled=True`` (or call :func:`get_tracer` after
     ``set_default_tracer``) to record spans.
 
     ``registry``: span durations land in a ``span_seconds{name=...}``
     histogram there (defaults to the process-global registry).
-    ``bridge_xprof``: wrap every span in a
-    ``jax.profiler.TraceAnnotation`` so host-side phases line up with
-    device ops in Xprof captures (imports jax lazily — the tracer stays
-    dependency-free when the bridge is off).
     ``max_finished``: ring buffer of completed spans kept for
     inspection/tests; 0 keeps none.
     """
 
     def __init__(self, enabled: bool = False,
                  registry: Optional[MetricsRegistry] = None,
-                 bridge_xprof: bool = False,
                  max_finished: int = 1024,
                  event_log=None):
         self._enabled = enabled
         self._registry = registry
-        self._bridge_xprof = bridge_xprof
         self._max_finished = max_finished
         self._event_log = event_log
         self._tls = threading.local()
@@ -175,48 +225,15 @@ class Tracer:
             self._finished.clear()
 
     # -- span lifecycle ------------------------------------------------------
-    @contextlib.contextmanager
     def span(self, name: str, **attributes):
-        """Open a nested span; yields the Span (or None when disabled)."""
+        """A context manager around one named interval.  Always a
+        ``jax.profiler.TraceAnnotation`` (the attributes become its
+        statistics); an enabled tracer also records a nested
+        :class:`Span` and yields it."""
+        annotation = jax.profiler.TraceAnnotation(name, **attributes)
         if not self._enabled:
-            with _noop_cm() as nothing:
-                yield nothing
-            return
-        st = self._stack()
-        parent = st[-1] if st else None
-        sp = Span(name=name,
-                  trace_id=parent.trace_id if parent else _new_id(),
-                  span_id=_new_id(),
-                  parent_id=parent.span_id if parent else None,
-                  attributes=dict(attributes),
-                  start_wall_s=wall_s(),
-                  _start_mono=monotonic_s())
-        st.append(sp)
-        annotation = None
-        if self._bridge_xprof:
-            try:
-                import jax
-                annotation = jax.profiler.TraceAnnotation(name)
-                annotation.__enter__()
-            except Exception:
-                annotation = None
-        try:
-            yield sp
-        finally:
-            if annotation is not None:
-                try:
-                    annotation.__exit__(None, None, None)
-                except Exception:
-                    pass
-            sp.duration_s = monotonic_s() - sp._start_mono
-            if st and st[-1] is sp:
-                st.pop()
-            else:  # tolerate out-of-order exits from generator teardown
-                try:
-                    st.remove(sp)
-                except ValueError:
-                    pass
-            self._record(sp)
+            return annotation
+        return _RecordedSpan(self, annotation, name, attributes)
 
     @contextlib.contextmanager
     def attach(self, ctx: Optional[SpanContext]):
@@ -265,12 +282,9 @@ class Tracer:
             rec.record_span(sp)
 
 
-# env opt-in: DL4J_TPU_TRACE=1 enables the default tracer at import time
-# (the knob production pods flip without code changes); =xprof also
-# bridges spans into profiler captures.
-_env = os.environ.get("DL4J_TPU_TRACE", "")
-_default_tracer = Tracer(enabled=bool(_env),
-                         bridge_xprof=_env.lower() == "xprof")
+# env opt-in: DL4J_TPU_TRACE=1 has the default tracer record its spans
+# from import time (the knob production pods flip without code changes)
+_default_tracer = Tracer(enabled=bool(os.environ.get("DL4J_TPU_TRACE", "")))
 _default_tracer_lock = threading.Lock()
 
 
@@ -286,3 +300,69 @@ def set_default_tracer(tracer: Tracer) -> Tracer:
     with _default_tracer_lock:
         prev, _default_tracer = _default_tracer, tracer
     return prev
+
+
+# ------------------------------------------------------- training entries
+class _CollectorWatch:
+    """Python's collector as seen from a training entry: while one is
+    open, each collection is a ``dl4j.gc`` span and adds its length to
+    ``host_gc_pause_seconds_total``.  ``gc.callbacks`` belongs
+    to the process, so entries that nest or run side by side (worker
+    threads of a master) share the one callback."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._depth = 0
+        self._open = None          # (annotation, start) of the collection
+        self._pause = None
+
+    def acquire(self) -> None:
+        with self._lock:
+            self._depth += 1
+            if self._depth > 1:
+                return
+            reg = default_registry()
+            self._pause = reg.counter(
+                "host_gc_pause_seconds_total",
+                "Seconds Python's collector held the host inside "
+                "training entries") if reg.enabled else None
+            gc.callbacks.append(self._on_collection)
+
+    def release(self) -> None:
+        with self._lock:
+            self._depth -= 1
+            if self._depth == 0:
+                gc.callbacks.remove(self._on_collection)
+                self._open = None
+
+    def _on_collection(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            annotation = jax.profiler.TraceAnnotation("dl4j.gc")
+            annotation.__enter__()
+            self._open = (annotation, monotonic_s())
+        elif self._open is not None:
+            annotation, start = self._open
+            self._open = None
+            annotation.__exit__(None, None, None)
+            if self._pause is not None:
+                self._pause.inc(monotonic_s() - start)
+
+
+_collector_watch = _CollectorWatch()
+
+
+def training_entry(name: str):
+    """Decorator for a training entry (``fit``, ``fit_on_device``): the
+    whole call is the span ``name``, and the collector is watched until
+    it returns or raises."""
+    def decorate(entry):
+        @functools.wraps(entry)
+        def traced(*args, **kwargs):
+            _collector_watch.acquire()
+            try:
+                with get_tracer().span(name):
+                    return entry(*args, **kwargs)
+            finally:
+                _collector_watch.release()
+        return traced
+    return decorate

@@ -72,9 +72,9 @@ class ProfilerListener(TrainingListener):
 def trace_annotation(name: str):
     """Label a host-side region so it shows up on the Xprof timeline
     (ETL, checkpointing, eval — the reference's StatsCalculationHelper
-    phase-timing role).  For spans that should ALSO land in the metrics
-    registry / event log, use ``observability.Tracer(bridge_xprof=True)``
-    — its spans wrap the same TraceAnnotation."""
+    phase-timing role).  ``observability.get_tracer().span(name)`` writes
+    the same annotation, and an enabled tracer also lands the span in
+    the metrics registry / event log."""
     with jax.profiler.TraceAnnotation(name):
         yield
 

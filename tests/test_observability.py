@@ -205,10 +205,14 @@ class TestTracer:
             assert sp.trace_id == ctx.trace_id
             assert sp.parent_id == ctx.span_id
 
-    def test_disabled_tracer_noop(self):
+    def test_disabled_tracer_records_nothing(self):
+        """A disabled tracer's span is the profiler's bare annotation:
+        no Span, no context, nothing kept."""
+        import jax
         t = Tracer(enabled=False)
-        with t.span("x") as sp:
-            assert sp is None
+        with t.span("x", worker=3) as sp:
+            assert isinstance(sp, jax.profiler.TraceAnnotation)
+            assert t.current_span() is None
         assert t.current_context() is None
         assert t.finished_spans == []
         # attach(None) composes silently
@@ -222,14 +226,55 @@ class TestTracer:
         s = t.finished_spans[0]
         assert s.attributes == {"worker": 3, "round": 1}
 
-    def test_xprof_bridge_path_runs(self):
-        """bridge_xprof wraps spans in jax.profiler.TraceAnnotation —
-        must work (as a no-op annotation) outside an active capture."""
-        t = Tracer(enabled=True, registry=MetricsRegistry(),
-                   bridge_xprof=True)
-        with t.span("bridged") as sp:
+    @pytest.mark.parametrize("enabled", [False, True])
+    def test_every_span_is_an_annotation(self, enabled, monkeypatch):
+        """One rule: a span is a ``jax.profiler.TraceAnnotation``, written
+        whether the tracer records or not (a no-op outside a capture);
+        an enabled tracer records the Span around the same interval."""
+        import jax
+        seen = []
+
+        class Annotation:
+            def __init__(self, name, **attributes):
+                self.name, self.attributes = name, attributes
+
+            def __enter__(self):
+                seen.append(("in", self.name, self.attributes))
+                return self
+
+            def __exit__(self, *exc):
+                seen.append(("out", self.name, self.attributes))
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        t = Tracer(enabled=enabled, registry=MetricsRegistry())
+        with t.span("outer"):
+            with t.span("inner", worker=3):
+                pass
+        assert seen == [("in", "outer", {}), ("in", "inner", {"worker": 3}),
+                        ("out", "inner", {"worker": 3}),
+                        ("out", "outer", {})]
+        names = [s.name for s in t.finished_spans]
+        assert names == (["inner", "outer"] if enabled else [])
+
+    def test_the_bridge_switches_are_gone(self):
+        """Bridging is what a span is: no ``bridge_xprof`` argument, and
+        the ``xprof`` value of DL4J_TPU_TRACE means what any value does."""
+        with pytest.raises(TypeError):
+            Tracer(enabled=True, bridge_xprof=True)
+        t = Tracer(enabled=True, registry=MetricsRegistry())
+        with t.span("outside a capture") as sp:
             assert sp is not None
         assert t.finished_spans[0].duration_s >= 0
+
+    def test_a_span_that_raises_still_closes(self):
+        t = Tracer(enabled=True, registry=MetricsRegistry())
+        with pytest.raises(KeyError):
+            with t.span("outer"):
+                with t.span("inner"):
+                    raise KeyError("x")
+        assert t.current_span() is None
+        assert [s.name for s in t.finished_spans] == ["inner", "outer"]
 
 
 class TestPerformanceListenerSteadyState:
