@@ -64,13 +64,14 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
 _ATTN_IMPLS = ("auto", "reference", "flash", "ring", "ulysses")
 
 # 'auto' crossover: flash from the kernel's minimum tile (128) upward.
-# The full-step comparison behind it (flash ahead of reference SDPA at
-# every kernel-supported length once the block sizes were swept, see
-# ops/flash_attention._auto_blocks) predates today's code and compiler;
-# on today's stack the crossover is not measured.  The env/field override
-# remains for chips where it differs.  Role mirror: the reference's
-# shape-based algorithm selection (``ConvolutionLayer.java:349``
-# CudnnAlgoMode).
+# The kernels' tiles (ops/flash_attention._auto_blocks) were swept on a
+# TPU v5e under jax 0.9.0 / libtpu 0.0.34 on 2026-10-01 (PERF.md section
+# 6, PR 28), from t=128 to t=8192 at head_dim 64; the crossover itself
+# (flash against reference SDPA in a full step) was not measured then and
+# rests on a comparison that predates today's code and compiler.  The
+# env/field override remains for chips where it differs.  Role mirror:
+# the reference's shape-based algorithm selection
+# (``ConvolutionLayer.java:349`` CudnnAlgoMode).
 DEFAULT_FLASH_MIN_SEQ = int(os.environ.get("DL4J_TPU_FLASH_MIN_SEQ", 128))
 
 

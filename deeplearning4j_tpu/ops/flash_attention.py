@@ -5,14 +5,30 @@ CudnnConvolutionHelper.java:54`` pattern: optional per-layer fast path,
 numerics-validated against the builtin fallback, cf. ``ValidateCudnnLSTM``).
 Here the builtin is ``ops.attention.sdpa_reference`` (chosen by the caller,
 never fallen back to from here) and the fast path is a tiled online-softmax
-kernel: O(t) memory instead of the O(t^2) score matrix,
-with [block_q × d] @ [d × block_k] matmuls shaped for the MXU and softmax
-statistics kept in VMEM scratch across the key-block grid dimension.
+kernel: O(t) memory instead of the O(t^2) score matrix.
 
-Grid: (batch*heads, q_blocks, k_blocks) — the last dimension iterates
-innermost and sequentially on TPU, so scratch (m, l, acc) carries the running
-softmax state across k-blocks of one q-block.  float32 accumulation
-regardless of input dtype (bfloat16 inputs stay bfloat16 in HBM/VMEM).
+Grid: (heads, blocks of queries, blocks of keys), a block up to 1024 rows;
+the last dimension iterates innermost and sequentially on TPU, so scratch
+carries the running softmax state (forward) or the gradient sums (backward)
+across the key blocks of one query block.  The dk/dv kernel swaps the
+roles — a block of keys held, queries walked — and works on the transposed
+score tile, so the saved row statistics meet it in the lane-major layout
+they are stored in.  Under ``causal`` a block wholly above the diagonal is
+neither computed nor copied (its index map repeats a live block), a block
+wholly below it is one unmasked step, and the block the diagonal enters is
+cut, when the kernel is traced, into one step a ``block_q`` rows of
+queries: all their live keys in one product, the mask on the
+``block_k``-wide tiles the diagonal crosses only, the tiles above it in no
+step.  Few large steps and not many small ones, in the body and not in the
+grid: a product of 256 x 256 scores costs about as much to start as to
+run, a grid step more (``PERF.md`` section 6, PR 28).
+
+Products take their operands in the type they arrive in and accumulate in
+float32 (Mosaic at its default precision gives float32 operands one
+bfloat16 pass all the same); ``p`` and ``dS`` are cast to the operand type
+for their second products; the softmax statistics, ``lse`` and ``D`` are
+float32 throughout.  Tiles and block size come from a sweep on a TPU v5e
+under jax 0.9.0 / libtpu 0.0.34 on 2026-10-01 (same section).
 """
 from __future__ import annotations
 
@@ -27,62 +43,177 @@ from jax.sharding import AxisType, PartitionSpec as P
 
 from .attention import NEG_INF
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
-# Block caps from a sweep on a TPU v5e at d=64 that predates today's code
-# and compiler (bigger q-blocks amortize DMA and feed the MXU
-# [block_q,d]@[d,block_k] matmuls at useful sizes; 2048x1024 at d=64 did
-# not compile then).  Caps scale down with head_dim to stay inside VMEM.
-# tests/test_chip_compile.py compiles the largest auto blocks (t=8192)
-# for the v5e on every run; the speed of these blocks on today's stack is
-# not measured.
-_BLOCK_Q_CAP = 2048 * 64
-_BLOCK_K_CAP = 512 * 64
+_LANES = 128
+# The tile that cuts the diagonal's block, swept at d=64 from t=128 to
+# t=8192, bfloat16 and float32, and at d=128, t=2048: 256 rows of queries
+# a step in the forward and dq kernels (128: 12 % slower a forward call;
+# 512: 14 % slower a dq call), 128 in the dk/dv kernel, whose tile has the
+# queries along the lanes (256: 12 % slower a call).
+_TILE = 256
+_DKV_TILE = 128
+# Rows of queries one grid step holds and of keys it fetches: the block
+# (512: 20 % slower at t=1024).  A block of scores at 1024 x 1024 float32
+# is 4 MiB; with the operands' own blocks at up to 512 bytes a row
+# (d=128 float32, d=256 bfloat16) the body fits the v5e's 16 MiB of scoped
+# VMEM, and wider rows take fewer of them (compile-only; not timed).
+_BLOCK_ROWS = 1024
+_BLOCK_ROW_BYTES = 512
 
 
 def _auto_blocks(t_q: int, t_k: int, d: int):
-    """Largest power-of-two divisors of the sequence lengths under the
-    VMEM-scaled caps — the cuDNN algo-search role
-    (``ConvolutionLayer.java:349``) resolved by sweep instead of per-call
-    search."""
-    def pick(t, cap):
+    """The score tile ``(block_q, block_k)`` for these shapes — the cuDNN
+    algo-search role (``ConvolutionLayer.java:349``) resolved by a sweep
+    instead of per-call search: ``_TILE``, or the largest power of two
+    under it that divides the sequence.  One rule for causal and not, for
+    bfloat16 and float32 and for every head_dim: the sweep found no shape
+    that wants another."""
+    def pick(t):
         if t <= 128:
             return t          # sub-tile sequences run as one block
-        b = max(128, min(t, cap // max(d, 1)))
-        # round down to a power of two, then to a divisor of t
-        b = 1 << (b.bit_length() - 1)
+        b = _TILE
         while b > 128 and t % b:
             b //= 2
         return b
-    return pick(t_q, _BLOCK_Q_CAP), pick(t_k, _BLOCK_K_CAP)
+    return pick(t_q), pick(t_k)
+
+
+def _block_rows(t_q: int, t_k: int, block_q: int, block_k: int,
+                causal: bool, row_bytes: int):
+    """``(rows of queries held, rows of keys fetched)`` a grid step: the
+    most whole tiles under ``_BLOCK_ROWS`` that divide the sequence.  Under
+    ``causal`` both are one size, so that a block the diagonal enters is
+    entered at its corner and the tiles it crosses are known when the
+    kernel is traced."""
+    cap = _BLOCK_ROWS * _BLOCK_ROW_BYTES // max(row_bytes, _BLOCK_ROW_BYTES)
+
+    def most(t, unit):
+        n = max(1, min(cap, t) // unit)
+        while (t // unit) % n:
+            n -= 1
+        return n * unit
+    if not causal:
+        return most(t_q, block_q), most(t_k, block_k)
+    unit = max(block_q, block_k)
+    rows = most(min(t_q, t_k), unit)
+    while rows > unit and (t_q % rows or t_k % rows):
+        rows -= unit
+    if t_q % rows or t_k % rows:
+        raise ValueError(
+            f"causal flash attention needs both sequence lengths divisible "
+            f"by its larger block: t_q={t_q}, t_k={t_k}, {unit}")
+    return rows, rows
 
 
 def _block_live(causal: bool, qi, ki, block_q: int, block_k: int):
     """False only for key blocks entirely above the causal diagonal —
-    shared by the forward and both backward kernels so the skip predicate
-    cannot drift between them."""
+    shared by the forward and both backward kernels, by the blocks of the
+    grid and the tiles inside them, so the skip predicate cannot drift.
+    (Blocks of the grid are one size for queries and keys under
+    ``causal``: there it reads ``ki <= qi``, which is what the index maps
+    clamp to.)"""
     if not causal:
         return True
     return qi * block_q + block_q - 1 >= ki * block_k
 
 
-def _masked_scores(q, k, qi, ki, *, scale, causal, block_q, block_k):
-    """scale·q@kᵀ with the causal mask applied — the one definition of the
-    score block used by forward and backward (replay must match exactly)."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
-    return s
+def _block_full(causal: bool, qi, ki, block_q: int, block_k: int):
+    """True for blocks entirely on or below the diagonal: every score in
+    them is live, so they take no mask."""
+    if not causal:
+        return True
+    return qi * block_q >= ki * block_k + block_k - 1
+
+
+def _diagonal_steps(rows: int, block_q: int, block_k: int):
+    """The work of a block the diagonal enters at its corner, as static
+    ``(q_start, q_size, k_start, k_size, cut)`` steps, one a query tile:
+    the keys of every live tile in one product, of which the last ``cut``
+    keys are the tiles the diagonal crosses and take the mask; key tiles
+    wholly above the diagonal are in no step."""
+    steps = []
+    for r in range(rows // block_q):
+        live = [c for c in range(rows // block_k)
+                if _block_live(True, r, c, block_q, block_k)]
+        full = [c for c in live if _block_full(True, r, c, block_q, block_k)]
+        steps.append((r * block_q, block_q, 0, len(live) * block_k,
+                      (len(live) - len(full)) * block_k))
+    return steps
+
+
+def _run_block(step, causal: bool, qi, ki, q_rows: int, k_rows: int,
+               block_q: int, block_k: int):
+    """One block of the grid: nothing where it is dead, one unmasked step
+    over all of it where it is full, the diagonal's steps where the
+    diagonal enters it.  Key blocks come in rising order and a step's keys
+    start at the block's first, so every row's first live step holds
+    key 0 and its running maximum is finite before a masked score meets
+    it."""
+    whole = (0, q_rows, 0, k_rows, 0)
+    if not causal:
+        return step(*whole)
+    live = _block_live(causal, qi, ki, q_rows, k_rows)
+    full = _block_full(causal, qi, ki, q_rows, k_rows)
+    pl.when(full)(lambda: step(*whole))
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
+    def _diagonal():
+        for s in _diagonal_steps(q_rows, block_q, block_k):
+            step(*s)
+
+
+def _scaled(x, scale: float):
+    """scale·x in x's own type: the scale rides the ``[rows, d]`` operand,
+    not every score tile.  Exact where the scale is a power of two (d=64:
+    1/8); all three kernels scale the same operand, q, so the backward
+    replays the forward's scores bit for bit."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _masked_scores(q, k, q0: int, k0: int, cut: int, *,
+                   transposed: bool = False):
+    """(scale·q)@kᵀ — q comes in scaled — with the causal mask on the last
+    ``cut`` keys, the tiles the diagonal crosses: the one definition of
+    the score tile used by forward and backward (replay must match
+    exactly).  ``q0``, ``k0`` are the tile's offsets from the corner the
+    diagonal enters at.  ``transposed`` gives k@(scale·q)ᵀ, keys down the
+    rows, for the dk/dv kernel."""
+    a, b = (k, q) if transposed else (q, k)
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if not cut:
+        return s
+    qdim, kdim = (1, 0) if transposed else (0, 1)
+    below = s.shape[kdim] - cut
+    head, tail = ((s[:below], s[below:]) if transposed
+                  else (s[:, :below], s[:, below:]))
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, tail.shape, qdim)
+    kpos = k0 + below + jax.lax.broadcasted_iota(jnp.int32, tail.shape, kdim)
+    tail = jnp.where(qpos >= kpos, tail, NEG_INF)
+    return jnp.concatenate([head, tail], axis=kdim) if below else tail
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` statistic at ``n`` lanes."""
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _column(x):
+    """A lane-major ``[1, rows]`` statistic (``lse``, ``D``) as
+    lane-replicated ``[rows, 128]``."""
+    return jnp.broadcast_to(x[0][:, None], (x.shape[1], _LANES))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                   *, scale: float, causal: bool, block_q: int, block_k: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    # grid: (heads, blocks of queries, blocks of keys)
+    qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    q_rows, d = q_ref.shape
 
     @pl.when(ki == 0)
     def _init():
@@ -90,34 +221,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(_block_live(causal, qi, ki, block_q, block_k))
-    def _step():
-        q = q_ref[0].astype(jnp.float32)            # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)            # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)            # [block_k, d]
-        s = _masked_scores(q, k, qi, ki, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k)
+    def step(q0, nq, k0, nkeys, cut):
+        rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
+        q = _scaled(q_ref[rows, :], scale)           # [nq, d]
+        v = v_ref[keys, :]                           # [nkeys, d]
+        s = _masked_scores(q, k_ref[keys, :], q0, k0, cut)
+        # m, l: lane-replicated [rows, 128], so the row statistics meet
+        # the score tile vreg for vreg with no lane broadcast
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, nkeys))
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rows, :] = alpha * l_ref[rows, :] + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_ref[rows, :] = _lanes(alpha, d) * acc_ref[rows, :] + (
+            jax.lax.dot_general(p.astype(v.dtype), v,
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32))
+        m_ref[rows, :] = m_new
 
-        m_prev = m_ref[:]                            # [block_q, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new) * (s > NEG_INF / 2)
-        alpha = jnp.exp(m_prev - m_new)              # [block_q, 1]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    _run_block(step, causal, qi, ki, q_rows, k_ref.shape[0],
+               block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = l_ref[:]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        # logsumexp per row — the backward's softmax replay key.  The lse
-        # block spans the whole row (Mosaic tiling: a (1, block_q) slice
-        # block is not expressible), so write this q-block's slice in place.
-        lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = (
-            m_ref[:] + jnp.log(l))[:, 0]
+        o_ref[...] = (acc_ref[:] / _lanes(l, d)).astype(o_ref.dtype)
+        # logsumexp per row — the backward's softmax replay key, stored
+        # lane-major: [1, rows] of a (heads, 1, t_q) array
+        lse_ref[...] = (m_ref[:] + jnp.log(l))[:, 0][None, :]
 
 
 def _like(x, shape=None, dtype=None):
@@ -127,30 +259,42 @@ def _like(x, shape=None, dtype=None):
                                 dtype or x.dtype, vma=jax.typeof(x).vma)
 
 
+def _specs(d: int, held: int, walked: int, clamp=None):
+    """Block specs of a kernel whose grid is (heads, held blocks, walked
+    blocks): the held operand, the walked operand — under ``causal`` a
+    walked block wholly above the diagonal repeats the live one next to
+    it (``clamp``: ``jnp.minimum`` where keys are walked, ``jnp.maximum``
+    where queries are), so it is not copied — and each one's slice of the
+    lane-major row statistics."""
+    def live(h, w):
+        return clamp(w, h) if clamp else w
+    return (pl.BlockSpec((None, held, d), lambda b, h, w: (b, h, 0)),
+            pl.BlockSpec((None, walked, d),
+                         lambda b, h, w: (b, live(h, w), 0)),
+            pl.BlockSpec((None, 1, held), lambda b, h, w: (b, 0, h)),
+            pl.BlockSpec((None, 1, walked),
+                         lambda b, h, w: (b, 0, live(h, w))))
+
+
 def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
-    grid = (bh, t_q // block_q, t_k // block_k)
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
+    q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
+                                 d * qr.dtype.itemsize)
+    q_spec, k_spec, row_spec, _ = _specs(
+        d, q_rows, k_rows, jnp.minimum if causal else None)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, t_q), lambda bh, qi, ki: (bh, 0, 0)),
-        ],
+        functools.partial(_flash_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(bh, t_q // q_rows, t_k // k_rows),
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[_like(qr, (bh, t_q, d)),
                    _like(qr, (bh, 1, t_q), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((q_rows, d), jnp.float32),
+            pltpu.VMEM((q_rows, _LANES), jnp.float32),
+            pltpu.VMEM((q_rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -158,29 +302,12 @@ def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
     return out, lse
 
 
-def _replay_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, qi, ki, *,
-                 scale, causal, block_q, block_k):
-    """Shared backward-step math: recompute the softmax block P from the
-    saved logsumexp and form dS = P∘(dP − D)·scale (FlashAttention-2 bwd).
-    lse/dd refs span the whole row; this q-block's slice is loaded here."""
-    q = q_ref[0].astype(jnp.float32)                # [block_q, d]
-    k = k_ref[0].astype(jnp.float32)                # [block_k, d]
-    v = v_ref[0].astype(jnp.float32)                # [block_k, d]
-    do = do_ref[0].astype(jnp.float32)              # [block_q, d]
-    lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]
-    dd = dd_ref[0, 0, pl.ds(qi * block_q, block_q)]
-    s = _masked_scores(q, k, qi, ki, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k)
-    p = jnp.exp(s - lse[:, None]) * (s > NEG_INF / 2)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - dd[:, None]) * scale
-    return q, k, do, p, ds
-
-
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                          dq_ref, dq_acc, *, scale, causal,
                          block_q, block_k):
+    """dq of one block of queries: replay P from the saved logsumexp, form
+    dS = P∘(dP − D) (FlashAttention-2 bwd) and add dS·k over the keys;
+    the scale meets the ``[rows, d]`` sum once at the end."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -188,24 +315,37 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_block_live(causal, qi, ki, block_q, block_k))
-    def _step():
-        _, k, _, _, ds = _replay_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, qi, ki,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+    def step(q0, nq, k0, nkeys, cut):
+        rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
+        q = _scaled(q_ref[rows, :], scale)           # [nq, d]
+        k = k_ref[keys, :]                           # [nkeys, d]
+        lse = _column(lse_ref[:, rows])              # [nq, 128]
+        dd = _column(dd_ref[:, rows])
+        s = _masked_scores(q, k, q0, k0, cut)
+        p = jnp.exp(s - _lanes(lse, nkeys))
+        dp = jax.lax.dot_general(do_ref[rows, :], v_ref[keys, :],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(dd, nkeys))
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
+               block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _done():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
                           block_q, block_k):
-    # grid: (bh, k_blocks, q_blocks) — q innermost so dk/dv accumulate
+    """dk, dv of one block of keys, on the transposed score tile (keys
+    down the rows, queries along the lanes): lse and D are used as the
+    lane-major rows they are stored as, and Pᵀ·dO, dSᵀ·q are plain
+    products.  grid: (heads, blocks of keys, blocks of queries)."""
     ki, qi = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -214,22 +354,31 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_live(causal, qi, ki, block_q, block_k))
-    def _step():
-        q, _, do, p, ds = _replay_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, qi, ki,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+    def step(q0, nqs, k0, nkeys, cut):
+        rows, keys = pl.ds(q0, nqs), pl.ds(k0, nkeys)
+        q = _scaled(q_ref[rows, :], scale)           # [nqs, d]
+        do = do_ref[rows, :]
+        st = _masked_scores(q, k_ref[keys, :], q0, k0, cut,
+                            transposed=True)         # [nkeys, nqs]
+        pt = jnp.exp(st - lse_ref[:, rows])
+        dv_acc[keys, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+        dpt = jax.lax.dot_general(v_ref[keys, :], do,
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dd_ref[:, rows])
+        dk_acc[keys, :] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
+               block_q, block_k)
 
     @pl.when(qi == nq - 1)
     def _done():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -249,34 +398,39 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
                        block_k, interpret):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, t_q), lambda bh, qi, ki: (bh, 0, 0))
+    q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
+                                 d * qr.dtype.itemsize)
+    q_spec, k_spec, row_spec, _ = _specs(
+        d, q_rows, k_rows, jnp.minimum if causal else None)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        grid=(bh, t_q // block_q, t_k // block_k),
+        grid=(bh, t_q // q_rows, t_k // k_rows),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_like(qr),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((q_rows, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(qr, kr, vr, do, lse, dd)
 
-    # swapped grid: k outer, q inner (sequential) so dk/dv carry in scratch
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    row_spec2 = pl.BlockSpec((1, 1, t_q), lambda bh, ki, qi: (bh, 0, 0))
+    # swapped roles: a block of keys held, queries walked, so dk/dv carry
+    # in scratch; a block of queries wholly above the diagonal repeats the
+    # first live one.  Its tile is the finer _DKV_TILE where that divides
+    # the caller's.
+    block_q, block_k = (_DKV_TILE if b % _DKV_TILE == 0 else b
+                        for b in (block_q, block_k))
+    k_spec2, q_spec2, _, row_spec2 = _specs(
+        d, k_rows, q_rows, jnp.maximum if causal else None)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        grid=(bh, t_k // block_k, t_q // block_q),
+        grid=(bh, t_k // k_rows, t_q // q_rows),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[k_spec2, k_spec2],
         out_shape=[_like(kr), _like(vr)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k_rows, d), jnp.float32),
+                        pltpu.VMEM((k_rows, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, do, lse, dd)
@@ -303,10 +457,11 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 def flash_blocks(t_q: int, t_k: int, d: int,
                  block_q: Optional[int] = None,
                  block_k: Optional[int] = None):
-    """``(block_q, block_k)`` the kernel runs these shapes with.  Raises
-    ``ValueError`` naming the reason when it cannot tile them — the one
-    support check, shared by ``flash_attention`` (which raises) and
-    ``attn_impl='auto'`` (which then chooses the reference path)."""
+    """``(block_q, block_k)``, the score tile the kernels run these shapes
+    with.  Raises ``ValueError`` naming the reason when it cannot tile
+    them — the one support check, shared by ``flash_attention`` (which
+    raises) and ``attn_impl='auto'`` (which then chooses the reference
+    path)."""
     auto_q, auto_k = _auto_blocks(t_q, t_k, d)
     block_q = min(block_q, t_q) if block_q else auto_q
     block_k = min(block_k, t_k) if block_k else auto_k
@@ -318,6 +473,10 @@ def flash_blocks(t_q: int, t_k: int, d: int,
         raise ValueError(
             f"flash attention needs sequence lengths divisible by its "
             f"blocks: t_q={t_q} % {block_q}, t_k={t_k} % {block_k}")
+    if max(block_q, block_k) % min(block_q, block_k):
+        raise ValueError(
+            f"flash attention needs one of its blocks divisible by the "
+            f"other: {block_q}, {block_k}")
     return block_q, block_k
 
 
@@ -371,6 +530,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     mesh, spec = _kernel_partitioning(q)
     if mesh is not None:
+        # the Pallas interpreter evaluates the kernel body's own equations
+        # under the varying-axes check, which refuses a varying tile times
+        # a constant; a compiled kernel is one opaque call to that check
         run = jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec)
+                            out_specs=spec, check_vma=not interpret)
     return run(q, k, v)

@@ -97,6 +97,131 @@ def test_flash_attention_gradients_match_reference(causal):
                                    err_msg=f"d{name}")
 
 
+def _rel_err(got, want):
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want))
+                 / np.max(np.abs(want)))
+
+
+# tilings the 64 x 64 cases above do not reach: a q tile spanning several
+# key tiles (one block sees skipped, unmasked and diagonal tiles), key
+# tiles wider than the q tile, t_q != t_k, and blocks smaller than the
+# sequence (the grid's own skip, and the index maps that keep dead blocks
+# from being copied)
+_BF16_TILINGS = [
+    (256, 256, 128, 64, None, "q-spans-k"),
+    (256, 256, 64, 128, None, "k-wider"),
+    (256, 256, 256, 64, None, "one-q-tile"),
+    (128, 256, 64, 128, None, "tq-ne-tk"),
+    (256, 256, 64, 64, 64, "tile-a-block"),
+    (512, 512, 128, 64, 256, "four-blocks"),
+]
+
+
+@pytest.mark.parametrize(
+    "causal,t_q,t_k,block_q,block_k,block_rows",
+    [pytest.param(causal, *case[:5],
+                  id=f"{case[5]}-{'causal' if causal else 'full'}")
+     for case in _BF16_TILINGS for causal in (False, True)
+     if not causal or case[0] == case[1]])   # no caller: causal, t_q != t_k
+def test_flash_attention_bf16_matches_float32_reference(
+        causal, t_q, t_k, block_q, block_k, block_rows, monkeypatch):
+    """bf16 operands go to the MXU as they arrive, ``p`` and ``dS`` are
+    rounded to bf16 for their second products: forward and all three
+    gradients stay within bf16 rounding of ``sdpa_reference`` evaluated in
+    float32 on the same bf16 inputs."""
+    from deeplearning4j_tpu.ops import flash_attention as F
+    if block_rows:
+        monkeypatch.setattr(F, "_BLOCK_ROWS", block_rows)
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.standard_normal((1, 2, t_q, 64)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, t_k, 64)), jnp.bfloat16)
+            for _ in range(2))
+    do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def f(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * do), out
+
+    def r(q, k, v):
+        out = sdpa_reference(*(a.astype(jnp.float32) for a in (q, k, v)),
+                             causal=causal)
+        return jnp.sum(out * do), out
+
+    gf, out = jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    gr, ref = jax.grad(r, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    assert _rel_err(out, ref) < 1e-2
+    for name, a, b in zip("qkv", gf, gr):
+        assert a.dtype == jnp.bfloat16
+        assert _rel_err(a, b) < 1.5e-2, f"d{name}"
+
+
+def test_causal_skip_is_real_and_shared_by_the_three_kernels():
+    """For the tiles the cell's shape runs under ``causal``: fewer tiles
+    are live than the square holds (the parent's 1024 x 512 read exactly
+    1), and with every key/value row of the tiles wholly above a query
+    tile's diagonal set to NaN the forward and dq of that query tile, and
+    with every query/dO row of the tiles wholly above a key tile's
+    diagonal poisoned the dk/dv of that key tile, are finite and equal to
+    the unpoisoned run: dead tiles are neither masked to zero nor read."""
+    from deeplearning4j_tpu.ops import flash_attention as F
+    t, d = 1024, 64
+    bq, bk = F.flash_blocks(t, t, d)
+    live = [bool(F._block_live(True, qi, ki, bq, bk))
+            for qi in range(t // bq) for ki in range(t // bk)]
+    assert sum(live) / len(live) < 1
+    assert all(F._block_live(True, qi, ki, 1024, 512)
+               for qi in range(1) for ki in range(2))
+
+    rng = np.random.default_rng(2)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((1, t, d)), jnp.bfloat16)
+                   for _ in range(4))
+    scale = d ** -0.5
+    out, lse = F._launch_fwd(q, k, v, scale, True, bq, bk, True)
+    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                 axis=-1)[:, None, :]
+    dq, dk, dv = F._launch_bwd(q, k, v, do, lse, dd, scale, True, bq, bk,
+                               True)
+
+    def same(got, want, rows):
+        got, want = (np.asarray(a[:, rows].astype(jnp.float32))
+                     for a in (got, want))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, want)
+
+    def first_dead_key(qi):      # keys from here on: wholly above qi's rows
+        dead = [c for c in range(t // bk)
+                if not F._block_live(True, qi, c, bq, bk)]
+        return min(dead or [t // bk]) * bk
+
+    def first_live_query(ki):    # queries before here: wholly above ki's keys
+        return min(r for r in range(t // bq)
+                   if F._block_live(True, r, ki, bq, bk)) * bq
+
+    q_tiles = [qi for qi in range(t // bq) if first_dead_key(qi) < t]
+    k_tiles = [ki for ki in range(t // bk) if first_live_query(ki) > 0]
+    assert q_tiles and k_tiles
+    for qi in q_tiles[::2]:
+        dead = first_dead_key(qi)
+        kp, vp = (a.at[:, dead:].set(jnp.nan) for a in (k, v))
+        rows = slice(qi * bq, (qi + 1) * bq)
+        same(F._launch_fwd(q, kp, vp, scale, True, bq, bk, True)[0], out,
+             rows)
+        same(F._launch_bwd(q, kp, vp, do, lse, dd, scale, True, bq, bk,
+                           True)[0], dq, rows)
+    for ki in k_tiles[::-2]:
+        dead = first_live_query(ki)
+        qp, dop = (a.at[:, :dead].set(jnp.nan) for a in (q, do))
+        lsep, ddp = (a.at[:, :, :dead].set(jnp.nan) for a in (lse, dd))
+        got = F._launch_bwd(qp, k, v, dop, lsep, ddp, scale, True, bq, bk,
+                            True)
+        rows = slice(ki * bk, (ki + 1) * bk)
+        same(got[1], dk, rows)
+        same(got[2], dv, rows)
+
+
 @pytest.mark.parametrize("t,d,why", [(7, 5, "head_dim"),
                                      (192, 64, "divisible")])
 def test_flash_attention_refuses_shapes_it_cannot_tile(t, d, why):

@@ -73,9 +73,16 @@ def _flash_rows(q, k, v, t):
     return F._flash(q, k, v, HEAD_DIM ** -0.5, True, bq, bk, False)
 
 
-# t=1024 is chip_smoke.py's LM; t=8192 takes the largest auto blocks
-# (2048x512), one step under the old "2048x1024 does not compile"
-@pytest.mark.parametrize("t,rows", [(1024, 96), (8192, 12)])
+# The causal auto tiles (ops/flash_attention._auto_blocks, swept on a v5e
+# under jax 0.9.0 / libtpu 0.0.34, PERF.md section 6, PR 28).  t=1024 at 96
+# rows is chip_smoke.py's LM; at 48 rows it is the benchmark cell
+# gpt2-medium.train-fit's own call (3 rows x 16 heads), so the tiles the
+# benchmark runs are compiled for the v5e here; t=8192 fetches the longest
+# block of keys one grid step holds (8192 x 64 bf16, 1 MiB a copy).
+_FLASH_SHAPES = [(1024, 96), (8192, 12), (1024, 48)]
+
+
+@pytest.mark.parametrize("t,rows", _FLASH_SHAPES)
 def test_flash_forward_compiles_for_v5e(one_chip, t, rows):
     q, k, v = _qkv(one_chip, rows, t)
     c = jax.jit(lambda q, k, v: _flash_rows(q, k, v, t)).lower(
@@ -83,7 +90,7 @@ def test_flash_forward_compiles_for_v5e(one_chip, t, rows):
     assert _custom_calls(c) == 1
 
 
-@pytest.mark.parametrize("t,rows", [(1024, 96), (8192, 12)])
+@pytest.mark.parametrize("t,rows", _FLASH_SHAPES)
 def test_flash_backward_dq_and_dkv_compile_for_v5e(one_chip, t, rows):
     """Both backward kernels (dq; dk/dv) beside the forward replay."""
     q, k, v = _qkv(one_chip, rows, t)
