@@ -1,13 +1,13 @@
 """Model zoo (reference ``deeplearning4j-zoo``) + bench/flagship selection."""
 import numpy as np
 
-from .zoo import (ALL_MODELS, AlexNet, FaceNetNN4Small2, GoogLeNet,
+from .zoo import (ALL_MODELS, AlexNet, EvaByteLM, FaceNetNN4Small2, GoogLeNet,
                   InceptionResNetV1, LeNet, ResNet50, SimpleCNN,
                   ModelSelector, TextGenerationLSTM, TransformerLM, VGG16,
                   VGG19, ZooModel)
 
 __all__ = [
-    "ALL_MODELS", "AlexNet", "FaceNetNN4Small2", "GoogLeNet",
+    "ALL_MODELS", "AlexNet", "EvaByteLM", "FaceNetNN4Small2", "GoogLeNet",
     "InceptionResNetV1", "LeNet", "ResNet50", "SimpleCNN",
     "ModelSelector", "TextGenerationLSTM", "TransformerLM", "VGG16",
     "VGG19", "ZooModel",

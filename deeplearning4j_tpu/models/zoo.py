@@ -612,6 +612,88 @@ class TransformerLM(ZooModel):
 ALL_MODELS.append(TransformerLM)
 
 
+@dataclass
+class EvaByteLM(ZooModel):
+    """EvaByte's architecture (https://huggingface.co/EvaByte/EvaByte,
+    ``model_type`` ``evabyte``): a byte-level decoder of bias-free pre-norm
+    blocks — RMSNorm with a unit offset, rotary positions, a gated SiLU
+    MLP, EVA chunked linearized attention (``nn/layers/attention.
+    _eva_attention``) — a final RMSNorm and an untied head that predicts
+    the next ``pred_heads`` bytes at every position.  Integer targets
+    ``[b, t, pred_heads]`` (head ``n`` at ``t``: byte ``t + 1 + n``) with a
+    label mask of the same shape that drops the targets past the sequence's
+    end; the loss is their mean cross-entropy.  Under a lower
+    ``compute_dtype`` the residual stream between the blocks and the final
+    norm stay float32 (the model's ``fp32_skip_add``); the projections, the
+    attention and the head compute in ``compute_dtype``.  The defaults are
+    the published 6.5 B model's."""
+    model_type = "rnn"
+    vocab_size: int = 320
+    seq_len: int = 32768
+    embed: int = 4096
+    n_layers: int = 32
+    n_heads: int = 32
+    head_dim: int = 128
+    ffn_hidden: int = 11008
+    pred_heads: int = 8
+    window: int = 2048
+    chunk: int = 16
+    rope_theta: float = 1e5
+    eps: float = 1e-5
+    attn_impl: str = "auto"
+    cache_mode: str = "none"   # 'remat': recompute each block in backward
+
+    def init(self):
+        from ..nn.layers.attention import RMSNormLayer, TransformerBlock
+        from ..nn.layers.feedforward import EmbeddingSequenceLayer
+        from ..nn.precision import PrecisionPolicy
+        b = self._builder()
+        if self.compute_dtype:
+            # the final norm reads the float32 stream as the blocks do
+            b = b.precision(PrecisionPolicy(
+                compute_dtype=self.compute_dtype,
+                keep_f32=("BatchNormalization", "RMSNormLayer")))
+        b = (b.updater(self.updater or Adam(learning_rate=3e-4))
+             .weight_init("xavier")
+             .cache_mode(self.cache_mode)
+             .list()
+             .layer(EmbeddingSequenceLayer(n_out=self.embed)))
+        for _ in range(self.n_layers):
+            b = b.layer(TransformerBlock(
+                n_heads=self.n_heads, head_dim=self.head_dim, causal=True,
+                attn_impl=self.attn_impl, eps=self.eps, norm="rms",
+                positions="rotary", rope_theta=self.rope_theta,
+                ffn_hidden=self.ffn_hidden, gated=True, has_bias=False,
+                attention="eva", window=self.window, chunk=self.chunk,
+                residual_dtype="float32"))
+        conf = (b.layer(RMSNormLayer(eps=self.eps))
+                .layer(RnnOutputLayer(
+                    n_out=self.pred_heads * self.vocab_size,
+                    has_bias=False, activation="softmax",
+                    loss="sparse_mcxent", pred_heads=self.pred_heads))
+                .set_input_type(InputType.recurrent(self.vocab_size,
+                                                    self.seq_len))
+                .build())
+        from ..nn.multilayer import MultiLayerNetwork
+        return MultiLayerNetwork(conf).init()
+
+    @staticmethod
+    def targets(ids, pred_heads: int = 8):
+        """``(x, y, None, label_mask)`` for ``fit`` from byte ids ``[b, t]``:
+        ``y[b, t, n] = ids[b, t + 1 + n]`` and the mask 1 where that byte
+        exists."""
+        import numpy as np
+        ids = np.asarray(ids)
+        t = ids.shape[1]
+        at = np.arange(t)[:, None] + 1 + np.arange(pred_heads)[None, :]
+        mask = (at < t).astype(np.float32)
+        y = ids[:, np.minimum(at, t - 1)].astype(np.int32)
+        return ids, y, None, np.broadcast_to(mask, y.shape)
+
+
+ALL_MODELS.append(EvaByteLM)
+
+
 class ModelSelector:
     """Select zoo models by name/type (reference
     ``deeplearning4j-zoo/.../ModelSelector.java``: select(ZooType) returns a

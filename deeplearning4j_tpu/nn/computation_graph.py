@@ -85,7 +85,7 @@ def _graph_forward(conf, params, state, inputs: List[Array], *, train: bool,
             ms = [mask_of.get(mi)] + ms[1:]
         lkey = jax.random.fold_in(key, vi) if key is not None else None
         if precision is not None:
-            vdt = precision.layer_dtype(getattr(v, "layer", None) or v)
+            vdt = precision.input_dtype(getattr(v, "layer", None) or v)
             xs = [_cast_act(x, vdt) for x in xs]
         variables = {"params": params.get(name, {}),
                      "state": state.get(name, {})}

@@ -1,6 +1,7 @@
 """Layer configs/implementations (reference ``nn/conf/layers`` + ``nn/layers``)."""
 from .attention import (LayerNormLayer, MultiHeadAttention,
-                        PositionalEncodingLayer, TransformerBlock)
+                        PositionalEncodingLayer, RMSNormLayer,
+                        TransformerBlock)
 from .base import BaseLayerConf, LayerConf
 from .convolution import (Convolution1DLayer, ConvolutionLayer,
                           Subsampling1DLayer, SubsamplingLayer, Upsampling1D,
@@ -26,7 +27,7 @@ __all__ = [
     "GravesLSTM", "LastTimeStep", "LayerConf", "LayerNormLayer",
     "LocalResponseNormalization", "LossLayer", "LSTM",
     "MixtureOfExpertsLayer", "MultiHeadAttention",
-    "OutputLayer", "PositionalEncodingLayer", "RBM", "RnnOutputLayer",
+    "OutputLayer", "PositionalEncodingLayer", "RBM", "RMSNormLayer", "RnnOutputLayer",
     "SimpleRnn", "TransformerBlock",
     "Subsampling1DLayer", "SubsamplingLayer", "Upsampling1D", "Upsampling2D",
     "VariationalAutoencoder", "Yolo2OutputLayer", "ZeroPaddingLayer",

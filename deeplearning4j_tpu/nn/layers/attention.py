@@ -1,4 +1,5 @@
-"""Attention layer family: LayerNorm, MultiHeadAttention, TransformerBlock.
+"""Attention layer family: LayerNorm, RMSNorm, MultiHeadAttention,
+TransformerBlock.
 
 No counterpart in the reference (pre-transformer, SURVEY.md §5) — this is the
 long-context capability the TPU build adds as first-class.  The layers follow
@@ -15,6 +16,13 @@ Attention impl tiers (select with ``attn_impl``):
                 sequences of at least ``DEFAULT_FLASH_MIN_SEQ`` tokens the
                 kernel can tile, reference otherwise — the
                 ``CudnnAlgoMode`` role.
+
+Attention kinds (select with ``attention``): ``'full'``, every key (under
+``causal`` every earlier key), and ``'eva'``, EVA chunked linearized
+attention (``_eva_attention``): exact causal attention inside a window,
+learned summaries of key chunks for everything before it.  Both reach the
+flash kernels; any other key set (a key-padding mask) runs
+``sdpa_reference`` under 'auto' and is refused by 'flash'.
 """
 from __future__ import annotations
 
@@ -61,6 +69,40 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
     return (x - mu) * jax.lax.rsqrt(var + eps) * gamma + beta
 
 
+def _rms_norm(x, gain, eps=1e-5):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + gain)``: RMSNorm with a unit
+    offset (a zero gain is the identity scale); the mean of squares in at
+    least float32, the result in x's type."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * (1.0 + gain.astype(y.dtype))).astype(x.dtype)
+
+
+@register_serde
+@dataclass
+class RMSNormLayer(BaseLayerConf):
+    """Root-mean-square normalization over the feature axis with a
+    learned gain stored as an offset from one (``_rms_norm``)."""
+    _BIAS_PARAMS = ("gain",)
+    n_out: int = 0
+    eps: float = 1e-5
+
+    def set_n_in(self, itype: InputType, override: bool = False) -> None:
+        if self.n_out == 0 or override:
+            self.n_out = itype.size
+
+    def output_type(self, itype: InputType) -> InputType:
+        return itype
+
+    def init(self, key, itype):
+        return {"params": {"gain": jnp.zeros((self.n_out,), self._dtype())},
+                "state": {}}
+
+    def apply(self, variables, x, *, train=False, key=None, mask=None):
+        return (_rms_norm(x, variables["params"]["gain"], self.eps),
+                variables.get("state", {}))
+
+
 _ATTN_IMPLS = ("auto", "reference", "flash", "ring", "ulysses")
 
 # 'auto' crossover: flash from the kernel's minimum tile (128) upward.
@@ -82,7 +124,14 @@ def auto_attention_impl(t_q: int, t_k: int, d: int, *, masked: bool,
     above the crossover (``flash_min_seq``, default
     ``DEFAULT_FLASH_MIN_SEQ``) and the kernel can tile it; else
     ``'reference'``.  The one place the choice is made, so a caller can
-    ask what was chosen instead of guessing."""
+    ask what was chosen instead of guessing.
+
+    Two attention kinds reach the kernels: full attention, causal or not,
+    at the sequence's own length, and EVA attention, whose windows are
+    causal calls at the window's length (the shapes to ask about are then
+    the window's).  A key-padding mask, or any other key set, is
+    ``masked``: 'auto' runs the O(t^2) reference for it and an explicit
+    ``'flash'`` raises — it refuses, it never falls back."""
     threshold = (DEFAULT_FLASH_MIN_SEQ if flash_min_seq is None
                  else flash_min_seq)
     if masked or t_q < threshold or jax.default_backend() != "tpu":
@@ -126,6 +175,111 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
     return sdpa_reference(q, k, v, mask=mask, causal=causal)
 
 
+def _rotary(x, theta: float):
+    """Rotary positions on ``[b, h, t, d]``: rotate-half pairing (feature
+    ``i`` with ``i + d/2``), absolute positions ``0..t-1``, angles in
+    float32, the result in x's type.  (One table shared by q and k reads
+    slower on the v5e than one each, fused into its user: PERF.md, PR 29.)"""
+    t, d = x.shape[2], x.shape[3]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
+    return (x * cos + half * sin).astype(x.dtype)
+
+
+def _eva_attention(q, k, v, phi, mu, *, window: int, chunk: int, impl: str,
+                   flash_min_seq: Optional[int] = None):
+    """EVA chunked linearized attention (Zheng et al., ICLR 2023,
+    arXiv:2302.04542, in the deterministic form of EvaByte's released
+    reference) over ``[b, h, t, d]``; ``phi``, ``mu`` are ``[h, d]``.
+
+    Every ``chunk`` keys are pooled into one summary key and value,
+    ``ks_c = sum_j a_j k_j + mu``, ``vs_c = sum_j a_j v_j`` with ``a =
+    softmax_j(k_j . phi)``.  Query ``i`` of window ``w = i // window``
+    attends, in ONE softmax, to the keys ``j <= i`` of its own window and
+    to the summaries of every chunk of the windows before it.  The window
+    part is plain causal attention with the windows as rows of the batch
+    (the flash kernels as they are, handing back their log-sum-exp); the
+    summary part is a product of a window's queries with the ``w * window
+    / chunk`` summaries before it; the two partial softmaxes merge by
+    ``ops.attention.combine_blocks``.  The last window may be short."""
+    from ...ops.attention import attn_block, combine_blocks, finalize_blocks
+    b, h, t, d = q.shape
+    if window % chunk or t % chunk:
+        raise ValueError(f"eva attention needs the window ({window}) and "
+                         f"the sequence ({t}) to be whole chunks of {chunk}")
+    acc_dt = jnp.promote_types(q.dtype, jnp.float32)
+    if impl == "auto":
+        impl = auto_attention_impl(window, window, d, masked=False,
+                                   flash_min_seq=flash_min_seq)
+    if impl not in ("flash", "reference"):
+        raise ValueError(f"eva attention runs 'flash' or 'reference', "
+                         f"not attn_impl='{impl}'")
+
+    with jax.named_scope("eva_pool"):
+        kc = k.reshape(b, h, t // chunk, chunk, d)
+        vc = v.reshape(b, h, t // chunk, chunk, d)
+        a = jax.nn.softmax(jnp.einsum(
+            "bhncd,hd->bhnc", kc, phi.astype(k.dtype),
+            preferred_element_type=acc_dt), axis=-1).astype(k.dtype)
+        ks = (jnp.einsum("bhnc,bhncd->bhnd", a, kc,
+                         preferred_element_type=acc_dt)
+              + mu.astype(acc_dt)[None, :, None, :]).astype(k.dtype)
+        vs = jnp.einsum("bhnc,bhncd->bhnd", a, vc,
+                        preferred_element_type=acc_dt).astype(v.dtype)
+
+    def windows(x, start, stop, size):
+        """Positions ``start..stop`` as rows of ``size``: [b, h*n, size, d]"""
+        return x[:, :, start:stop].reshape(b, -1, size, d)
+
+    def local(start, stop, size):
+        """(acc, m, l) of causal attention inside windows of ``size``."""
+        qw, kw, vw = (windows(x, start, stop, size) for x in (q, k, v))
+        if impl == "flash":
+            from ...ops.flash_attention import flash_attention
+            o, lse = flash_attention(qw, kw, vw, causal=True,
+                                     return_lse=True)
+            part = o.astype(acc_dt), lse, jnp.ones_like(lse)
+        else:
+            part = attn_block(qw, kw, vw, causal=True)
+        return tuple(x.reshape(b, h, stop - start, *x.shape[3:])
+                     for x in part)
+
+    whole = (t // window) * window
+    with jax.named_scope("eva_window"):
+        parts = [local(0, whole, window)] if whole else []
+        if t > whole:
+            parts.append(local(whole, t, t - whole))
+        acc, m, l = (jnp.concatenate(x, axis=2) if len(x) > 1 else x[0]
+                     for x in zip(*parts))
+
+    def summaries(rows, n):
+        """(acc, m, l) of ``rows``' queries over the first ``n`` summaries:
+        scores and sums in float32, p in the operands' type for its
+        product, as the kernels do."""
+        s = jnp.einsum("bhqd,bhkd->bhqk", q[:, :, rows], ks[:, :, :n],
+                       preferred_element_type=acc_dt) * d ** -0.5
+        m_s = jnp.max(s, axis=-1)
+        p = jnp.exp(s - m_s[..., None])
+        return (jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype),
+                           vs[:, :, :n], preferred_element_type=acc_dt),
+                m_s, jnp.sum(p, axis=-1))
+
+    out = [finalize_blocks(acc[:, :, :window], m[:, :, :window],
+                           l[:, :, :window], q.dtype)]
+    for start in range(window, t, window):
+        rows = slice(start, min(start + window, t))
+        with jax.named_scope("eva_summary"):
+            summary = summaries(rows, start // chunk)
+        with jax.named_scope("eva_merge"):
+            out.append(finalize_blocks(*combine_blocks(
+                acc[:, :, rows], m[:, :, rows], l[:, :, rows], *summary),
+                q.dtype))
+    return jnp.concatenate(out, axis=2) if len(out) > 1 else out[0]
+
+
 def _kv_quantize(x):
     """Per-(row, head) absmax int8 quantization of a ``[..., d]`` K/V
     write: returns (q int8, scale f32 ``[...]``) with q*scale ≈ x."""
@@ -143,6 +297,16 @@ class MultiHeadAttention(BaseLayerConf):
     Projections pack all heads into single [n_in, h*d] matmuls (MXU-shaped);
     softmax statistics run in at least float32 even under bfloat16 params.
 
+    ``positions='rotary'`` turns q and k by their absolute position
+    (``_rotary``, base ``rope_theta``).  ``attention`` chooses the key set:
+    ``'full'`` or ``'eva'`` (``_eva_attention``: ``window``, ``chunk``, and
+    two learned ``[h, d]`` leaves, ``phi`` and ``mu``; causal only).  Both
+    kinds reach the flash kernels under ``attn_impl`` 'auto' or 'flash'; a
+    key-padding mask sends 'auto' to the reference and makes 'flash' raise
+    (it refuses, it never falls back), and 'eva' takes none.  Rotary
+    positions and EVA attention train and run forward; the KV-cache path
+    (``attend_cached``) refuses them.
+
     HAS_CARRY: the carry is a KV cache ({k, v, pos}, capacity
     ``max_cache_len``) enabling incremental decoding through
     ``rnn_time_step`` — the attention-era face of the reference's stateful
@@ -151,7 +315,7 @@ class MultiHeadAttention(BaseLayerConf):
     """
     INPUT_KIND = "rnn"
     HAS_CARRY = True
-    _BIAS_PARAMS = ("bq", "bk", "bv", "bo")
+    _BIAS_PARAMS = ("bq", "bk", "bv", "bo", "phi", "mu")
 
     n_in: int = 0
     n_out: int = 0              # model/embed dim of the output projection
@@ -166,6 +330,11 @@ class MultiHeadAttention(BaseLayerConf):
     has_bias: bool = True
     attn_dropout: Optional[float] = None   # retain prob on attention output
     max_cache_len: int = 512    # KV-cache capacity for incremental decode
+    positions: str = "none"     # none|rotary
+    rope_theta: float = 10000.0
+    attention: str = "full"     # full|eva
+    window: int = 0             # eva: keys attended exactly
+    chunk: int = 0              # eva: keys pooled into one summary
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:
@@ -197,6 +366,22 @@ class MultiHeadAttention(BaseLayerConf):
                           bk=self.make_bias((h * d,)),
                           bv=self.make_bias((h * d,)),
                           bo=self.make_bias((self.n_out,)))
+        if self.attention == "eva":
+            if not (self.causal and self.window and self.chunk):
+                raise ValueError(
+                    f"layer '{self.name}': attention='eva' is causal and "
+                    "needs a window and a chunk")
+            # normal, clipped to [-1, 1], times d^-1/2
+            for name, k_ in zip(("phi", "mu"),
+                                jax.random.split(jax.random.fold_in(key, 4))):
+                params[name] = (jnp.clip(jax.random.normal(
+                    k_, (h, d), self._dtype()), -1.0, 1.0) * d ** -0.5)
+        elif self.attention != "full":
+            raise ValueError(f"layer '{self.name}': unknown attention "
+                             f"'{self.attention}'; expected full or eva")
+        if self.positions not in ("none", "rotary"):
+            raise ValueError(f"layer '{self.name}': unknown positions "
+                             f"'{self.positions}'; expected none or rotary")
         return {"params": params, "state": {}}
 
     def _heads(self, x, p, w, b):
@@ -212,9 +397,20 @@ class MultiHeadAttention(BaseLayerConf):
         q = self._heads(x, p, "Wq", "bq")
         k = self._heads(x, p, "Wk", "bk")
         v = self._heads(x, p, "Wv", "bv")
-        o = _run_attention(q, k, v, impl=self.attn_impl, causal=self.causal,
-                           mask=mask, seq_axis=self.seq_axis,
-                           flash_min_seq=self.flash_min_seq)
+        if self.positions == "rotary":
+            q, k = _rotary(q, self.rope_theta), _rotary(k, self.rope_theta)
+        if self.attention == "eva":
+            if mask is not None:
+                raise ValueError("attention='eva' takes no key-padding mask")
+            o = _eva_attention(q, k, v, p["phi"], p["mu"],
+                               window=self.window, chunk=self.chunk,
+                               impl=self.attn_impl,
+                               flash_min_seq=self.flash_min_seq)
+        else:
+            o = _run_attention(q, k, v, impl=self.attn_impl,
+                               causal=self.causal, mask=mask,
+                               seq_axis=self.seq_axis,
+                               flash_min_seq=self.flash_min_seq)
         b_, h, t, d = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(b_, t, h * d)
         y = o @ p["Wo"]
@@ -270,6 +466,10 @@ class MultiHeadAttention(BaseLayerConf):
         :meth:`_attend_paged` instead — same contract, K/V gathered
         through a block table."""
         from ...ops.attention import sdpa_reference
+        if self.positions != "none" or self.attention != "full":
+            raise NotImplementedError(
+                "the KV-cache path has no rotary positions and no eva "
+                "attention yet: such a layer trains and runs forward only")
         if isinstance(carry, dict) and "kp" in carry:
             return self._attend_paged(p, x, carry, mask=mask)
         q = self._heads(x, p, "Wq", "bq")                 # [b,h,t,d]
@@ -439,16 +639,31 @@ class MultiHeadAttention(BaseLayerConf):
 @register_serde
 @dataclass
 class TransformerBlock(BaseLayerConf):
-    """Pre-norm transformer block: LN→MHA→residual, LN→MLP(GELU)→residual.
+    """Pre-norm transformer block: norm→MHA→residual, norm→MLP→residual.
 
     The attention half delegates to ``MultiHeadAttention`` (params carried
     under a ``mha_`` prefix) so the two layers share one projection/head
-    implementation; ffn_mult sizes the hidden MLP.
+    implementation; ``ffn_hidden`` (else ``ffn_mult`` times the width)
+    sizes the hidden MLP.
+
+    The defaults are GPT-2's block: LayerNorm, no positions of its own,
+    heads of ``n_in / n_heads``, a GELU MLP, biases everywhere, full
+    attention.  The fields after ``aux_loss_weight`` choose the block of
+    today's decoders instead: ``norm='rms'`` (``_rms_norm``: unit offset,
+    no shift), ``positions='rotary'``, an explicit ``head_dim``,
+    ``gated=True`` (``W2 (silu(Wg x) * (W1 x))``), ``has_bias=False``
+    (no bias in any projection), ``attention='eva'`` with ``window`` and
+    ``chunk``; ``residual_dtype='float32'`` keeps the residual stream
+    (the block's input, its two adds and its output) in float32 under a
+    lower compute type, as EvaByte's ``fp32_skip_add`` does: the walk then
+    hands the block its input uncast (``PrecisionPolicy.input_dtype``) and
+    each norm's output goes to the projections in their own type.  The
+    defaults trace the program they always did.
     """
     INPUT_KIND = "rnn"
     HAS_CARRY = True
     _BIAS_PARAMS = ("mha_bq", "mha_bk", "mha_bv", "mha_bo", "b1", "b2",
-                    "ln1_g", "ln1_b", "ln2_g", "ln2_b")
+                    "ln1_g", "ln1_b", "ln2_g", "ln2_b", "mha_phi", "mha_mu")
 
     n_in: int = 0
     n_heads: int = 4
@@ -464,6 +679,17 @@ class TransformerBlock(BaseLayerConf):
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
+    norm: str = "layer"         # layer|rms
+    positions: str = "none"     # none|rotary
+    rope_theta: float = 10000.0
+    head_dim: int = 0           # default n_in // n_heads
+    ffn_hidden: int = 0         # default ffn_mult * n_in
+    gated: bool = False         # silu(Wg x) * (W1 x) in place of gelu(W1 x)
+    has_bias: bool = True
+    attention: str = "full"     # full|eva
+    window: int = 0
+    chunk: int = 0
+    residual_dtype: Optional[str] = None   # None: the compute type
 
     @property
     def AUX_LOSS(self):
@@ -487,15 +713,24 @@ class TransformerBlock(BaseLayerConf):
             seq_axis=self.seq_axis, activation="identity",
             weight_init=self.weight_init, weight_dist=self.weight_dist,
             bias_init=self.bias_init, dtype=self.dtype,
-            max_cache_len=self.max_cache_len)
+            max_cache_len=self.max_cache_len, head_dim=self.head_dim,
+            has_bias=self.has_bias, positions=self.positions,
+            rope_theta=self.rope_theta, attention=self.attention,
+            window=self.window, chunk=self.chunk)
         return m
 
     def init(self, key, itype):
         e = self.n_in
-        f = self.ffn_mult * e
+        f = self.ffn_hidden or self.ffn_mult * e
         k_mha, k1, k2, kr = jax.random.split(key, 4)
         mha_vars = self._mha().init(k_mha, itype)
         params = {f"mha_{k}": v for k, v in mha_vars["params"].items()}
+        if self.norm not in ("layer", "rms"):
+            raise ValueError(f"layer '{self.name}': unknown norm "
+                             f"'{self.norm}'; expected layer or rms")
+        if self.moe_experts > 0 and (self.gated or not self.has_bias):
+            raise ValueError(f"layer '{self.name}': the routed experts "
+                             "are ungated and biased")
         if self.moe_experts > 0:
             E = self.moe_experts
             params.update({
@@ -508,26 +743,48 @@ class TransformerBlock(BaseLayerConf):
         else:
             params.update({
                 "W1": self.make_weight(k1, (e, f)),
-                "b1": self.make_bias((f,)),
                 "W2": self.make_weight(k2, (f, e)),
-                "b2": self.make_bias((e,)),
             })
-        params.update({
-            "ln1_g": jnp.ones((e,), self._dtype()),
-            "ln1_b": jnp.zeros((e,), self._dtype()),
-            "ln2_g": jnp.ones((e,), self._dtype()),
-            "ln2_b": jnp.zeros((e,), self._dtype()),
-        })
+            if self.has_bias:
+                params.update(b1=self.make_bias((f,)),
+                              b2=self.make_bias((e,)))
+            if self.gated:
+                params["Wg"] = self.make_weight(jax.random.fold_in(key, 4),
+                                                (e, f))
+        if self.norm == "rms":
+            # the gain is an offset from one
+            params.update(ln1_g=jnp.zeros((e,), self._dtype()),
+                          ln2_g=jnp.zeros((e,), self._dtype()))
+        else:
+            params.update({
+                "ln1_g": jnp.ones((e,), self._dtype()),
+                "ln1_b": jnp.zeros((e,), self._dtype()),
+                "ln2_g": jnp.ones((e,), self._dtype()),
+                "ln2_b": jnp.zeros((e,), self._dtype()),
+            })
         state = {}
         if self.moe_experts > 0:
             state["aux_loss"] = jnp.zeros((), self._dtype())
         return {"params": params, "state": state}
 
+    def _norm(self, p, x, which: str):
+        if self.norm == "rms":
+            y = _rms_norm(x, p[which + "_g"], self.eps)
+        else:
+            y = _layer_norm(x, p[which + "_g"], p[which + "_b"], self.eps)
+        # a stream wider than the weights: the projections compute in theirs
+        return y.astype(p["mha_Wq"].dtype) if self.residual_dtype else y
+
     def _ffn(self, p, xn):
         """Dense or routed MLP; returns (out, state_update)."""
         if self.moe_experts == 0:
-            return (jax.nn.gelu(xn @ p["W1"] + p["b1"]) @ p["W2"]
-                    + p["b2"], {})
+            up = xn @ p["W1"]
+            if self.has_bias:
+                up = up + p["b1"]
+            hidden = (jax.nn.silu(xn @ p["Wg"]) * up if self.gated
+                      else jax.nn.gelu(up))
+            out = hidden @ p["W2"]
+            return (out + p["b2"] if self.has_bias else out), {}
         from ...parallel.expert import moe_ffn
         b, t, e = xn.shape
         x2d = xn.reshape(b * t, e)
@@ -544,11 +801,13 @@ class TransformerBlock(BaseLayerConf):
         p = self.maybe_noise_weights(key, variables["params"], train)
         x = self.maybe_dropout_input(key, x, train)
         mha_p = {k[4:]: v for k, v in p.items() if k.startswith("mha_")}
+        if self.residual_dtype:
+            x = x.astype(self.residual_dtype)
 
-        xn = _layer_norm(x, p["ln1_g"], p["ln1_b"], self.eps)
+        xn = self._norm(p, x, "ln1")
         x = x + self._mha().attend(mha_p, xn, train=train, key=key, mask=mask)
 
-        xn = _layer_norm(x, p["ln2_g"], p["ln2_b"], self.eps)
+        xn = self._norm(p, x, "ln2")
         ff, st = self._ffn(p, xn)
         return x + ff, st if st else variables.get("state", {})
 
@@ -564,11 +823,11 @@ class TransformerBlock(BaseLayerConf):
         p = self.maybe_noise_weights(key, variables["params"], train)
         x = self.maybe_dropout_input(key, x, train)
         mha_p = {k[4:]: v for k, v in p.items() if k.startswith("mha_")}
-        xn = _layer_norm(x, p["ln1_g"], p["ln1_b"], self.eps)
+        xn = self._norm(p, x, "ln1")
         attn, new_carry = self._mha().attend_cached(mha_p, xn, carry,
                                                     mask=mask)
         x = x + attn
-        xn = _layer_norm(x, p["ln2_g"], p["ln2_b"], self.eps)
+        xn = self._norm(p, x, "ln2")
         ff, st = self._ffn(p, xn)
         if st:
             # thread the MoE aux loss out through the caller's mutable

@@ -7,6 +7,7 @@ DropoutLayer,EmbeddingLayer}``.  The matmul runs in the layer's dtype
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -70,13 +71,37 @@ class OutputLayer(DenseLayer):
     """Dense + loss head (reference ``nn/conf/layers/OutputLayer``).
     ``loss_weights`` is the reference's per-output weight vector
     (e.g. ``LossMCXENT(weights)`` for class imbalance): the per-unit loss
-    is scaled column-wise before reduction."""
+    is scaled column-wise before reduction.
+
+    ``pred_heads`` > 1 makes the ``n_out`` units that many prediction
+    heads of ``n_out / pred_heads`` classes each (a multi-token head: head
+    ``n`` at a position predicts the ``n``-th target after it): the
+    activation runs per head, labels and label mask carry a trailing axis
+    of ``pred_heads``, and the loss is the mean over the targets the mask
+    keeps (where the single head's score, the reference's, sums an
+    example's units and averages over the examples)."""
     loss: str = "mcxent"
     loss_weights: Optional[Sequence[float]] = None
+    pred_heads: int = 1
+
+    def _by_head(self, z):
+        if self.pred_heads == 1:
+            return z
+        return z.reshape(*z.shape[:-1], self.pred_heads,
+                         self.n_out // self.pred_heads)
+
+    def apply(self, variables, x, *, train=False, key=None, mask=None):
+        if self.pred_heads == 1:
+            return super().apply(variables, x, train=train, key=key,
+                                 mask=mask)
+        z = self.pre_output(variables, x, train=train, key=key)
+        return (self.act_fn(self._by_head(z)).reshape(z.shape),
+                variables.get("state", {}))
 
     def compute_loss(self, variables, x, labels, *, train=False, key=None,
                      mask=None, average=True):
-        z = self.pre_output(variables, x, train=train, key=key)
+        z = self._by_head(self.pre_output(variables, x, train=train,
+                                          key=key))
         act = self.resolved("activation", "identity")
         if self.loss_weights is not None:
             w = jnp.asarray(self.loss_weights, z.dtype)
@@ -84,9 +109,20 @@ class OutputLayer(DenseLayer):
                 raise ValueError(
                     f"layer '{self.name}': {w.shape[-1]} loss weights for "
                     f"{self.n_out} outputs")
-            return _losses.get(self.loss)(labels, z, act, mask,
+            loss = _losses.get(self.loss)(labels, z, act, mask,
                                           unit_weights=w)
-        return _losses.get(self.loss)(labels, z, act, mask)
+        else:
+            loss = _losses.get(self.loss)(labels, z, act, mask)
+        if self.pred_heads > 1:
+            # the loss came as a sum over the kept targets divided by the
+            # examples that keep any
+            if mask is None:
+                return loss * (z.shape[0] / math.prod(z.shape[:-1]))
+            m = mask.astype(loss.dtype)
+            rows = jnp.sum(jnp.max(m.reshape(m.shape[0], -1), axis=1))
+            return loss * jnp.maximum(rows, 1.0) / jnp.maximum(jnp.sum(m),
+                                                               1.0)
+        return loss
 
 
 @register_serde

@@ -261,7 +261,7 @@ def _stack_forward(conf, params, state, x, *, train: bool, key, mask=None,
                     else None
                 mask = pp.feed_forward_mask(mask, itype)
         if precision is not None:
-            h = _cast_act(h, precision.layer_dtype(lc))
+            h = _cast_act(h, precision.input_dtype(lc))
         stop = runs.get(i)
         if stop is not None:
             # homogeneous run [i, stop): ONE traced body under lax.scan

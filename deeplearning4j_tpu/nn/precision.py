@@ -149,6 +149,15 @@ class PrecisionPolicy:
             return "float32"
         return self.compute_dtype
 
+    def input_dtype(self, lc) -> Optional[str]:
+        """The type the walk hands a layer its input in: the layer's
+        compute type, but a layer that carries a residual stream in a type
+        of its own (``TransformerBlock.residual_dtype``) takes it in that
+        one and rounds only what its projections read."""
+        if not self.active:
+            return None
+        return getattr(lc, "residual_dtype", None) or self.layer_dtype(lc)
+
 
 def named_policy(name: str) -> PrecisionPolicy:
     """Policy from a shorthand string: ``'bfloat16'``/``'bf16'`` (no

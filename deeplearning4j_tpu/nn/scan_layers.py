@@ -17,7 +17,15 @@ Exact parity with the unrolled walk is preserved by construction:
   - the layer body is the layer's own ``apply`` on its own params/state
     slice, so the math per iteration is the unrolled math;
   - ``cache_mode='remat'`` wraps the scan body in ``jax.checkpoint``
-    (remat-compatible carry).
+    (remat-compatible carry).  What the scan then saves for the backward
+    pass is each layer's input, stacked over the run, and the backward
+    pass recomputes a layer's forward just before it differentiates it:
+    the run's forward FLOPs once more a step, for one layer's internals
+    live at a time instead of every layer's.  On a TPU v5e the
+    benchmark's ``evabyte-4l.train-fit-long`` trains this way: four
+    blocks 4096 wide on 8192 positions fit one chip beside 9.86 GB of
+    state only so, and the recomputed forward is 85 ms of a 466 ms
+    step (``PERF.md`` section 5, PR 29).
 
 Eligibility (anything else falls back to the unrolled walk, which stays
 bit-identical): dataclass confs equal ignoring ``name``; no preprocessor

@@ -381,17 +381,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(qr, kr, vr, scale, causal, block_q, block_k, interpret):
-    out, _ = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
-                             interpret)
-    return out
-
-
-def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(qr, kr, vr, scale, causal, block_q, block_k, interpret,
+           with_lse=False):
+    """The output, and with ``with_lse`` the rows' log-sum-exp
+    ``(bh, 1, t_q)`` beside it, as a second differentiable result."""
     out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
-                               interpret)
-    return out, (qr, kr, vr, out, lse)
+                           interpret)
+    return (out, lse) if with_lse else out
+
+
+def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
+               with_lse=False):
+    out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
+                           interpret)
+    return ((out, lse) if with_lse else out), (qr, kr, vr, out, lse)
 
 
 def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
@@ -437,11 +441,18 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     return dq, dk, dv
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, with_lse, res,
+               do):
     qr, kr, vr, out, lse = res
+    if with_lse:
+        do, dlse = do
     # D = rowsum(dO ∘ O): one elementwise+reduce pass, XLA-fused
     dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                  axis=-1)[:, None, :]               # (bh, 1, t_q) row form
+    if with_lse:
+        # d lse_i / d s_ij = p_ij, so a cotangent on the log-sum-exp adds
+        # dlse_i * p_ij to dS = P∘(dP − D): the kernels take it as D − dlse
+        dd = dd - dlse
     return _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
                        block_k, interpret)
 
@@ -504,10 +515,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, return_lse: bool = False):
     """Flash attention over [b, h, t, d] tensors — differentiable: the
     FlashAttention-2 style backward (saved logsumexp, softmax replayed per
     block, separate dq and dk/dv kernels) keeps training memory O(t).
+
+    ``return_lse`` gives ``(out, lse)``, ``lse`` the float32 ``[b, h, t]``
+    log-sum-exp of each row's scaled scores, differentiable like ``out``
+    (the same three kernels; a cotangent on it rides the backward's ``D``):
+    what a caller needs to merge this softmax with one over further keys
+    (``ops.attention.combine_blocks`` with ``m = lse``, ``l = 1``).
 
     Never falls back: shapes the kernel cannot tile raise ``ValueError``
     (``flash_blocks``), and off a TPU backend the Pallas lowering itself
@@ -525,7 +542,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
         rows = q.shape[0] * h
         out = _flash(q.reshape(rows, t_q, d), k.reshape(rows, t_k, d),
                      v.reshape(rows, t_k, d), scale, causal, block_q,
-                     block_k, interpret)
+                     block_k, interpret, return_lse)
+        if return_lse:
+            return out[0].reshape(q.shape), out[1].reshape(q.shape[:3])
         return out.reshape(q.shape)
 
     mesh, spec = _kernel_partitioning(q)
@@ -534,5 +553,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
         # under the varying-axes check, which refuses a varying tile times
         # a constant; a compiled kernel is one opaque call to that check
         run = jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=not interpret)
+                            out_specs=(spec, spec) if return_lse else spec,
+                            check_vma=not interpret)
     return run(q, k, v)

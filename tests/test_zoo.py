@@ -162,7 +162,7 @@ def test_model_selector():
 def test_model_selector_type_filter():
     from deeplearning4j_tpu.models import ModelSelector
     rnn = ModelSelector.select("rnn")
-    assert set(rnn) == {"TextGenerationLSTM", "TransformerLM"}
+    assert set(rnn) == {"TextGenerationLSTM", "TransformerLM", "EvaByteLM"}
     cnn = ModelSelector.select("cnn")
     assert "TextGenerationLSTM" not in cnn and "LeNet" in cnn
 
