@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ...utils.serde import register_serde
 from ..conf.input_type import InputType
@@ -170,9 +171,11 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
         if mask is not None:
             raise ValueError("attn_impl='flash' does not take key-padding "
                              "masks; use 'reference'/'auto' or pre-mask inputs")
+        # the kernel names its own output and log-sum-exp (_flash_fwd)
         from ...ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal)
-    return sdpa_reference(q, k, v, mask=mask, causal=causal)
+    return checkpoint_name(sdpa_reference(q, k, v, mask=mask, causal=causal),
+                           "attn_out")
 
 
 def _rotary(x, theta: float):
@@ -399,6 +402,10 @@ class MultiHeadAttention(BaseLayerConf):
         v = self._heads(x, p, "Wv", "bv")
         if self.positions == "rotary":
             q, k = _rotary(q, self.rope_theta), _rotary(k, self.rope_theta)
+        # as the attention takes them: the layout a backward reads
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_k")
+        v = checkpoint_name(v, "attn_v")
         if self.attention == "eva":
             if mask is not None:
                 raise ValueError("attention='eva' takes no key-padding mask")
@@ -695,6 +702,22 @@ class TransformerBlock(BaseLayerConf):
     def AUX_LOSS(self):
         return self.moe_experts > 0
 
+    @property
+    def SAVED_NAMES(self):
+        """What a backward pass cannot cheaply rebuild from the block's
+        input, each tagged with ``checkpoint_name`` where it is computed:
+        q, k, v after the head split, the attention's output and (from the
+        flash kernel) its log-sum-exp, the stream after the first add, the
+        MLP's pre-activation and its gate's.  A scanned run saves these and
+        recomputes the rest: the norms, the activation, the head merge
+        (``nn/scan_layers.run_scan``).  Sequence-parallel attention is a
+        loop of collectives, which a backward must not replay: such a
+        block declares nothing."""
+        if self.attn_impl in ("ring", "ulysses"):
+            return ()
+        return ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse",
+                "block_mid", "mlp_up", "mlp_gate")
+
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:
             if itype.kind != "rnn":
@@ -781,8 +804,9 @@ class TransformerBlock(BaseLayerConf):
             up = xn @ p["W1"]
             if self.has_bias:
                 up = up + p["b1"]
-            hidden = (jax.nn.silu(xn @ p["Wg"]) * up if self.gated
-                      else jax.nn.gelu(up))
+            up = checkpoint_name(up, "mlp_up")
+            hidden = (jax.nn.silu(checkpoint_name(xn @ p["Wg"], "mlp_gate"))
+                      * up if self.gated else jax.nn.gelu(up))
             out = hidden @ p["W2"]
             return (out + p["b2"] if self.has_bias else out), {}
         from ...parallel.expert import moe_ffn
@@ -805,7 +829,9 @@ class TransformerBlock(BaseLayerConf):
             x = x.astype(self.residual_dtype)
 
         xn = self._norm(p, x, "ln1")
-        x = x + self._mha().attend(mha_p, xn, train=train, key=key, mask=mask)
+        x = checkpoint_name(
+            x + self._mha().attend(mha_p, xn, train=train, key=key,
+                                   mask=mask), "block_mid")
 
         xn = self._norm(p, x, "ln2")
         ff, st = self._ffn(p, xn)

@@ -16,16 +16,50 @@ Exact parity with the unrolled walk is preserved by construction:
     fold the unrolled loop performs — and scanned over as inputs;
   - the layer body is the layer's own ``apply`` on its own params/state
     slice, so the math per iteration is the unrolled math;
-  - ``cache_mode='remat'`` wraps the scan body in ``jax.checkpoint``
-    (remat-compatible carry).  What the scan then saves for the backward
-    pass is each layer's input, stacked over the run, and the backward
-    pass recomputes a layer's forward just before it differentiates it:
-    the run's forward FLOPs once more a step, for one layer's internals
-    live at a time instead of every layer's.  On a TPU v5e the
-    benchmark's ``evabyte-4l.train-fit-long`` trains this way: four
-    blocks 4096 wide on 8192 positions fit one chip beside 9.86 GB of
-    state only so, and the recomputed forward is 85 ms of a 466 ms
-    step (``PERF.md`` section 5, PR 29).
+  - the body's mathematics does not depend on what is saved: whatever the
+    backward recomputes is the same operations on the same inputs.
+
+What a scanned run saves for the backward pass, stacked over the run
+(each saved value is written into a ``[n, ...]`` stack in the forward
+and read back in the backward: a copy each way, every step):
+
+  ``all``    the layer declares nothing (``Dense``, ``LSTM``, conv
+             stacks, a block of ring or all-to-all attention):
+             ``scan``'s partial evaluation stacks every intermediate the
+             transposed body reads.  The program is the one a plain
+             ``lax.scan`` gives; an inner time scan or a loop of
+             collectives is never replayed.
+  ``named``  the layer declares ``SAVED_NAMES``, the values of its
+             ``apply`` that are dear to recompute, each tagged with
+             ``jax.ad_checkpoint.checkpoint_name`` where it is computed
+             (``TransformerBlock``: q, k, v after the head split, the
+             attention's output and log-sum-exp, the stream after the
+             first add, the MLP's pre-activation).  The body runs under
+             ``jax.checkpoint`` with ``save_only_these_names``: the run
+             saves the block's input and those, and the backward
+             recomputes the rest from them, for a GPT-2 block the
+             element-wise rest (norms, GELU, head merge): no matmul and
+             no kernel runs twice.  (What is neither named nor cheap is
+             recomputed all the same: EVA attention's summaries, or the
+             scores of ``sdpa_reference``, which the flash kernels
+             recompute by design.)  A GPT-2 medium block at 3 x 1024
+             tokens saves 8 arrays, 63 MB, where ``all`` stacks 19,
+             245 MB: on a TPU v5e the step is 5.5 ms of 89.0 shorter
+             (``PERF.md`` sections 5 and 6, PR 30).
+  ``input``  ``cache_mode='remat'``: ``jax.checkpoint`` with no policy.
+             The run saves each layer's input alone and the backward
+             recomputes a layer's forward just before it differentiates
+             it: the run's forward FLOPs once more a step, for one
+             layer's internals live at a time instead of every layer's.
+             On a TPU v5e the benchmark's ``evabyte-4l.train-fit-long``
+             trains this way: four blocks 4096 wide on 8192 positions
+             fit one chip beside 9.86 GB of state only so, and the
+             recomputed forward is 85 ms of a 466 ms step (``PERF.md``
+             section 5, PR 29).
+
+The choice follows from ``cache_mode`` and the layer; each scanned run
+traced into a training step counts into
+``scan_runs_traced_total{layer, saved}``.
 
 Eligibility (anything else falls back to the unrolled walk, which stays
 bit-identical): dataclass confs equal ignoring ``name``; no preprocessor
@@ -130,6 +164,18 @@ def scan_runs(conf, n: int, *, mask_present: bool, carries_present: bool,
     return runs
 
 
+def _count_run(layer: str, saved: str) -> None:
+    """One scanned training run traced: trace-time work, like
+    ``training_compile_total``."""
+    from ..observability.registry import default_registry
+    reg = default_registry()
+    if reg.enabled:
+        reg.counter("scan_runs_traced_total",
+                    "Scanned layer runs traced into a training step, by "
+                    "what the run saves for the backward pass",
+                    ("layer", "saved")).labels(layer, saved).inc()
+
+
 def run_scan(lc, params_slices, state_slices, h, key, start: int,
              *, train: bool, mask, remat: bool):
     """Execute one homogeneous run under ``jax.lax.scan``.
@@ -161,8 +207,21 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
                              key=k, mask=mask)
         return y, ns
 
+    names = tuple(getattr(lc, "SAVED_NAMES", ()))
     if remat:
+        saved = "input"
         body = jax.checkpoint(body)
+    elif train and names:
+        saved = "named"
+        # in a scan nothing can be shared with the recomputation, so no
+        # barrier against common-subexpression elimination is needed
+        body = jax.checkpoint(
+            body, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+    else:
+        saved = "all"
+    if train:
+        _count_run(type(lc).__name__, saved)
     # explicit length: a paramless/stateless run at inference (no keys)
     # has no xs leaves for scan to infer it from
     h, stacked_ns = jax.lax.scan(body, h, (stacked_p, stacked_s, keys),

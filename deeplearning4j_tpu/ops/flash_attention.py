@@ -37,6 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import AxisType, PartitionSpec as P
@@ -395,6 +396,11 @@ def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
                with_lse=False):
     out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
                            interpret)
+    # the backward's residuals are results of this rule, not of the
+    # caller's code: named here, or a checkpoint policy that saves by name
+    # (nn/scan_layers) reruns the forward kernel in the backward
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return ((out, lse) if with_lse else out), (qr, kr, vr, out, lse)
 
 

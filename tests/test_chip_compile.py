@@ -63,6 +63,23 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _kernel_calls(compiled) -> dict:
+    """How often each flash kernel is a custom call of the compiled
+    program (a kernel's call carries its name in its scope)."""
+    calls = [line for line in compiled.as_text().split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return {name: sum(f"/{name}/pallas_call" in c for c in calls)
+            for name in F.KERNEL_NAMES}
+
+
+def _while_stacks(compiled, shape: str) -> int:
+    """The most arrays of ``shape`` that one ``while`` of the compiled
+    program carries: what the forward scan stacks for the backward."""
+    return max(line.split(" while(")[0].count(shape)
+               for line in compiled.as_text().split("\n")
+               if " while(" in line)
+
+
 def _qkv(sharding, rows, t, dtype=jnp.bfloat16):
     return (jax.ShapeDtypeStruct((rows, t, HEAD_DIM), dtype,
                                  sharding=sharding),) * 3
@@ -212,6 +229,10 @@ def test_lm_train_step_compiles_with_flash_kernels(topo, four_chips, chips,
     assert all(name in text for name in F.KERNEL_NAMES)
     compiled = lowered.compile()
     assert _custom_calls(compiled) == 3
+    # one scan body each way, and each kernel once: the policy the scan
+    # saves by (the block's names) does not replay the forward kernel in
+    # the backward, on one chip or inside the shard_map of four
+    assert _kernel_calls(compiled) == dict.fromkeys(F.KERNEL_NAMES, 1)
     if chips == 4:
         # parameters and optimizer state at about a quarter per chip
         whole = sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -219,3 +240,21 @@ def test_lm_train_step_compiles_with_flash_kernels(topo, four_chips, chips,
                         (net.params, net.opt_state)))
         per_chip = compiled.memory_analysis().argument_size_in_bytes
         assert 0.24 * whole < per_chip < 0.30 * whole
+
+    # the same step with the block's names ignored (the scan then stacks
+    # every intermediate its backward reads) needs more room for its
+    # temporaries; built afresh, so that nothing traced is served again
+    from deeplearning4j_tpu.nn.layers.attention import TransformerBlock
+    from deeplearning4j_tpu.nn.multilayer import _build_train_step
+    monkeypatch.setattr(TransformerBlock, "SAVED_NAMES", ())
+    everything = jax.jit(_build_train_step(net.conf, net._tx, False),
+                         donate_argnums=(0, 1, 2, 3)).lower(*args).compile()
+    assert _kernel_calls(everything) == dict.fromkeys(F.KERNEL_NAMES, 1)
+    named_temp = compiled.memory_analysis().temp_size_in_bytes
+    all_temp = everything.memory_analysis().temp_size_in_bytes
+    assert named_temp < 0.9 * all_temp, (named_temp, all_temp)
+    # per chip two rows: the scan stacks the block's input and the stream
+    # after the first add, and no third array of that shape
+    stack = "bf16[4,2,1024,768]"
+    assert _while_stacks(compiled, stack) == 2 < _while_stacks(everything,
+                                                               stack)
