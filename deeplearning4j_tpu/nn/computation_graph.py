@@ -13,6 +13,7 @@ layers (reference computeGradientAndScore, ComputationGraph.java:1310-1320).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -21,18 +22,16 @@ import numpy as np
 import optax
 
 from . import precision as _precision
-from ._common import (_cast_floats, apply_constraints_all,
-                      apply_gradient_norm_all, build_tx,
-                      fit_on_device_epochs, hyperparam_conf)
+from ._common import (_cast_act, _on_device, build_train_step, build_tx,
+                      compute_dtypes, finish_step, fit_batches,
+                      fit_on_device_epochs, hyperparam_conf, placed)
 from .compile_cache import shared_jit, topology_signature
-from .multilayer import _cast_act
 from .conf.computation_graph import (ComputationGraphConfiguration,
                                      GraphVertexConf, LayerVertex)
 from .conf.updaters import Sgd, UpdaterConf
 from .layers.base import BaseLayerConf
 from ..data.shapes import default_shape_policy
-from ..observability.clock import monotonic_s
-from ..observability.tracer import get_tracer, training_entry
+from ..observability.tracer import training_entry
 from ..train.listeners import TrainingListener
 
 Array = jax.Array
@@ -44,6 +43,12 @@ def _as_list(x) -> List:
     if isinstance(x, (list, tuple)):
         return list(x)
     return [x]
+
+
+def _on_device_list(a):
+    """Device placement for one part of a graph's batch: a list of leaves
+    (one an input, output or mask), or None."""
+    return None if a is None else [_on_device(e) for e in _as_list(a)]
 
 
 def _vertex_confs(conf) -> Dict[str, Any]:
@@ -109,8 +114,11 @@ def _graph_forward(conf, params, state, inputs: List[Array], *, train: bool,
     return acts, new_state, mask_of
 
 
-def _graph_loss(conf, params, state, inputs, labels, *, train: bool, key,
-                masks=None, label_masks=None, precision=None):
+def _graph_loss(conf, params, state, inputs, labels, masks=None,
+                label_masks=None, *, train: bool, key, precision=None):
+    """Sum of the output layers' losses + regularization.  Free function
+    over the configuration; with ``conf`` and ``train`` bound it is the
+    ``loss`` of ``_common.build_train_step``."""
     acts, new_state, mask_of = _graph_forward(
         conf, params, state, inputs, train=train, key=key, masks=masks,
         exclude_outputs=True, precision=precision)
@@ -187,10 +195,8 @@ def _build_graph_fn(conf, tx, kind: str):
 
 
 def _build_graph_train_step(conf, tx):
-    gn_mode = conf.defaults.get("gradient_normalization")
-    gn_thr = float(conf.defaults.get(
-        "gradient_normalization_threshold", 1.0))
-    pol = _precision.resolve(conf.defaults)
+    """The graph's train step: ``_common.build_train_step`` over
+    ``_graph_loss``."""
     confs = _vertex_confs(conf)
     for name, lc in confs.items():
         if getattr(lc, "sparse_grad", False) or \
@@ -206,66 +212,12 @@ def _build_graph_train_step(conf, tx):
                 "ComputationGraph train step has no densified sparse-"
                 "gradient pre-pass — drop the flag, or move the "
                 "embedding model to a MultiLayerNetwork stack")
-    cast_map = {}
-    if pol is not None:
-        for name, v in conf.vertices.items():
-            dt = pol.layer_dtype(getattr(v, "layer", None) or v)
-            if dt not in (None, "float32"):
-                cast_map[name] = dt
-
-    def step(params, state, opt_state, key, xs, ys, masks, label_masks):
-        # fused RNG succession (see nn/multilayer._build_train_step): the
-        # host-side split moves into the program — bit-identical key
-        # sequence, one less dispatch, and the key becomes donatable
-        new_rng, key = jax.random.split(key)
-        if pol is not None:
-            xs = [_cast_act(x, pol.compute_dtype) for x in xs]
-        ls = state.get(_precision.SCALE_STATE_KEY) \
-            if pol is not None and pol.scaled else None
-        scale = ls["scale"] if ls is not None else None
-
-        # the same scopes as nn/multilayer._build_train_step
-        @jax.named_scope("forward")
-        def loss_fn(p):
-            if cast_map:
-                p = {k: (_cast_floats(v, cast_map[k]) if k in cast_map
-                         else v) for k, v in p.items()}
-            loss, new_state = _graph_loss(conf, p, state, xs, ys,
-                                          train=True, key=key, masks=masks,
-                                          label_masks=label_masks,
-                                          precision=pol)
-            obj = loss * scale if scale is not None else loss
-            return obj, (loss, new_state)
-        (_obj, (loss, new_state)), grads = \
-            jax.value_and_grad(loss_fn, has_aux=True)(params)
-        finite = None
-        with jax.named_scope("grad_post"):
-            if scale is not None:
-                grads, finite = _precision.unscale_and_check(grads, scale)
-            grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
-            gleaves = jax.tree_util.tree_leaves(grads)
-            gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
-                if gleaves else jnp.zeros((), jnp.float32)
-            glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
-                                      for g in jax.tree_util.tree_leaves(v)))
-                      for k, v in grads.items() if v}
-        with jax.named_scope("optimizer"):
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            new_params = apply_constraints_all(new_params, confs)
-        if pol is not None:
-            new_state = _cast_floats(new_state, jnp.float32,
-                                     only=pol.compute_dtype)
-        gstats = {"global_norm": gnorm, "layer_norms": glayer}
-        if ls is not None:
-            # overflow: skip the step wholesale (nn/precision)
-            new_params, new_opt, new_state, _sel = \
-                _precision.overflow_skip(
-                    pol, ls, finite, params, new_params, opt_state,
-                    new_opt, state, new_state, gstats)
-        return new_params, new_state, new_opt, new_rng, loss, gstats
-
-    return step
+    cast_map = compute_dtypes(
+        conf.defaults, {name: getattr(v, "layer", None) or v
+                        for name, v in conf.vertices.items()})
+    return build_train_step(
+        functools.partial(_graph_loss, conf, train=True), conf.defaults,
+        confs, cast_map, tx)
 
 
 class ComputationGraph:
@@ -288,8 +240,8 @@ class ComputationGraph:
         self.last_drained_iteration = -1
         self._last_grad_stats = None
         self._last_step_traced = False
-        # per-fit StepProfiler (see MultiLayerNetwork): _fit_one credits
-        # its h2d/listener slices through it when a fit attaches one
+        # the running fit's StepProfiler (``_common.fit_batches`` attaches
+        # it; ``placed`` and ``finish_step`` credit it their slices)
         self._stepprof = None
         self._tx = None
         self._rng = jax.random.PRNGKey(conf.seed)
@@ -468,24 +420,10 @@ class ComputationGraph:
     def _pad_train_safe(self) -> bool:
         return self._pad_flags()[2]
 
-    def _fit_one(self, xs, ys, ms, lms):
-        """One train step (shared by fit's inner loop and fit_batch).
-        Leaves ``_score`` as the ASYNC device loss scalar — see
-        ``MultiLayerNetwork._fit_one`` (the host-sync sweep); the fit
-        loop materializes once at the end, ``fit_batch`` on return."""
-        prof = self._stepprof
-        if prof is not None:
-            _t = monotonic_s()
-        with get_tracer().span("dl4j.h2d"):
-            xs = [jnp.asarray(x) for x in xs]
-            ys = [jnp.asarray(y) for y in ys]
-            ms = None if ms is None else [
-                None if m is None else jnp.asarray(m) for m in _as_list(ms)]
-            lms = None if lms is None else [
-                None if m is None else jnp.asarray(m)
-                for m in _as_list(lms)]
-        if prof is not None:
-            prof.mark("h2d", monotonic_s() - _t)
+    def _prepare(self, batch):
+        """Place and pad one batch: the train step's four batch arguments
+        (the fit loop's ``prepare``)."""
+        xs, ys, ms, lms = placed(self, _on_device_list, batch)
         self.last_batch_size = int(xs[0].shape[0])
         pol = self.shape_policy
         if pol is not None and pol.enabled and ms is None and \
@@ -493,26 +431,16 @@ class ComputationGraph:
             # ragged batches pad onto an already-compiled bucket; padded
             # rows carry a zero label mask on EVERY output head
             xs, ys, lms = pol.pad_multi_batch(xs, ys, lms, path="train")
-        step_fn = self._get_jitted("train_step")
-        # fused-RNG step: splits the key inside the program (bit-identical
-        # to the host split it replaces) and returns the successor
-        (self.params, self.state, self.opt_state, self._rng, loss,
-         gstats) = step_fn(
-            self.params, self.state, self.opt_state, self._rng, xs, ys,
-            ms, lms)
-        self._score = loss
-        self._last_grad_stats = gstats
-        self._last_step_traced = bool(getattr(step_fn, "last_call_traced",
-                                              False))
-        self.iteration += 1
-        if prof is None:
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, self.epoch)
-        else:
-            _t = monotonic_s()
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, self.epoch)
-            prof.mark("listener", monotonic_s() - _t)
+        return xs, ys, ms, lms
+
+    def _fit_one(self, xs, ys, ms, lms):
+        """One train step outside a fit loop (``fit_batch``,
+        ``fit_on_device``'s ragged tail), from the loop's two pieces;
+        returns the still-async loss (``_common.finish_step``)."""
+        step = self._get_jitted("train_step")
+        args = self._prepare((xs, ys, ms, lms))
+        finish_step(self, step, step(
+            self.params, self.state, self.opt_state, self._rng, *args))
         return self._score
 
     def fit_batch(self, batch) -> float:
@@ -530,8 +458,8 @@ class ComputationGraph:
         arrays) or an iterable of MultiDataSet-shaped batches.
 
         ``checkpoint``/``resume_from``: crash-consistent periodic saves and
-        exact mid-epoch resume (``faulttolerance.CheckpointConfig``; see
-        ``MultiLayerNetwork.fit``)."""
+        exact mid-epoch resume (``faulttolerance.CheckpointConfig``; the
+        loop is ``nn/_common.fit_batches``)."""
         if self.params == {}:
             self.init()
         if labels is not None:
@@ -565,139 +493,8 @@ class ComputationGraph:
         if checkpoint is not None or resume_from is not None:
             from ..faulttolerance.checkpoint import FitCheckpointer
             ckpt = FitCheckpointer(self, checkpoint, resume_from)
-        from ..observability.health import get_health_monitor
-        from ..observability.profiler import step_profiler_for
-        from ..observability.recorder import get_flight_recorder
-        from .multilayer import _StepForensics
-        rec = get_flight_recorder()
-        rec_on = rec is not None and rec.enabled
-        mon = get_health_monitor()
-        forensics = _StepForensics(self, rec, mon, ckpt) \
-            if (rec_on or mon is not None) else None
-        # per-step phase attribution with a sampled device fence (see
-        # MultiLayerNetwork.fit / observability/profiler.py)
-        prof = step_profiler_for("train_step")
-        self._stepprof = prof
-
-        # bounded async dispatch (ISSUE 18; see MultiLayerNetwork.fit):
-        # up to DL4J_TPU_DISPATCH_DEPTH steps in flight, drained at epoch
-        # ends and checkpoint boundaries, NaN-checked per drained token
-        from .dispatch import DispatchWindow
-
-        def _nan_at_drain(iteration, value):
-            if rec_on:
-                rec.record("train", "nan_at_drain", score=value,
-                           iteration=int(iteration))
-        win = DispatchWindow(owner=self, profiler=prof,
-                             on_nan=_nan_at_drain)
-        start_epoch = ckpt.start_epoch if ckpt is not None else 0
-        stop = False
-        span = get_tracer().span
-        try:
-            for ep in range(start_epoch, epochs):
-                for lst in self.listeners:
-                    lst.on_epoch_start(self)
-                batches = iter(batches_factory())
-                # resume cursor: skip already-consumed batches of the first
-                # resumed epoch without fitting (see MultiLayerNetwork.fit)
-                skip = ckpt.skip_batches \
-                    if (ckpt is not None and ep == ckpt.start_epoch) else 0
-                seq = 0
-                while True:
-                    with span("dl4j.input_wait"):
-                        batch = next(batches, None)
-                    if batch is None:
-                        break
-                    if seq < skip:
-                        seq += 1
-                        continue
-                    t_step = monotonic_s()
-                    if prof is not None:
-                        prof.begin(t_step)
-                    self._fit_one(*batch)
-                    if prof is not None:
-                        prof.dispatched(self._score, window=win)
-                    seq += 1
-                    t_end = monotonic_s()
-                    if forensics is not None and forensics.step(
-                            ep, seq, self._last_step_traced,
-                            t_end - t_step, t_end):
-                        stop = True   # opt-in health stop: clean return
-                    if prof is not None:
-                        prof.lap("forensics")
-                    if not stop and ckpt is not None:
-                        if ckpt.due():
-                            # checkpoint boundary drains the window first
-                            # (mid-window resume stays digest-exact)
-                            win.drain()
-                        if ckpt.after_batch(ep, seq):
-                            stop = True   # SIGTERM: final save taken
-                    if prof is not None:
-                        if ckpt is not None:
-                            prof.lap("checkpoint")
-                        prof.end(self.iteration, self._last_step_traced)
-                    if stop:
-                        break
-                    # admit this step into the in-flight window (bounded-
-                    # pipeline backpressure point)
-                    win.push(self._score, self.iteration)
-                if stop:
-                    break
-                # ONE materialization per epoch (fit_on_device's sync
-                # convention): steps pipelined async all epoch; epoch-end
-                # listeners (MetricsListener score/grad-norm) see a host
-                # float without forcing their own sync
-                win.drain()
-                with span("dl4j.sync"):
-                    self._score = float(self._score)
-                if prof is not None:
-                    prof.materialized()
-                for lst in self.listeners:
-                    lst.on_epoch_end(self)
-                self.epoch += 1
-                if ckpt is not None and ckpt.after_epoch(ep):
-                    stop = True
-                    break
-            # stop-path exits break before the epoch-end drain
-            win.drain()
-        except Exception as e:
-            # never block on in-flight work while unwinding (the final
-            # un-guarded float(_score) still surfaces deferred failures)
-            win.abandon()
-            if rec_on:   # crash forensics before the exception propagates
-                if forensics is not None:
-                    try:
-                        forensics.flush()
-                    except Exception:
-                        pass   # forensics must not mask the real error
-                rec.record("train", "fit_exception",
-                           error=f"{type(e).__name__}: {e}",
-                           iteration=int(self.iteration))
-                rec.maybe_dump(
-                    "fit_exception",
-                    directory=(ckpt.manager.directory
-                               if ckpt is not None and ckpt.manager
-                               is not None else None))
-            raise
-        finally:
-            if forensics is not None:
-                try:
-                    forensics.flush()
-                except Exception:
-                    pass
-            if prof is not None:
-                self._stepprof = None
-                try:
-                    prof.flush()
-                except Exception:
-                    pass   # profile telemetry must not mask the real error
-            if ckpt is not None:
-                ckpt.close()
-        # ONE materialization for the whole fit (async steps pipeline).
-        # NOT exception-guarded: deferred device failures surface here
-        with span("dl4j.sync"):
-            self._score = float(self._score)
-        return self
+        return fit_batches(self, batches_factory, epochs, self._prepare,
+                           self._get_jitted("train_step"), ckpt=ckpt)
 
     @training_entry("dl4j.fit_on_device")
     def fit_on_device(self, inputs, labels, *, batch_size: int,

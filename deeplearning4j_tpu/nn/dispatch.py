@@ -1,4 +1,5 @@
-"""Bounded asynchronous dispatch window for the fit loops (ISSUE 18).
+"""Bounded asynchronous dispatch window of the fit loop,
+``nn/_common.fit_batches`` (ISSUE 18).
 
 JAX dispatch is asynchronous: a jitted step call returns device futures
 immediately and the host is free to run step N+1's work (ETL wait,
@@ -18,7 +19,7 @@ blocks on the oldest until at most ``depth - 1`` remain, so ``depth=1``
 reproduces the fully serial per-step-sync loop and the default
 ``depth=2`` overlaps one step of host work with device execution.
 
-Contract-preserving drains (the fit loops own these):
+Contract-preserving drains (the fit loop owns these):
 
 - epoch ends and checkpoint-due boundaries call :meth:`drain` so
   exact-resume parity and the one-sync-per-epoch listener cadence hold;

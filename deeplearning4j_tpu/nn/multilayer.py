@@ -22,7 +22,6 @@ utils/model_serializer.py).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -32,187 +31,24 @@ import optax
 
 from . import precision as _precision
 from . import scan_layers as _scan_layers
-from . import sparse as _sparse
-from ._common import (_cast_floats, apply_constraints_all,
-                      apply_gradient_norm_all, apply_gradient_normalization,
-                      build_tx, fit_on_device_epochs, float_grad_leaves,
-                      hyperparam_conf)
+from ._common import (_cast_act, _on_device, build_train_step, build_tx,
+                      compute_dtypes, finish_step, fit_batches,
+                      fit_on_device_epochs, hyperparam_conf, placed)
 from .compile_cache import shared_jit, topology_signature
-from .dispatch import DispatchWindow
 from .conf.multi_layer import MultiLayerConfiguration
 from .conf.schedules import resolve as resolve_schedule
 from .conf.updaters import Sgd, UpdaterConf
 from .layers.base import BaseLayerConf
-from ..data.pipeline import ETL_BUCKETS as _ETL_BUCKETS
 from ..data.shapes import _pad_time, default_shape_policy
-from ..observability.clock import monotonic_s, wall_s
-from ..observability.registry import default_registry
-from ..observability.tracer import get_tracer, training_entry
+from ..observability.tracer import training_entry
 from ..train.listeners import TrainingListener
 
-# training-step histogram bounds: sub-ms CPU steps up to multi-second
-# XLA compiles in the "compile" phase series
-_STEP_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-                 0.5, 1.0, 2.5, 5.0, 10.0, 30.0)
-
-
-def _on_device(a):
-    """Device placement for one batch leaf; a leaf the input pipeline
-    already placed (``DevicePrefetchIterator``) passes through untouched —
-    no second H2D copy, no resharding."""
-    if a is None or isinstance(a, jax.Array):
-        return a
-    return jnp.asarray(a)
-
-
-class _StepForensics:
-    """Per-step flight-recorder + health-monitor feed for the fit loops
-    (shared by MultiLayerNetwork and ComputationGraph), amortized.
-
-    Processing a step — a recorder dict build plus the monitor's EWMA
-    updates — is only a few microseconds warm, but the train loop runs
-    that Python cache-cold right after each multi-ms XLA dispatch, which
-    inflates every call ~4x and blows the <2% overhead budget on small
-    steps.  So :meth:`step` only captures a raw tuple (and, every
-    ``grad_check_every``-th step, a *reference* to the still-on-device
-    grad stats — the host fetch is deferred too) and :meth:`flush`
-    drains the buffer through ``record()``/``observe_step()`` in a tight
-    warm loop every ``FLUSH_EVERY`` steps.
-
-    The loss is only materialized per step (``float`` = host sync) when
-    a health MONITOR is armed: its NaN/stop/checkpoint reaction is
-    contractually same-step, so that configuration pays the sync it
-    always paid, and a non-finite loss still flushes IMMEDIATELY.
-    Recorder-only forensics buffer the still-async device scalar and
-    materialize at flush time — by then the value has long computed, so
-    the D2H copy no longer stalls the dispatch pipeline (the lifetime
-    audit's host-sync sweep; see tools/graftaudit).  Every dump path
-    flushes first: the fit loops flush on exception and in their
-    ``finally``, and the checkpointer's preemption dump calls the
-    ``pre_dump`` hook this helper installs — buffered steps can never
-    miss an artifact."""
-
-    FLUSH_EVERY = 16
-    __slots__ = ("net", "rec", "ring", "mon", "ckpt", "pol", "_buf",
-                 "_grad_every", "_wall0", "_saved_kinds")
-
-    def __init__(self, net, rec, mon, ckpt):
-        self.net = net
-        self.rec = rec if (rec is not None and rec.enabled) else None
-        self.ring = self.rec.channel("train") \
-            if self.rec is not None else None
-        self.mon = mon
-        self.ckpt = ckpt
-        pol = getattr(net, "shape_policy", None)
-        self.pol = pol if hasattr(pol, "last_pad_ratio") else None
-        self._grad_every = mon.config.grad_check_every \
-            if mon is not None else 0
-        # wall = mono + _wall0: record timestamps derive from the step
-        # end the loop already clocked, saving a wall read per step
-        self._wall0 = wall_s() - monotonic_s()
-        self._buf: list = []
-        self._saved_kinds: set = set()
-        if ckpt is not None:
-            ckpt.pre_dump = self.flush
-
-    def step(self, ep: int, seq: int, compile_step: bool,
-             dt: float, t_end: float) -> bool:
-        """Capture one fitted step (``t_end`` = the loop's monotonic
-        step-end read); returns True when the monitor's opt-in
-        ``stop_training`` policy says to halt the fit."""
-        net = self.net
-        loss = net._score
-        mon = self.mon
-        if mon is not None:
-            # the monitor's same-step NaN reaction needs the value NOW;
-            # recorder-only runs keep the device scalar async
-            loss = float(loss)
-        every = self._grad_every
-        pol = self.pol
-        buf = self._buf
-        buf.append(
-            (t_end, net.iteration, ep, seq, net.last_batch_size,
-             loss, dt, compile_step,
-             net._last_grad_stats
-             if every > 0 and net.iteration % every == 0 else None,
-             pol.last_pad_ratio if pol is not None else None))
-        # loss - loss is 0.0 for finite loss, NaN for nan/±inf: the
-        # non-finite check without a function call (monitor-armed only —
-        # on the async path the check itself would be the host sync)
-        if len(buf) >= self.FLUSH_EVERY or \
-                (mon is not None and loss - loss != 0.0):
-            return self.flush()
-        return False
-
-    def flush(self) -> bool:
-        """Drain buffered steps into the recorder ring and the monitor;
-        returns the monitor's stop verdict."""
-        buf = self._buf
-        mon = self.mon
-        if not buf:
-            return mon.should_stop() if mon is not None else False
-        self._buf = []
-        rec, ckpt, ring = self.rec, self.ckpt, self.ring
-        wall0 = self._wall0
-        for t_end, it, ep, seq, bs, loss, dt, comp, gref, pad in buf:
-            # recorder-only steps buffered the async device scalar; one
-            # cheap D2H each at drain time (the value computed steps ago).
-            # NOT exception-guarded: this float() is where deferred
-            # device-side failures first surface, and they must propagate
-            loss = float(loss)
-            if ring is not None:
-                # literal-dict append onto the hoisted ring: same record
-                # shape record() builds, minus the wrapper overhead
-                ring.append({"ts": wall0 + t_end, "type": "step",
-                             "iteration": it, "epoch": ep, "score": loss,
-                             "batch": bs, "step_s": round(dt, 6),
-                             "compile": comp})
-            if mon is None:
-                continue
-            grad_norm = None
-            if gref is not None:
-                try:
-                    grad_norm = float(gref["global_norm"])
-                except (KeyError, TypeError, ValueError):
-                    grad_norm = None
-            eps = bs / dt if dt > 0 and not comp else None
-            detections = mon.observe_step(
-                loss=loss, grad_norm=grad_norm, examples_per_sec=eps,
-                padding_ratio=pad, step=it)
-            if detections and ckpt is not None and \
-                    mon.config.checkpoint_on_detection and \
-                    ckpt.manager is not None and \
-                    any(d.kind not in self._saved_kinds
-                        for d in detections):
-                self._saved_kinds.update(d.kind for d in detections)
-                try:
-                    # ONE immediate save per detection kind marks the
-                    # incident step durably without letting a sticky NaN
-                    # (re-detected every dedupe_s) rotate the manager's
-                    # keep_last window past every pre-incident checkpoint
-                    ckpt._save(ep, seq)
-                    mon.checkpoint_saves += 1
-                except Exception:
-                    pass   # a failed emergency save must not kill the fit
-        if rec is not None:
-            rec.snapshot_metrics()   # internally time-throttled
-        return mon.should_stop() if mon is not None else False
 
 Array = jax.Array
 
 
 def _layer_confs(conf) -> Dict[str, Any]:
     return {f"layer_{i}": lc for i, lc in enumerate(conf.layers)}
-
-
-def _cast_act(h, dtype: Optional[str]):
-    """Cast a floating activation to a policy dtype (ints — token ids —
-    pass through untouched)."""
-    if dtype is None or not hasattr(h, "dtype") or \
-            not jnp.issubdtype(h.dtype, jnp.floating) or \
-            str(h.dtype) == dtype:
-        return h
-    return h.astype(dtype)
 
 
 def _stack_forward(conf, params, state, x, *, train: bool, key, mask=None,
@@ -308,11 +144,12 @@ def _stack_forward(conf, params, state, x, *, train: bool, key, mask=None,
     return out, new_state
 
 
-def _stack_loss(conf, params, state, x, y, *, train: bool, key, mask=None,
-                label_mask=None, carries=None, precision=None):
+def _stack_loss(conf, params, state, x, y, mask=None, label_mask=None, *,
+                train: bool, key, carries=None, precision=None):
     """Forward to last layer's loss + regularization (reference
     computeGradientAndScore, MultiLayerNetwork.java:2206).  Free function
-    over the configuration — see ``_stack_forward``."""
+    over the configuration — see ``_stack_forward``; with ``conf`` and
+    ``train`` bound it is the ``loss`` of ``_common.build_train_step``."""
     layers = conf.layers
     n = len(layers)
     h, new_state, pmask = _stack_forward(
@@ -458,180 +295,16 @@ def _sparse_embedding_conf(conf):
 
 
 def _build_train_step(conf, tx, with_carry: bool):
-    gn_mode = conf.defaults.get("gradient_normalization")
-    gn_thr = float(conf.defaults.get("gradient_normalization_threshold", 1.0))
-    pol = _precision.resolve(conf.defaults)
+    """The stack's train step: ``_common.build_train_step`` over
+    ``_stack_loss``, with the sparse-embedding pre-pass where layer_0 asks
+    for it and the recurrent carries for tBPTT."""
     confs = _layer_confs(conf)
     sparse_emb = _sparse_embedding_conf(conf)
-    # per-layer compute dtypes, resolved once at build time (keep_f32
-    # classes and per-name overrides stay f32 — their params are never
-    # downcast, and _stack_forward casts activations to match)
-    cast_map = {}
-    if pol is not None:
-        for name, lc in confs.items():
-            dt = pol.layer_dtype(lc)
-            if dt not in (None, "float32"):
-                cast_map[name] = dt
-
-    def step(params, state, opt_state, key, x, y, mask, label_mask,
-             carries=None):
-        # fused RNG succession: the split that used to run host-side
-        # (``self._rng, key = jax.random.split(self._rng)``) happens
-        # inside the program — bit-identical key sequence, one less
-        # device dispatch per step, and the key argument gains an
-        # alias-matched output (``new_rng``) so it can be donated
-        new_rng, key = jax.random.split(key)
-        if pol is not None:
-            # floating inputs only: integer token ids must reach the
-            # embedding gather exact (a bf16 cast quantizes ids > 256)
-            x = _cast_act(x, pol.compute_dtype)
-        # sparse-embedding pre-pass (nn/sparse): coalesce the batch's
-        # touched table rows OUTSIDE the differentiated function and
-        # substitute (table -> gathered rows, ids -> row slots), so the
-        # table's cotangent is [capacity, dim] — the dense [vocab, dim]
-        # cotangent never exists in this program.  All decisions here
-        # are trace-time static (dtype/shape/conf), so the compiled
-        # program is fixed per batch signature: zero steady recompiles.
-        ctx = None
-        if sparse_emb is not None:
-            W0 = params["layer_0"]["W"]
-            ids = sparse_emb.decode_ids(x)
-            if ids is None:
-                # never a silent dense fallback: falling through here
-                # would quietly restore the O(vocab·dim) exchange the
-                # flag exists to remove
-                raise ValueError(
-                    f"layer '{sparse_emb.name}': sparse_grad=True needs "
-                    "an integer id batch for the densified pre-pass, but "
-                    f"this input (shape {tuple(x.shape)}, dtype "
-                    f"{x.dtype}) rides the one-hot path — feed ids "
-                    "(argmax the one-hots upstream), or drop sparse_grad")
-            if not _sparse.table_is_unambiguous(params, W0.shape):
-                raise ValueError(
-                    f"layer '{sparse_emb.name}': another parameter leaf "
-                    f"shares the table's exact shape {tuple(W0.shape)} — "
-                    "the row-space mirror walk is shape-keyed and cannot "
-                    "disambiguate the updater mirrors; resize/split the "
-                    "twin parameter or drop sparse_grad")
-            ctx = _sparse.RowContext(
-                W0, ids, sparse_emb.sparse_grad_capacity)
-        if ctx is not None:
-            params_in = {**params, "layer_0": dict(params["layer_0"],
-                                                   W=ctx.rows_ext)}
-            x_in = ctx.x_sub
-        else:
-            params_in, x_in = params, x
-        ls = state.get(_precision.SCALE_STATE_KEY) \
-            if pol is not None and pol.scaled else None
-        scale = ls["scale"] if ls is not None else None
-
-        # scopes are metadata: under value_and_grad the forward's
-        # operations are named jvp(forward)/<layer>/..., the backward's
-        # transpose(jvp(forward))/<layer>/...
-        @jax.named_scope("forward")
-        def loss_fn(p):
-            if cast_map:
-                # mixed precision: cast params per layer for the traced
-                # stack; grads w.r.t. the f32 masters accumulate in f32
-                # (the cast is part of the differentiated program)
-                p = {k: (_cast_floats(v, cast_map[k]) if k in cast_map
-                         else v) for k, v in p.items()}
-            if with_carry:
-                # carry state flows INTO the chunk; gradients do not flow
-                # back across the chunk boundary (tBPTT truncation).
-                cs = dict(jax.tree_util.tree_map(jax.lax.stop_gradient,
-                                                 carries))
-                loss, new_state = _stack_loss(
-                    conf, p, state, x_in, y, train=True, key=key,
-                    mask=mask, label_mask=label_mask, carries=cs,
-                    precision=pol)
-            else:
-                cs = None
-                loss, new_state = _stack_loss(
-                    conf, p, state, x_in, y, train=True, key=key,
-                    mask=mask, label_mask=label_mask, precision=pol)
-            # loss scaling happens on the objective so the whole backward
-            # pass sees scaled gradients (fp16 underflow protection); the
-            # reported loss stays unscaled
-            obj = loss * scale if scale is not None else loss
-            return obj, (loss, new_state, cs)
-        (_obj, (loss, new_state, new_carries)), grads = \
-            jax.value_and_grad(loss_fn, has_aux=True)(params_in)
-        if ctx is not None:
-            # the densified carrier: coalesced row indices + values (the
-            # custom-vjp lookup's segment-summed cotangent), in place of
-            # a dense table gradient
-            grads = dict(grads)
-            grads["layer_0"] = dict(grads["layer_0"],
-                                    W=ctx.wrap_grad(grads["layer_0"]["W"]))
-        finite = None
-        with jax.named_scope("grad_post"):
-            if scale is not None:
-                grads, finite = _precision.unscale_and_check(grads, scale)
-            grads = apply_gradient_norm_all(grads, confs, gn_mode, gn_thr)
-            # per-iteration gradient stats for listeners (reference
-            # ParamAndGradientIterationListener / StatsListener): computed
-            # inside the same program so they fuse with the update.  Float
-            # leaves only (_common.float_grad_leaves): SparseRows carries
-            # int32 indices, and coalesced values give the SAME norm the
-            # dense gradient would.
-            gleaves = float_grad_leaves(grads)
-            gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in gleaves)) \
-                if gleaves else jnp.zeros((), jnp.float32)
-            glayer = {k: jnp.sqrt(sum(jnp.sum(g * g)
-                                      for g in float_grad_leaves(v)))
-                      for k, v in grads.items() if v}
-        with jax.named_scope("optimizer"):
-            if ctx is not None:
-                # lazy row-space update: the SAME optax transform runs on
-                # [capacity, dim] views — touched rows of the table and of
-                # every param-shaped mirror leaf (mu/nu/trace) — then only
-                # those rows scatter back.  Untouched rows and mirrors keep
-                # their pre-step bytes.
-                g_upd = dict(grads)
-                g_upd["layer_0"] = dict(g_upd["layer_0"],
-                                        W=g_upd["layer_0"]["W"].values)
-                p_upd = {**params, "layer_0": dict(params["layer_0"],
-                                                   W=ctx.rows)}
-                opt_upd = _sparse.gather_rows_tree(opt_state, ctx)
-            else:
-                g_upd, p_upd, opt_upd = grads, params, opt_state
-            updates, new_opt = tx.update(g_upd, opt_upd, p_upd)
-            new_params = optax.apply_updates(p_upd, updates)
-            if ctx is not None:
-                new_params = {**new_params, "layer_0": dict(
-                    new_params["layer_0"],
-                    W=ctx.scatter_rows(params["layer_0"]["W"],
-                                       new_params["layer_0"]["W"]))}
-                new_opt = _sparse.scatter_rows_tree(opt_state, new_opt, ctx)
-            new_params = apply_constraints_all(new_params, confs)
-        if pol is not None:
-            # keep running state (BN statistics) in f32 so the step's
-            # input/output treedefs+dtypes stay fixed across iterations
-            new_state = _cast_floats(new_state, jnp.float32,
-                                     only=pol.compute_dtype)
-        gstats = {"global_norm": gnorm, "layer_norms": glayer}
-        if ctx is not None:
-            # observability: how many real table rows this step exchanged
-            # (vs the static capacity) — the densification win, visible
-            # to listeners without a host sync
-            gstats["embedding_rows_touched"] = ctx.touched()
-        if ls is not None:
-            new_params, new_opt, new_state, sel = _precision.overflow_skip(
-                pol, ls, finite, params, new_params, opt_state, new_opt,
-                state, new_state, gstats)
-            if with_carry:
-                # the overflowed forward also poisoned the recurrent
-                # carries — a skipped chunk must hand the NEXT chunk its
-                # pre-step carries, or one overflow taints the rest of
-                # the sequence
-                new_carries = sel(new_carries, carries)
-        if with_carry:
-            return (new_params, new_state, new_opt, new_rng, loss, gstats,
-                    new_carries)
-        return new_params, new_state, new_opt, new_rng, loss, gstats
-
-    return step
+    return build_train_step(
+        functools.partial(_stack_loss, conf, train=True), conf.defaults,
+        confs, compute_dtypes(conf.defaults, confs), tx,
+        sparse=None if sparse_emb is None else ("layer_0", sparse_emb),
+        with_carry=with_carry)
 
 
 def _build_pretrain_step(conf, tx, i: int):
@@ -641,8 +314,8 @@ def _build_pretrain_step(conf, tx, i: int):
     lc = conf.layers[i]
 
     def step(p_i, opt_state, key, x, frozen, state):
-        # fused RNG succession (see _build_train_step): the host-side
-        # split moves into the program; the successor key is returned
+        # fused RNG succession (see _common.build_train_step): the split
+        # happens inside the program; the successor key is returned
         new_rng, key = jax.random.split(key)
 
         def loss_fn(pp):
@@ -702,8 +375,9 @@ class MultiLayerNetwork:
         # trace events, so a clone's cache-hit first step reads steady and
         # a mid-fit retrace (new shape/treedef) reads compile
         self._last_step_traced = False
-        # per-fit StepProfiler, attached by fit() so _fit_one can credit
-        # its h2d/listener slices; None outside a profiled fit
+        # the running fit's StepProfiler (``_common.fit_batches`` attaches
+        # it; ``placed`` and ``finish_step`` credit it their slices); None
+        # outside a profiled fit
         self._stepprof = None
 
     # ------------------------------------------------------------------ init
@@ -914,7 +588,8 @@ class MultiLayerNetwork:
         ``CheckpointManager`` — restores full training state and resumes
         mid-epoch at the exact saved batch cursor, reproducing the
         uninterrupted run's params (checkpointing is RNG-neutral, so runs
-        with and without it are byte-identical)."""
+        with and without it are byte-identical).  The loop itself is
+        ``nn/_common.fit_batches``."""
         from ..data.dataset import DataSet
         if self.params == {}:
             self.init()
@@ -971,210 +646,11 @@ class MultiLayerNetwork:
         if checkpoint is not None or resume_from is not None:
             from ..faulttolerance.checkpoint import FitCheckpointer
             ckpt = FitCheckpointer(self, checkpoint, resume_from)
-        step_fn = self._get_jitted("train_step")
-        span = get_tracer().span
-        # observability (cheap by default: plain host float math per
-        # step, instruments resolved once per fit, and the step timing
-        # closes on the loss sync _fit_one/_fit_tbptt already perform —
-        # no extra device sync is ever forced here; a disabled registry
-        # reduces all of it to one bool check)
-        reg = default_registry()
-        obs = reg.enabled
-        # runtime forensics: the flight recorder keeps the recent-step
-        # window for crash dumps; the health monitor (when installed)
-        # watches the step signals for NaNs/spikes/throughput collapse
-        from ..observability.health import get_health_monitor
-        from ..observability.profiler import step_profiler_for
-        from ..observability.recorder import get_flight_recorder
-        rec = get_flight_recorder()
-        rec_on = rec is not None and rec.enabled
-        mon = get_health_monitor()
-        forensics = _StepForensics(self, rec, mon, ckpt) \
-            if (rec_on or mon is not None) else None
-        # per-step phase attribution (etl/h2d/dispatch/device/listener/
-        # forensics/checkpoint) with a SAMPLED device fence — steady
-        # unsampled steps stay fully async (the host-sync sweep holds)
-        prof = step_profiler_for("train_step")
-        self._stepprof = prof
-
-        # bounded async dispatch (ISSUE 18): the host may run up to
-        # DL4J_TPU_DISPATCH_DEPTH (default 2) steps ahead of the device,
-        # overlapping step N+1's ETL/padding/h2d/bookkeeping with step
-        # N's execution.  Drains at epoch ends and checkpoint boundaries
-        # keep exact-resume parity; every drained token is NaN-checked
-        # with ITS OWN iteration so deferred device failures surface
-        # within the window bound, correctly attributed.
-        def _nan_at_drain(iteration, value):
-            if rec_on:
-                rec.record("train", "nan_at_drain", score=value,
-                           iteration=int(iteration))
-        win = DispatchWindow(owner=self, profiler=prof,
-                             on_nan=_nan_at_drain)
-        if obs:
-            steps_c = reg.counter("training_steps_total",
-                                  "Optimizer steps taken")
-            examples_c = reg.counter("training_examples_total",
-                                     "Training examples consumed")
-            step_h = reg.histogram(
-                "training_step_seconds",
-                "Train step wall time, split compile vs steady",
-                ("phase",), buckets=_STEP_BUCKETS)
-            etl_fetch_h = reg.histogram(
-                "training_etl_seconds",
-                "Time blocked on the data pipeline per batch, by stage",
-                ("stage",), buckets=_ETL_BUCKETS).labels("fetch")
-            step_compile_h = step_h.labels("compile")
-            step_steady_h = step_h.labels("steady")
-        steady_examples, steady_s = 0, 0.0
-        start_epoch = ckpt.start_epoch if ckpt is not None else 0
-        stop = False
-        try:
-            for ep in range(start_epoch, epochs):
-                for lst in self.listeners:
-                    lst.on_epoch_start(self)
-                batches = iter(batches_factory())
-                # resume cursor: the first resumed epoch skips the batches
-                # the checkpointed run already consumed (the data-pipeline
-                # seq cursor) WITHOUT fitting or touching the RNG, so the
-                # resumed stream lines up with the uninterrupted run's
-                skip = ckpt.skip_batches \
-                    if (ckpt is not None and ep == ckpt.start_epoch) else 0
-                seq = 0
-                while True:
-                    t_etl = time.perf_counter()
-                    with span("dl4j.input_wait"):
-                        batch = next(batches, None)
-                    # ETL/compute boundary timing (reference lastEtlTime,
-                    # MultiLayerNetwork.java:1203-1209): time blocked on the
-                    # data pipeline, visible to PerformanceListener
-                    self.last_etl_ms = (time.perf_counter() - t_etl) * 1e3
-                    if batch is None:
-                        break
-                    if seq < skip:
-                        seq += 1
-                        continue
-                    x, y, m, lm = batch
-                    self.last_batch_size = int(getattr(x, "shape", (0,))[0])
-                    t_step = monotonic_s()
-                    if prof is not None:
-                        prof.begin(t_step, self.last_etl_ms * 1e-3)
-                    if self.conf.backprop_type == "tbptt" and \
-                            getattr(x, "ndim", 2) == 3 and \
-                            x.shape[1] > self.conf.tbptt_fwd_length:
-                        self._fit_tbptt(step_fn, x, y, m, lm)
-                    else:
-                        self._fit_one(x, y, m, lm)
-                    if prof is not None:
-                        prof.dispatched(self._score, window=win)
-                    compile_step = self._last_step_traced
-                    t_end = monotonic_s()
-                    dt = t_end - t_step
-                    if obs:
-                        (step_compile_h if compile_step
-                         else step_steady_h).observe(dt)
-                        etl_fetch_h.observe(self.last_etl_ms / 1e3)
-                        steps_c.inc()
-                        examples_c.inc(self.last_batch_size)
-                        if not compile_step:
-                            steady_examples += self.last_batch_size
-                            steady_s += dt
-                    seq += 1
-                    if forensics is not None and \
-                            forensics.step(ep, seq, compile_step, dt,
-                                           t_end):
-                        stop = True   # opt-in health stop: clean return
-                    if prof is not None:
-                        prof.lap("forensics")
-                    if not stop and ckpt is not None:
-                        if ckpt.due():
-                            # checkpoint boundary: materialize the whole
-                            # window so the save captures finished steps
-                            # and mid-window resume stays digest-exact
-                            win.drain()
-                        if ckpt.after_batch(ep, seq):
-                            stop = True   # SIGTERM: final save — return
-                    if prof is not None:
-                        if ckpt is not None:
-                            prof.lap("checkpoint")
-                        prof.end(self.iteration, compile_step)
-                    if stop:
-                        break
-                    # admit this step into the in-flight window (blocks on
-                    # the oldest step once the window is full — the
-                    # bounded-pipeline backpressure point)
-                    win.push(self._score, self.iteration)
-                if stop:
-                    break
-                # ONE materialization per epoch (fit_on_device's sync
-                # convention): steps pipelined async all epoch; epoch-end
-                # listeners (MetricsListener score/grad-norm) see a host
-                # float without forcing their own sync
-                win.drain()
-                with span("dl4j.sync"):
-                    self._score = float(self._score)
-                if prof is not None:
-                    prof.materialized()
-                for lst in self.listeners:
-                    lst.on_epoch_end(self)
-                self.epoch += 1
-                if ckpt is not None and ckpt.after_epoch(ep):
-                    stop = True
-                    break
-            # stop-path exits (health stop, SIGTERM) break before the
-            # epoch-end drain; materialize what's still in flight so the
-            # drained-score bookkeeping is consistent on clean returns
-            win.drain()
-        except Exception as e:
-            # never block on in-flight work while unwinding — the final
-            # un-guarded float(_score) convention still surfaces deferred
-            # device failures for callers that catch and continue
-            win.abandon()
-            # unhandled fit exception: commit the flight-recorder window
-            # BEFORE propagating — the artifact that explains the crash
-            # must exist even if the process dies on the way up
-            if rec_on:
-                if forensics is not None:
-                    try:
-                        forensics.flush()
-                    except Exception:
-                        pass   # forensics must not mask the real error
-                rec.record("train", "fit_exception",
-                           error=f"{type(e).__name__}: {e}",
-                           iteration=int(self.iteration))
-                rec.maybe_dump(
-                    "fit_exception",
-                    directory=(ckpt.manager.directory
-                               if ckpt is not None and ckpt.manager
-                               is not None else None))
-            raise
-        finally:
-            if forensics is not None:
-                try:
-                    forensics.flush()
-                except Exception:
-                    pass
-            if prof is not None:
-                self._stepprof = None
-                try:
-                    prof.flush()
-                except Exception:
-                    pass   # profile telemetry must not mask the real error
-            if ckpt is not None:
-                ckpt.close()
-        # ONE materialization for the whole fit: _fit_one keeps _score
-        # as the async device scalar so steps pipeline.  NOT
-        # exception-guarded: this float() is where deferred device-side
-        # failures first surface, and they must propagate
-        with span("dl4j.sync"):
-            self._score = float(self._score)
-        if obs and steady_s > 0:
-            # steady-state throughput: the compile-dominated first step
-            # is excluded (same convention as utils/benchmarks.py)
-            reg.gauge("training_examples_per_sec",
-                      "Training examples/sec over the last fit() "
-                      "(compile excluded where the path can tell)"
-                      ).set(steady_examples / steady_s)
-        return self
+        return fit_batches(
+            self, batches_factory, epochs, self._prepare,
+            self._get_jitted("train_step"), ckpt=ckpt,
+            walk=self._fit_tbptt if self.conf.backprop_type == "tbptt"
+            else None)
 
     @training_entry("dl4j.fit_on_device")
     def fit_on_device(self, x, y, *, batch_size: int, epochs: int = 1,
@@ -1223,15 +699,22 @@ class MultiLayerNetwork:
             fit_tail=lambda xt, yt: self._fit_one(xt[0], yt[0], None, None),
             ckpt=ckpt)
 
-    def _fit_tbptt(self, step_fn, x, y, mask, label_mask):
+    def _fit_tbptt(self, batch) -> bool:
         """Truncated BPTT (reference ``doTruncatedBPTT``,
         MultiLayerNetwork.java:1393): split the time axis into
         tbptt_fwd_length chunks; recurrent state (h, c) carries across chunk
         boundaries with gradients stopped at each boundary — so the backward
         window equals the forward chunk, the reference's default fwd==back
         configuration.  ``tbptt_back_length`` is accepted for config parity.
+
+        The fit loop's ``walk``: false, and nothing done, for a batch no
+        longer than one chunk (the plain step trains it).
         """
-        del step_fn  # tbptt uses the carry-aware step
+        x, y, mask, label_mask = batch
+        if getattr(x, "ndim", 2) != 3 or \
+                x.shape[1] <= self.conf.tbptt_fwd_length:
+            return False
+        self.last_batch_size = int(x.shape[0])
         self._validate_input_ids(x)
         step = self._get_jitted("train_step_carry")
         pol = self.shape_policy
@@ -1243,8 +726,8 @@ class MultiLayerNetwork:
                 x, y, mask, label_mask, path="tbptt")
         L = self.conf.tbptt_fwd_length
         T = x.shape[1]
-        batch = x.shape[0]
-        carries = self._init_carries(batch)
+        rows = x.shape[0]
+        carries = self._init_carries(rows)
         # one device placement per BATCH, not per chunk (JX012: the
         # transfer belongs outside the loop); chunk slices below are
         # device-side views of these arrays
@@ -1273,7 +756,7 @@ class MultiLayerNetwork:
                 pad = L - (sl.stop - sl.start)
                 xc_len = sl.stop - sl.start
                 xm = xm if xm is not None else jnp.ones(
-                    (batch, xc_len), jnp.float32)
+                    (rows, xc_len), jnp.float32)
                 xm = _pad_time(xm, pad)
                 if ym is not None and getattr(ym, "ndim", 1) == 2:
                     ym = _pad_time(ym, pad)
@@ -1283,22 +766,16 @@ class MultiLayerNetwork:
                 x_chunk = xc
             else:
                 x_chunk = x[:, sl]
-            (self.params, self.state, self.opt_state, self._rng, loss,
-             gstats, carries) = step(
+            *out, carries = step(
                 self.params, self.state, self.opt_state, self._rng,
                 x_chunk, yc, xm, ym, carries)
-            traced = traced or step.last_call_traced
-            # device scalar inside the chunk loop: a float() here would
-            # host-sync every chunk, serializing tBPTT windows against
-            # dispatch RTT; listeners reading get_score() materialize it
-            self._score = loss
-            self._last_grad_stats = gstats
-            self.iteration += 1
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, self.epoch)
+            # the score stays a device scalar inside the chunk loop
+            finish_step(self, step, out)
+            traced = traced or self._last_step_traced
         # one sync per batch, so deferred device failures surface in fit
         self._score = float(self._score)
         self._last_step_traced = traced
+        return True
 
     def _init_carries(self, batch: int):
         """Zero carries for every recurrent layer (keyed ``layer_i``)."""
@@ -1393,51 +870,28 @@ class MultiLayerNetwork:
         for b in data:
             yield b if hasattr(b, "shape") else self._normalize_batch(b)[0]
 
-    def _fit_one(self, x, y, m, lm):
-        """One train step (shared by fit's inner loop and fit_batch).
-
-        Returns (and leaves in ``_score``) the still-ASYNC device loss
-        scalar: the per-step ``float()`` here was the last unconditional
-        host sync in the hot fit loop — it stalled the dispatch pipeline
-        once per step for a value nothing reads until a listener or
-        forensics flush asks (the lifetime audit's host-sync sweep).
-        ``fit_batch``/``get_score`` materialize on demand; the fit loop
-        materializes once at the end."""
+    def _prepare(self, batch):
+        """Validate, pad and place one batch: the train step's four batch
+        arguments (the fit loop's ``prepare``)."""
+        x, y, m, lm = batch
+        self.last_batch_size = int(getattr(x, "shape", (0,))[0])
         self._validate_input_ids(x)
-        step_fn = self._get_jitted("train_step")
         pol = self.shape_policy
         if pol is not None and pol.enabled and self._pad_train_safe():
             # ragged batches (partial epoch tails) pad onto an
             # already-compiled bucket; padded rows are loss-masked so the
             # step is numerically the unpadded one (data/shapes.py)
             x, y, m, lm = pol.pad_train_batch(x, y, m, lm)
-        prof = self._stepprof
-        if prof is not None:
-            _t = monotonic_s()
-        with get_tracer().span("dl4j.h2d"):
-            x, y, m, lm = (_on_device(x), _on_device(y), _on_device(m),
-                           _on_device(lm))
-        if prof is not None:
-            prof.mark("h2d", monotonic_s() - _t)
-        # fused-RNG step: the key split happens inside the program and the
-        # successor key comes back as an output (bit-identical sequence to
-        # the host-side split this replaces; one less dispatch per step)
-        (self.params, self.state, self.opt_state, self._rng, loss,
-         gstats) = step_fn(
-            self.params, self.state, self.opt_state, self._rng, x, y, m, lm)
-        self._score = loss
-        self._last_grad_stats = gstats
-        self._last_step_traced = bool(getattr(step_fn, "last_call_traced",
-                                              False))
-        self.iteration += 1
-        if prof is None:
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, self.epoch)
-        else:
-            _t = monotonic_s()
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, self.epoch)
-            prof.mark("listener", monotonic_s() - _t)
+        return placed(self, _on_device, (x, y, m, lm))
+
+    def _fit_one(self, x, y, m, lm):
+        """One train step outside a fit loop (``fit_batch``,
+        ``fit_on_device``'s ragged tail), from the loop's two pieces;
+        returns the still-async loss (``_common.finish_step``)."""
+        step = self._get_jitted("train_step")
+        args = self._prepare((x, y, m, lm))
+        finish_step(self, step, step(
+            self.params, self.state, self.opt_state, self._rng, *args))
         return self._score
 
     def fit_batch(self, batch) -> float:

@@ -8,8 +8,8 @@ coalesce the rows a batch actually touches and exchange fixed-capacity
 index + value blocks instead of the dense table, so per-step comms go
 from O(vocab·dim) to O(touched_rows·dim).
 
-The mechanism (wired into ``nn/multilayer._build_train_step`` when an
-embedding layer declares ``sparse_grad=True``):
+The mechanism (wired into ``nn/_common.build_train_step`` when the
+stack's first layer, an embedding, declares ``sparse_grad=True``):
 
 1. **Coalesce outside the gradient** — :func:`coalesce` computes the
    sorted unique touched row ids (``jnp.unique`` with a STATIC

@@ -198,12 +198,12 @@ def live_device_bytes() -> Optional[int]:
 class StepProfiler:
     """Per-step phase attribution for one fit/serve loop.
 
-    Hot-path protocol (the fit loops drive it; every call is a couple of
-    ``perf_counter`` reads and float math — no allocation, no locks, no
-    device access on unsampled steps)::
+    Hot-path protocol (``nn/_common.fit_batches`` drives it; every call
+    is a couple of ``perf_counter`` reads and float math — no allocation,
+    no locks, no device access on unsampled steps)::
 
         prof.begin(t_step, etl_s)      # loop's existing step-start read
-        prof.mark("h2d", dt)           # inner slices, from _fit_one
+        prof.mark("h2d", dt)           # inner slices: placed, finish_step
         prof.mark("listener", dt)
         prof.dispatched(loss)          # async dispatch returned; maybe
                                        #   fence (sampled): device slice,
@@ -491,7 +491,7 @@ class StepProfiler:
 
 
 def step_profiler_for(program: str, **kwargs) -> Optional[StepProfiler]:
-    """The fit loops' entry point: a fresh profiler, or None when
+    """The fit loop's entry point: a fresh profiler, or None when
     ``DL4J_TPU_STEPPROF=0`` — and never an exception, because telemetry
     must not break training.  ``DL4J_TPU_STEPPROF_PROGRAM`` overrides
     the label (mapping a run onto its canonical card/budget entry)."""

@@ -22,9 +22,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import (DATA_AXIS, MODEL_AXIS, batch_spec, make_mesh,
                    place_sharded, shard_batch, zero3_spec)
-from ..observability.clock import monotonic_s
-from ..observability.registry import default_registry
-from ..observability.tracer import get_tracer
+from ..nn._common import finish_step, fit_batches, placed
+from ..observability.tracer import training_entry
 
 
 def _param_specs(params, rule: Optional[Callable[[str, str, Any], P]]):
@@ -195,23 +194,25 @@ class ParallelWrapper:
         trimmed = self._trim(m._normalize_batch(batch))
         if trimmed is None:    # sub-shard batch: nothing to step on
             return m._score
-        x, y, mk, lmk = trimmed
+        step = self._get_step()
+        args = self._prepare(trimmed)
+        finish_step(m, step, step(m.params, m.state, m.opt_state, m._rng,
+                                  *args))
+        m._score = float(m._score)
+        return m._score
+
+    def _prepare(self, batch):
+        """Validate one trimmed batch and shard it over the mesh: the
+        train step's four batch arguments (the fit loop's ``prepare``)."""
+        m = self.model
+        x = batch[0]
         if hasattr(m, "_validate_input_ids"):
             # embedding-first boundary validation (the traced gather
             # clamps out-of-range ids silently)
             m._validate_input_ids(x)
-        put = self._put
-        # fused-RNG step: splits the key inside the program (bit-identical
-        # to the host split it replaces) and returns the successor
-        m.params, m.state, m.opt_state, m._rng, loss, \
-            m._last_grad_stats = \
-            self._get_step()(m.params, m.state, m.opt_state, m._rng,
-                             put(x), put(y), put(mk), put(lmk))
-        m._score = float(loss)
-        m.iteration += 1
-        for lst in m.listeners:
-            lst.iteration_done(m, m.iteration, m.epoch)
-        return m._score
+        first = x[0] if isinstance(x, (list, tuple)) else x
+        m.last_batch_size = int(getattr(first, "shape", (0,))[0])
+        return placed(m, self._put, batch)
 
     def _data_axis_size(self) -> int:
         return int(np.prod([self.mesh.shape[a] for a in (DATA_AXIS,)
@@ -267,119 +268,34 @@ class ParallelWrapper:
         return self._step
 
     # ------------------------------------------------------------------
+    @training_entry("dl4j.fit")
     def fit(self, data=None, labels=None, *, epochs: int = 1,
             mask=None, label_mask=None):
         """Shard each batch over the mesh then run the jitted SPMD step.
         Same contract as ``MultiLayerNetwork.fit``: (x, y) arrays or an
-        iterable/iterator of batches, optional masks, multiple epochs."""
+        iterable/iterator of batches, optional masks, multiple epochs;
+        the loop is the model's own (``nn/_common.fit_batches``), so on a
+        ZeRO-3 layout the dispatch window is what lets the NEXT step's
+        host work overlap the in-flight step's all-gather + compute."""
         m = self.model
-        put = self._put
         if labels is not None:
-            batches_factory = lambda: [(data, labels, mask, label_mask)]
+            src = [(data, labels, mask, label_mask)]
         elif hasattr(data, "reset") or hasattr(data, "__iter__"):
             src = data
             if not hasattr(src, "reset") and epochs > 1 and iter(src) is src:
                 src = [m._normalize_batch(b) for b in src]
-
-            def batches_factory():
-                if hasattr(src, "reset"):
-                    src.reset()
-                for b in src:
-                    yield m._normalize_batch(b)
         else:
             raise ValueError("fit() needs (x, y) or an iterator")
-        step = self._get_step()
-        # observability: counters only inside the loop (per-step TIMING
-        # would need a host sync each step — deliberately absent; the
-        # span below closes after the final score sync, so its duration
-        # is honest end-to-end wall time)
-        reg = default_registry()
-        obs = reg.enabled
-        if obs:
-            steps_c = reg.counter("training_steps_total",
-                                  "Optimizer steps taken")
-            examples_c = reg.counter("training_examples_total",
-                                     "Training examples consumed")
-        # phase attribution with a SAMPLED fence (observability/profiler):
-        # unsampled steps keep the zero-per-step-sync contract above —
-        # only every sample_every-th step pays one block_until_ready
-        from ..observability.profiler import step_profiler_for
-        prof = step_profiler_for("train_step")
-        # bounded async dispatch (ISSUE 18; see MultiLayerNetwork.fit):
-        # the host runs up to DL4J_TPU_DISPATCH_DEPTH steps ahead of the
-        # mesh — on a ZeRO-3 layout this is what lets the NEXT step's
-        # host work overlap the in-flight step's all-gather + compute
-        from ..nn.dispatch import DispatchWindow
-        win = DispatchWindow(owner=m, profiler=prof)
-        n_examples = 0
-        t_fit = monotonic_s()
-        with get_tracer().span("wrapper.fit", epochs=epochs,
-                               devices=len(self.mesh.devices.flat)):
-            for _ in range(epochs):
-                for lst in m.listeners:
-                    lst.on_epoch_start(m)
-                for raw in batches_factory():
-                    trimmed = self._trim(raw)
-                    if trimmed is None:
-                        continue
-                    x, y, mk, lmk = trimmed
-                    if hasattr(m, "_validate_input_ids"):
-                        m._validate_input_ids(x)
-                    if prof is not None:
-                        prof.begin(monotonic_s())
-                        _t = monotonic_s()
-                    xd, yd, mkd, lmkd = put(x), put(y), put(mk), put(lmk)
-                    if prof is not None:
-                        prof.mark("h2d", monotonic_s() - _t)
-                    # fused-RNG step: key split happens in the program;
-                    # the successor key comes back as an output
-                    (m.params, m.state, m.opt_state, m._rng, loss,
-                     m._last_grad_stats) = step(
-                        m.params, m.state, m.opt_state, m._rng,
-                        xd, yd, mkd, lmkd)
-                    # device scalar inside the batch loop (a float() here
-                    # would host-sync every step); get_score() materializes
-                    # on demand
-                    m._score = loss
-                    m.iteration += 1
-                    if prof is not None:
-                        prof.dispatched(loss, window=win)
-                    if obs:
-                        steps_c.inc()
-                        xb = x[0] if isinstance(x, (list, tuple)) else x
-                        bs = int(getattr(xb, "shape", (0,))[0])
-                        examples_c.inc(bs)
-                        n_examples += bs
-                    if prof is None:
-                        for lst in m.listeners:
-                            lst.iteration_done(m, m.iteration, m.epoch)
-                    else:
-                        _t = monotonic_s()
-                        for lst in m.listeners:
-                            lst.iteration_done(m, m.iteration, m.epoch)
-                        prof.mark("listener", monotonic_s() - _t)
-                        prof.end(m.iteration)
-                    # bounded-pipeline backpressure point
-                    win.push(m._score, m.iteration)
-                # epoch boundary drains the window (one-sync-per-epoch
-                # listener cadence, same as the single-device fit)
-                win.drain()
-                for lst in m.listeners:
-                    lst.on_epoch_end(m)
-                m.epoch += 1
-            # one final sync: "fit returned" still means "training finished",
-            # and deferred device failures surface here instead of downstream
-            m._score = float(m._score)
-            if prof is not None:
-                prof.materialized()
-                prof.flush()
-        if obs and n_examples:
-            # whole-fit throughput, fetch-closed by the score sync above
-            dt = max(monotonic_s() - t_fit, 1e-9)
-            reg.gauge("training_examples_per_sec",
-                      "Training examples/sec over the last fit() "
-                      "(compile excluded where the path can tell)"
-                      ).set(n_examples / dt)
+
+        def batches_factory():
+            if hasattr(src, "reset"):
+                src.reset()
+            for b in src:
+                trimmed = self._trim(m._normalize_batch(b))
+                if trimmed is not None:
+                    yield trimmed
+        fit_batches(m, batches_factory, epochs, self._prepare,
+                    self._get_step())
         return self
 
     def average_params(self):

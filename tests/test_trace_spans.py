@@ -22,6 +22,8 @@ from deeplearning4j_tpu.nn.conf.updaters import Adam
 from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.observability.registry import default_registry
+from deeplearning4j_tpu.parallel import (ParallelWrapper, ShardedTrainer,
+                                         make_mesh)
 from deeplearning4j_tpu.train.listeners import TrainingListener
 
 STEPS = 18          # the step profiler fences every 16th step of a fit
@@ -51,6 +53,20 @@ def graph_net(seed=42):
 
 
 NETS = {"stack": stack_net, "graph": graph_net}
+
+
+def wrapper_net(seed=42):
+    return ParallelWrapper(stack_net(seed), make_mesh(dp=2))
+
+
+def sharded_net(seed=42):
+    return ShardedTrainer(stack_net(seed), make_mesh(dp=2),
+                          min_shard_size=0)
+
+
+# everything with a ``fit``: the two containers and the two mesh wrappers
+# (two of the rig's eight host devices); one loop runs them all
+FITS = {**NETS, "wrapper": wrapper_net, "sharded": sharded_net}
 
 
 def batches(n=STEPS, batch=8, seed=0):
@@ -105,9 +121,9 @@ def collector_callbacks():
 
 
 # ------------------------------------------------------------------ fit
-@pytest.mark.parametrize("kind", sorted(NETS))
+@pytest.mark.parametrize("kind", sorted(FITS))
 def test_fit_writes_every_span_under_the_entry(kind, recorded):
-    net = NETS[kind]()
+    net = FITS[kind]()
     net.fit(batches())
     names = recorded.names()
     assert names[0] == "dl4j.fit" and recorded.parents("dl4j.fit") == {None}
@@ -200,9 +216,9 @@ def test_a_collection_is_a_span_and_adds_to_the_pause_counter(recorded):
     assert not collector_callbacks()
 
 
-@pytest.mark.parametrize("kind", sorted(NETS))
+@pytest.mark.parametrize("kind", sorted(FITS))
 def test_the_collectors_callback_goes_when_fit_raises(kind):
-    net = NETS[kind]()
+    net = FITS[kind]()
 
     def broken():
         yield batches(1)[0]
@@ -211,10 +227,11 @@ def test_the_collectors_callback_goes_when_fit_raises(kind):
     with pytest.raises(RuntimeError, match="the iterator broke"):
         net.fit(broken())
     assert not collector_callbacks()
-    with pytest.raises(ValueError):
-        x, y = batches(1, batch=4)[0]
-        net.fit_on_device(x, y, batch_size=8)     # larger than the data
-    assert not collector_callbacks()
+    if kind in NETS:
+        with pytest.raises(ValueError):
+            x, y = batches(1, batch=4)[0]
+            net.fit_on_device(x, y, batch_size=8)  # larger than the data
+        assert not collector_callbacks()
 
 
 def test_nested_entries_share_one_callback():
@@ -235,14 +252,98 @@ def test_nested_entries_share_one_callback():
     assert seen == [1, 1, 1] and not collector_callbacks()
 
 
+# ------------------------------------------- one loop, one step builder
+def test_a_step_that_raises_in_the_wrappers_fit_leaves_nothing_behind(
+        monkeypatch):
+    """The wrapper's ``fit`` is the one loop: a step that raises abandons
+    the window's tokens without waiting for them and flushes the profiler
+    on the way out."""
+    from deeplearning4j_tpu.nn import _common
+    from deeplearning4j_tpu.observability import profiler as profiler_mod
+    windows, profilers = [], []
+
+    class Window(_common.DispatchWindow):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            windows.append(self)
+
+    def profiler_for(program, **kwargs):
+        profilers.append(profiler_mod.StepProfiler(program, **kwargs))
+        return profilers[-1]
+
+    monkeypatch.setattr(_common, "DispatchWindow", Window)
+    monkeypatch.setattr(profiler_mod, "step_profiler_for", profiler_for)
+    wrapper = wrapper_net()
+    step, calls = wrapper._get_step(), []
+
+    def third_call_raises(*args):
+        calls.append(1)
+        if len(calls) == 3:
+            raise RuntimeError("the step broke")
+        return step(*args)
+
+    wrapper._step = third_call_raises
+    with pytest.raises(RuntimeError, match="the step broke"):
+        wrapper.fit(batches(6))
+    (window,), (prof,) = windows, profilers
+    assert wrapper.model.iteration == 2
+    assert len(window) == 0
+    assert prof.steps == 2 and prof._buf == []
+    assert wrapper.model._stepprof is None
+    assert not collector_callbacks()
+
+
+@pytest.mark.parametrize("kind", sorted(FITS))
+def test_every_fit_counts_its_steps_and_clocks_the_feed(kind):
+    from deeplearning4j_tpu.observability.registry import (
+        MetricsRegistry, set_default_registry)
+    previous = set_default_registry(MetricsRegistry())
+    try:
+        net = FITS[kind]()
+        net.fit(batches(5, batch=8))
+        reg = default_registry()
+        assert reg.get("training_steps_total").value == 5
+        assert reg.get("training_examples_total").value == 40
+        steps = reg.get("training_step_seconds")
+        assert steps.labels("compile").count \
+            + steps.labels("steady").count == 5
+        assert reg.get("training_etl_seconds").labels("fetch").count == 5
+    finally:
+        set_default_registry(previous)
+    model = getattr(net, "model", net)
+    assert model.iteration == 5 and model.last_batch_size == 8
+    assert model.last_etl_ms >= 0.0
+
+
+def test_a_stack_and_a_graph_of_the_same_layers_train_alike():
+    """One builder: the same dense net written as a stack and as a graph,
+    from one seed over the same batches, holds the same parameters bit for
+    bit after three steps."""
+    stack, graph = stack_net(seed=23), graph_net(seed=23)
+    names = {"layer_0": "d0", "layer_1": "out"}
+    for layer, vertex in names.items():
+        for leaf, a in stack.params[layer].items():
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(graph.params[vertex][leaf]))
+    feed = batches(3)
+    stack.fit(feed)
+    graph.fit(feed)
+    assert stack.iteration == graph.iteration == 3
+    assert stack.get_score() == graph.get_score()
+    for layer, vertex in names.items():
+        for leaf, a in stack.params[layer].items():
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(graph.params[vertex][leaf]))
+
+
 # ------------------------------------------------- nothing else changes
-@pytest.mark.parametrize("kind", sorted(NETS))
+@pytest.mark.parametrize("kind", sorted(FITS))
 def test_spans_change_no_number(kind, recorded, monkeypatch):
-    stubbed = NETS[kind]()
+    stubbed = FITS[kind]()
     stubbed.fit(batches())
     assert recorded.spans
     monkeypatch.undo()                  # the profiler's own annotation
-    plain = NETS[kind]()
+    plain = FITS[kind]()
     plain.fit(batches())
     assert plain.get_score() == stubbed.get_score()
     for a, b in zip(leaves(plain), leaves(stubbed)):
