@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import optax
 
 from . import precision as _precision
+from . import scan_layers as _scan_layers
 from . import sparse as _sparse
 from .dispatch import DispatchWindow
 from .layers.base import BaseLayerConf, LayerConf
@@ -323,8 +324,11 @@ def build_train_step(loss, defaults: Dict[str, Any],
             # reported loss stays unscaled
             obj = loss_value * scale if scale is not None else loss_value
             return obj, (loss_value, new_state, cs)
-        (_obj, (loss_value, new_state, new_carries)), grads = \
-            jax.value_and_grad(loss_fn, has_aux=True)(params_in)
+        # a scanned run under remat keeps what fits beside these
+        with _scan_layers.holding(params, state, opt_state, key, x, y, mask,
+                                  label_mask, carries):
+            (_obj, (loss_value, new_state, new_carries)), grads = \
+                jax.value_and_grad(loss_fn, has_aux=True)(params_in)
         if ctx is not None:
             # the densified carrier: coalesced row indices + values (the
             # custom-vjp lookup's segment-summed cotangent), in place of
@@ -864,8 +868,10 @@ def _fit_on_device_epochs(model, xs, ys, batch_size, epochs, shuffle,
                     p, s, o, k, loss, gstats = call_step(p, s, o, k, bx, by)
                     return (p, s, o, k), (loss, gstats)
 
-                (p, s, o, k), (losses, gstats) = jax.lax.scan(
-                    body, (params, state, opt_state, key), perm_steps)
+                # the dataset lies on the device beside every step
+                with _scan_layers.holding(xd, yd):
+                    (p, s, o, k), (losses, gstats) = jax.lax.scan(
+                        body, (params, state, opt_state, key), perm_steps)
                 # listeners see the final step's gradient norms
                 gstats = jax.tree_util.tree_map(lambda a: a[-1], gstats)
                 # the final key is returned (and discarded by the caller)
@@ -925,9 +931,10 @@ def _fit_on_device_epochs(model, xs, ys, batch_size, epochs, shuffle,
                         body, (p, s, o, ek), perm)
                     return (p, s, o, k), losses[-1]
 
-                (p, s, o, k), last_losses = jax.lax.scan(
-                    epoch_body, (params, state, opt_state, key), None,
-                    length=epochs)
+                with _scan_layers.holding(xd, yd):
+                    (p, s, o, k), last_losses = jax.lax.scan(
+                        epoch_body, (params, state, opt_state, key), None,
+                        length=epochs)
                 return p, s, o, k, last_losses
 
             fused = shared_jit((type(model).__name__, sig) + fused_key,

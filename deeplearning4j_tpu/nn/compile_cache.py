@@ -33,6 +33,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
+import logging
 import os
 import threading
 import weakref
@@ -40,9 +41,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
+from . import scan_layers as _scan_layers
 from ..observability.clock import monotonic_s
 from ..observability.registry import default_registry
 from ..observability.tracer import get_tracer
+
+log = logging.getLogger(__name__)
 
 __all__ = ["topology_signature", "shared_jit", "InstrumentedJit",
            "wire_persistent_cache", "persistent_cache_status",
@@ -271,6 +275,9 @@ class InstrumentedJit:
         self._audit_specs: Dict[Tuple, Tuple] = {}
         self._audit_live: Dict[Tuple, Tuple] = {}
         self._audit_lock = threading.Lock()
+        self.fn = self._jitted(fun)
+
+    def _jitted(self, fun: Callable):
         holder_ref = weakref.ref(self)
 
         def traced(*args, **kwargs):
@@ -281,8 +288,31 @@ class InstrumentedJit:
 
         # the program carries the wrapper's name to the compiler (module
         # ``jit_<name>``) and into a profile (scopes start ``jit(<name>)/``)
-        traced.__name__ = traced.__qualname__ = name
-        self.fn = jax.jit(traced, donate_argnums=donate_argnums)
+        traced.__name__ = traced.__qualname__ = self.name
+        return jax.jit(traced, donate_argnums=self._donate)
+
+    def _inputs_alone(self, error, args, kwargs):
+        """The compiler refused, for memory, a program in which a scanned
+        run under ``cache_mode="remat"`` kept names beside its input
+        (``nn/scan_layers``): trace it again, now and at every later
+        shape, with such runs keeping their input alone, which is the
+        program remat had before it kept anything."""
+        fun = self._fun
+
+        def inputs_alone(*a, **k):
+            with _scan_layers.input_alone():
+                return fun(*a, **k)
+        log.warning("%s does not fit the device with the names its remat "
+                    "runs kept; traced again with their inputs alone (%s)",
+                    self.name, str(error).splitlines()[0][:200])
+        reg = default_registry()
+        if reg.enabled:
+            reg.counter("scan_fallbacks_total",
+                        "Programs the compiler refused for memory and "
+                        "that were traced again with remat runs keeping "
+                        "their input alone", ("fn",)).labels(self.name).inc()
+        self.fn = self._jitted(inputs_alone)
+        return self.fn(*args, **kwargs)
 
     def _note_trace(self) -> None:
         self._tls.traced = True
@@ -296,8 +326,15 @@ class InstrumentedJit:
     def __call__(self, *args, **kwargs):
         self._tls.traced = False
         t0 = monotonic_s()
+        kept = _scan_layers.kept_runs()
         with get_tracer().span(self._span_name):
-            out = self.fn(*args, **kwargs)
+            try:
+                out = self.fn(*args, **kwargs)
+            except Exception as e:
+                if _scan_layers.kept_runs() == kept or \
+                        not _scan_layers.refused_for_memory(e):
+                    raise
+                out = self._inputs_alone(e, args, kwargs)
         if _AUDIT_MODE == "all" or (_AUDIT_MODE == "trace"
                                     and self._tls.traced):
             self._record_spec(args, kwargs)

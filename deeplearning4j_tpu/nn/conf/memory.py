@@ -68,7 +68,11 @@ class NetworkMemoryReport:
       + bf16 parameter copy when ``compute_dtype`` is low-precision
       + batch x layer-boundary activations in the compute dtype (an upper
         bound on TPU; remat recomputes only interior intermediates this
-        term never counted, so it does not change the bound).
+        term never counted, so it does not change the bound).  A scanned
+        run under remat also keeps as many of the values its layer names
+        as the device has free (``nn/scan_layers``): memory that this
+        report leaves uncounted because the step would not otherwise use
+        it; on a device that reports no limit, none.
     """
     layer_reports: List[LayerMemoryReport]
     model_class: str
@@ -100,7 +104,9 @@ class NetworkMemoryReport:
             # layer-boundary activations: per-layer jax.checkpoint (remat)
             # saves exactly these and recomputes only interior
             # intermediates, which this term never counted — so the bound
-            # is unchanged by remat (just tighter in practice)
+            # is unchanged by remat (just tighter in practice; what a
+            # scanned remat run keeps by name comes out of the device's
+            # free memory, not out of this bound)
             acts = self.activation_elems_per_example * batch
             b += acts * self.activation_bytes
             return b

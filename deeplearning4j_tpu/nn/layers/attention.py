@@ -224,14 +224,21 @@ def _eva_attention(q, k, v, phi, mu, *, window: int, chunk: int, impl: str,
     with jax.named_scope("eva_pool"):
         kc = k.reshape(b, h, t // chunk, chunk, d)
         vc = v.reshape(b, h, t // chunk, chunk, d)
-        a = jax.nn.softmax(jnp.einsum(
+        # a sixteenth of k and v each: the first names a scanned run
+        # keeps (TransformerBlock.SAVED_NAMES)
+        a = checkpoint_name(jax.nn.softmax(jnp.einsum(
             "bhncd,hd->bhnc", kc, phi.astype(k.dtype),
-            preferred_element_type=acc_dt), axis=-1).astype(k.dtype)
-        ks = (jnp.einsum("bhnc,bhncd->bhnd", a, kc,
-                         preferred_element_type=acc_dt)
-              + mu.astype(acc_dt)[None, :, None, :]).astype(k.dtype)
-        vs = jnp.einsum("bhnc,bhncd->bhnd", a, vc,
-                        preferred_element_type=acc_dt).astype(v.dtype)
+            preferred_element_type=acc_dt), axis=-1).astype(k.dtype),
+            "eva_a")
+        ks = checkpoint_name(
+            (jnp.einsum("bhnc,bhncd->bhnd", a, kc,
+                        preferred_element_type=acc_dt)
+             + mu.astype(acc_dt)[None, :, None, :]).astype(k.dtype),
+            "eva_ks")
+        vs = checkpoint_name(
+            jnp.einsum("bhnc,bhncd->bhnd", a, vc,
+                       preferred_element_type=acc_dt).astype(v.dtype),
+            "eva_vs")
 
     def windows(x, start, stop, size):
         """Positions ``start..stop`` as rows of ``size``: [b, h*n, size, d]"""
@@ -708,15 +715,28 @@ class TransformerBlock(BaseLayerConf):
         input, each tagged with ``checkpoint_name`` where it is computed:
         q, k, v after the head split, the attention's output and (from the
         flash kernel) its log-sum-exp, the stream after the first add, the
-        MLP's pre-activation and its gate's.  A scanned run saves these and
-        recomputes the rest: the norms, the activation, the head merge
-        (``nn/scan_layers.run_scan``).  Sequence-parallel attention is a
-        loop of collectives, which a backward must not replay: such a
-        block declares nothing."""
+        MLP's pre-activation and its gate's; of EVA attention the pooled
+        keys and values and the pooling weights.  A scanned run saves these
+        and recomputes the rest: the norms, the activation, the head merge
+        (``nn/scan_layers.run_scan``).  **In order of worth**, what the
+        backward would repeat per byte stacked, for a run under
+        ``cache_mode="remat"`` that has room for some of them only: EVA's
+        three (a sixteenth of k and v, and the pool's middle pass), the
+        matmul outputs (a product's FLOPs per byte of output is its
+        contraction width, the same for all five: q and k first, which
+        carry their rotation too, then v, then the MLP's two, each as large
+        as q, k and v together, so what is left of the room rarely holds
+        one), the kernel's log-sum-exp and output, then the float32 stream
+        after the first add, which saves the output projection alone.  The
+        order means nothing where every name is kept.
+        Sequence-parallel attention is a loop of collectives, which a
+        backward must not replay: such a block declares nothing."""
         if self.attn_impl in ("ring", "ulysses"):
             return ()
-        return ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse",
-                "block_mid", "mlp_up", "mlp_gate")
+        eva = ("eva_ks", "eva_vs", "eva_a") if self.attention == "eva" \
+            else ()
+        return eva + ("attn_q", "attn_k", "attn_v", "mlp_up", "mlp_gate",
+                      "attn_lse", "attn_out", "block_mid")
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:

@@ -21,45 +21,63 @@ Exact parity with the unrolled walk is preserved by construction:
 
 What a scanned run saves for the backward pass, stacked over the run
 (each saved value is written into a ``[n, ...]`` stack in the forward
-and read back in the backward: a copy each way, every step):
+and read back in the backward: a copy each way, every step).  One rule:
+the run saves each layer's input and a set ``kept`` of the values the
+layer names, under ``jax.checkpoint`` with ``save_only_these_names``;
+the backward recomputes the rest from them.  A layer declares
+``SAVED_NAMES``, the values of its ``apply`` that are dear to recompute,
+each tagged with ``jax.ad_checkpoint.checkpoint_name`` where it is
+computed, in order of what the backward would repeat per byte stacked
+(``TransformerBlock``: EVA attention's pooled keys, values and weights,
+the MLP's pre-activation and its gate's, q, k, v after the head split,
+the attention's log-sum-exp and output, the stream after the first add).
 
-  ``all``    the layer declares nothing (``Dense``, ``LSTM``, conv
-             stacks, a block of ring or all-to-all attention):
-             ``scan``'s partial evaluation stacks every intermediate the
-             transposed body reads.  The program is the one a plain
-             ``lax.scan`` gives; an inner time scan or a loop of
-             collectives is never replayed.
-  ``named``  the layer declares ``SAVED_NAMES``, the values of its
-             ``apply`` that are dear to recompute, each tagged with
-             ``jax.ad_checkpoint.checkpoint_name`` where it is computed
-             (``TransformerBlock``: q, k, v after the head split, the
-             attention's output and log-sum-exp, the stream after the
-             first add, the MLP's pre-activation).  The body runs under
-             ``jax.checkpoint`` with ``save_only_these_names``: the run
-             saves the block's input and those, and the backward
-             recomputes the rest from them, for a GPT-2 block the
-             element-wise rest (norms, GELU, head merge): no matmul and
-             no kernel runs twice.  (What is neither named nor cheap is
-             recomputed all the same: EVA attention's summaries, or the
-             scores of ``sdpa_reference``, which the flash kernels
+  without ``cache_mode='remat'``  ``kept`` is every name.  For a GPT-2
+             block the backward recomputes the element-wise rest (norms,
+             GELU, head merge): no matmul and no kernel runs twice.
+             (What is neither named nor cheap is recomputed all the same:
+             the scores of ``sdpa_reference``, which the flash kernels
              recompute by design.)  A GPT-2 medium block at 3 x 1024
-             tokens saves 8 arrays, 63 MB, where ``all`` stacks 19,
+             tokens saves 8 arrays, 63 MB, where a plain scan stacks 19,
              245 MB: on a TPU v5e the step is 5.5 ms of 89.0 shorter
              (``PERF.md`` sections 5 and 6, PR 30).
-  ``input``  ``cache_mode='remat'``: ``jax.checkpoint`` with no policy.
-             The run saves each layer's input alone and the backward
-             recomputes a layer's forward just before it differentiates
-             it: the run's forward FLOPs once more a step, for one
-             layer's internals live at a time instead of every layer's.
-             On a TPU v5e the benchmark's ``evabyte-4l.train-fit-long``
-             trains this way: four blocks 4096 wide on 8192 positions
-             fit one chip beside 9.86 GB of state only so, and the
-             recomputed forward is 85 ms of a 466 ms step (``PERF.md``
-             section 5, PR 29).
+  under ``cache_mode='remat'``  ``kept`` is **what fits**, derived and
+             never set.  The bytes of each name come from an abstract
+             trace of the body as the backward differentiates it
+             (:func:`body_census`; the names inside a kernel's forward
+             rule are there), times the run's length.  The room is the
+             device's ``memory_stats()["bytes_limit"]`` less what the
+             step being traced has declared it holds (:func:`holding`:
+             ``nn/_common.build_train_step`` declares its arguments, an
+             epoch scan its dataset), less the run's own stacks (the
+             weights as the walk hands them, their gradients, the layers'
+             inputs), less a reserve for one layer's backward:
+             ``RESERVE`` times the bytes of the forward's values that the
+             transposed body reads, from the same trace.  The names are
+             taken greedily in the layer's order, one that does not fit
+             passed over (:func:`fitting`).  Where the device reports no
+             limit (the CPU) or no step declared anything, the room is
+             nought, ``kept`` is empty and the program is a bare
+             ``jax.checkpoint`` around the body: the layer's input alone,
+             the run's forward FLOPs once more a step.  On a TPU v5e the
+             benchmark's ``evabyte-4l.train-fit-long`` (four blocks 4096
+             wide on 8192 positions beside 9.86 GB of state) keeps the
+             pooled three, the MLP's pre-activation and q, 1.03 GB, of
+             eleven names that would stack 3.1 GB (``PERF.md`` sections 5
+             to 7, PR 32).
+  a layer that names nothing  (``Dense``, ``LSTM``, conv stacks, a block
+             of ring or all-to-all attention) keeps ``lax.scan``'s own
+             program, whose partial evaluation stacks every intermediate
+             the transposed body reads (an inner time scan or a loop of
+             collectives is never replayed), and under remat the bare
+             ``jax.checkpoint``.
 
-The choice follows from ``cache_mode`` and the layer; each scanned run
-traced into a training step counts into
-``scan_runs_traced_total{layer, saved}``.
+Each scanned run traced into a training step counts into
+``scan_runs_traced_total{layer, saved}``: ``all`` (nothing named, no
+remat), ``named`` (every name), ``some`` (remat, a part of them),
+``input`` (remat, none); ``scan_saved_stack_bytes{layer}`` holds the
+bytes the last such run stacks beyond its inputs, and a remat run logs
+what it kept, its bytes and the room.
 
 Eligibility (anything else falls back to the unrolled walk, which stays
 bit-identical): dataclass confs equal ignoring ``name``; no preprocessor
@@ -76,12 +94,19 @@ length.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import copy
 import json
+import logging
 import os
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
-__all__ = ["scan_runs", "run_scan", "DEFAULT_MIN_RUN"]
+import numpy as np
+
+__all__ = ["scan_runs", "run_scan", "holding", "DEFAULT_MIN_RUN"]
+
+log = logging.getLogger(__name__)
 
 DEFAULT_MIN_RUN = 4
 
@@ -164,7 +189,7 @@ def scan_runs(conf, n: int, *, mask_present: bool, carries_present: bool,
     return runs
 
 
-def _count_run(layer: str, saved: str) -> None:
+def _count_run(layer: str, saved: str, stack_bytes: int) -> None:
     """One scanned training run traced: trace-time work, like
     ``training_compile_total``."""
     from ..observability.registry import default_registry
@@ -174,15 +199,178 @@ def _count_run(layer: str, saved: str) -> None:
                     "Scanned layer runs traced into a training step, by "
                     "what the run saves for the backward pass",
                     ("layer", "saved")).labels(layer, saved).inc()
+        reg.gauge("scan_saved_stack_bytes",
+                  "Bytes the last scanned run traced stacks for the "
+                  "backward pass beyond its layers' inputs",
+                  ("layer",)).labels(layer).set(stack_bytes)
+
+
+# ---- what a training program holds while a run is traced -----------------
+
+_held = contextvars.ContextVar("dl4j_tpu_step_held_bytes", default=None)
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of a pytree's array leaves, from their shapes."""
+    import jax
+    return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree)
+               if hasattr(a, "shape") and hasattr(a, "dtype"))
+
+
+@contextlib.contextmanager
+def holding(*trees):
+    """While a training program is traced: its arguments (parameters,
+    optimizer state, batch; a device-resident dataset around an epoch
+    scan) lie in the device's memory beside whatever a scanned run
+    stacks.  Nested programs add up."""
+    token = _held.set((_held.get() or 0) + tree_bytes(trees))
+    try:
+        yield
+    finally:
+        _held.reset(token)
+
+
+def _device_limit() -> Optional[int]:
+    """``bytes_limit`` of the device a step runs on by default; ``None``
+    where the backend reports none (the CPU)."""
+    import jax
+    dev = jax.config.jax_default_device
+    if not hasattr(dev, "memory_stats"):
+        dev = jax.local_devices()[0]
+    return (dev.memory_stats() or {}).get("bytes_limit")
+
+
+def free_bytes() -> int:
+    """What the device has left beside what the program being traced has
+    declared it holds: nought where no step declared anything, the device
+    reports no limit or the compiler has refused this program once, so
+    that a remat run keeps its input alone."""
+    held = _held.get()
+    if held is None or _input_alone.get():
+        return 0
+    limit = _device_limit()
+    return max(0, int(limit) - held) if limit else 0
+
+
+def _claim(n_bytes: int) -> None:
+    """A traced run's stacks join what the step holds: a later run of the
+    same step sees the room that is left."""
+    if _held.get() is not None:
+        _held.set(_held.get() + n_bytes)
+
+
+# ---- when the compiler refuses what the arithmetic allowed ----------------
+# The room is arithmetic on shapes; whether a program fits is the
+# compiler's buffer assignment, which fragments by a third to a half of
+# the step's temporaries and not smoothly in the shapes (PERF.md section
+# 7).  So the first call of a jitted program that the compiler refuses for
+# memory after a remat run kept names is traced once more with every such
+# run keeping its input alone (``compile_cache.InstrumentedJit``): a job
+# that compiled before remat kept anything still compiles.
+
+_input_alone = contextvars.ContextVar("dl4j_tpu_scan_input_alone",
+                                      default=False)
+_kept_runs = contextvars.ContextVar("dl4j_tpu_scan_kept_runs", default=0)
+
+
+@contextlib.contextmanager
+def input_alone():
+    """While a program is traced: remat runs keep their input alone."""
+    token = _input_alone.set(True)
+    try:
+        yield
+    finally:
+        _input_alone.reset(token)
+
+
+def kept_runs() -> int:
+    """How many remat runs that kept a name this thread has traced."""
+    return _kept_runs.get()
+
+
+def refused_for_memory(error: BaseException) -> bool:
+    """Is ``error`` the compiler's refusal of a program that does not fit
+    the device's memory?  (Its words, not a failed allocation at run
+    time, after which donated arguments are gone.)"""
+    text = str(error)
+    return "RESOURCE_EXHAUSTED" in text and \
+        "Ran out of memory in memory space" in text
+
+
+# ---- the set of names a run saves ----------------------------------------
+
+# The reserve for one body's backward, in multiples of the bytes its
+# transposed form reads from its forward (PERF.md section 7: the TPU's
+# compiler was asked, compile-only, at four shapes).
+RESERVE = 0.77
+
+
+def _named_bytes(jaxpr, into: Dict[str, int]) -> None:
+    """Bytes by ``checkpoint_name`` over a jaxpr and the jaxprs inside its
+    equations (a jitted helper, the forward rule of a kernel)."""
+    from jax.extend import core
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            name = eqn.params["name"]
+            into[name] = into.get(name, 0) + tree_bytes(eqn.outvars[0].aval)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, core.Jaxpr):
+                    _named_bytes(sub, into)
+
+
+def body_census(body, carry, per_layer) -> Tuple[Dict[str, int], int]:
+    """``({name: bytes}, residual bytes)`` of one layer of a run, from an
+    abstract trace of ``body`` as the backward differentiates it (so the
+    names inside a kernel's forward rule are there): what each
+    ``checkpoint_name`` would add to the run's stacks a layer, and the
+    bytes of the forward's values that the transposed body reads, its
+    arguments aside: what one body's backward holds live."""
+    import jax
+    from jax.extend import core
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    p, s, k = per_layer
+
+    def differentiated(c, p_, s_, k_):
+        return jax.vjp(lambda c_, pp: body(c_, (pp, s_, k_)), c, p_)
+    closed, shape = jax.make_jaxpr(differentiated, return_shape=True)(
+        abstract(carry), abstract(p), abstract(s), abstract(k))
+    names: Dict[str, int] = {}
+    _named_bytes(closed.jaxpr, names)
+    n_out = len(jax.tree_util.tree_leaves(shape[0]))
+    given = set(closed.jaxpr.invars) | set(closed.jaxpr.constvars)
+    read = {v for v in closed.jaxpr.outvars[n_out:]
+            if isinstance(v, core.Var) and v not in given}
+    return names, tree_bytes([v.aval for v in read])
+
+
+def fitting(sizes: Dict[str, int], room: int) -> Tuple[str, ...]:
+    """Greedy in the order given: each name that still fits is kept, one
+    that does not is passed over."""
+    kept = []
+    for name, n_bytes in sizes.items():
+        if n_bytes <= room:
+            kept.append(name)
+            room -= n_bytes
+    return tuple(kept)
 
 
 def run_scan(lc, params_slices, state_slices, h, key, start: int,
-             *, train: bool, mask, remat: bool):
+             *, train: bool, mask, remat: bool, room: Optional[int] = None):
     """Execute one homogeneous run under ``jax.lax.scan``.
 
     ``params_slices``/``state_slices``: the per-layer pytrees in stack
     order.  Returns ``(h, new_state_slices)`` with the same per-layer
-    structure the unrolled walk would have produced.
+    structure the unrolled walk would have produced.  ``room``: the bytes
+    a remat run may spend on stacks of its own (the step's arguments
+    already taken off); read from the device and the step being traced
+    where not given.
     """
     import jax
     import jax.numpy as jnp
@@ -207,21 +395,46 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
                              key=k, mask=mask)
         return y, ns
 
-    names = tuple(getattr(lc, "SAVED_NAMES", ()))
-    if remat:
-        saved = "input"
-        body = jax.checkpoint(body)
-    elif train and names:
-        saved = "named"
-        # in a scan nothing can be shared with the recomputation, so no
-        # barrier against common-subexpression elimination is needed
+    names = tuple(getattr(lc, "SAVED_NAMES", ())) if train else ()
+    kept, stack_bytes, saved = names, 0, "input" if remat else "all"
+    if names:
+        per_layer, reads = body_census(body, h, (
+            params_slices[0], state_slices[0], None if keys is None
+            else jax.ShapeDtypeStruct(keys.shape[1:], keys.dtype)))
+        sizes = {n: per_layer[n] * n_run for n in names if n in per_layer}
+        if remat:
+            # what the run stacks whatever it keeps: the weights as the
+            # walk hands them, their gradients, each layer's input
+            own = 2 * tree_bytes(stacked_p) + n_run * tree_bytes(h)
+            reserve = int(RESERVE * reads)
+            room = (free_bytes() if room is None else room) - own - reserve
+            kept = fitting(sizes, room)
+            log.info("scan of %d %s under remat keeps %s: %d bytes of %d "
+                     "free (beside %d of its own stacks and %d reserved "
+                     "for one layer's backward); passed over %s",
+                     n_run, type(lc).__name__, list(kept),
+                     sum(sizes[n] for n in kept), max(room, 0), own,
+                     reserve, [n for n in sizes if n not in kept])
+            _claim(own + sum(sizes[n] for n in kept))
+            _kept_runs.set(kept_runs() + bool(kept))
+        stack_bytes = sum(sizes.get(n, 0) for n in kept)
+        saved = "named" if len(kept) >= len(sizes) else \
+            "some" if kept else "input"
+    if kept:
+        # the run saves each layer's input and the values named in
+        # ``kept``; the backward recomputes the rest from them.  In a scan
+        # nothing can be shared with the recomputation, so no barrier
+        # against common-subexpression elimination is needed; under remat
+        # it stays, as the bare checkpoint has it: a step that fills the
+        # device is scheduled by memory, and without the barrier the same
+        # kept set reads 15-18 ms a step slower (PERF.md section 6, PR 32)
         body = jax.checkpoint(
-            body, prevent_cse=False,
-            policy=jax.checkpoint_policies.save_only_these_names(*names))
-    else:
-        saved = "all"
+            body, prevent_cse=remat,
+            policy=jax.checkpoint_policies.save_only_these_names(*kept))
+    elif remat:
+        body = jax.checkpoint(body)
     if train:
-        _count_run(type(lc).__name__, saved)
+        _count_run(type(lc).__name__, saved, stack_bytes)
     # explicit length: a paramless/stateless run at inference (no keys)
     # has no xs leaves for scan to infer it from
     h, stacked_ns = jax.lax.scan(body, h, (stacked_p, stacked_s, keys),

@@ -258,3 +258,95 @@ def test_lm_train_step_compiles_with_flash_kernels(topo, four_chips, chips,
     stack = "bf16[4,2,1024,768]"
     assert _while_stacks(compiled, stack) == 2 < _while_stacks(everything,
                                                                stack)
+
+
+# memory_stats()["bytes_limit"] of one TPU v5e, the compiler's 15.75 GiB
+# (chip run, PR 32): what ``nn/scan_layers._device_limit`` reads there
+V5E_BYTES_LIMIT = 16909336064
+SPARE = int(0.1 * 2 ** 30)
+
+
+def _evabyte_step(one_chip, monkeypatch, seq, fitting=None):
+    """The benchmark's ``evabyte-4l`` train step (four blocks 4096 wide,
+    32 heads of 128, MLP 11008, eight heads of 320 bytes, bfloat16 under
+    ``cache_mode="remat"``) lowered from shapes for one described v5e at
+    one row of ``seq`` bytes; the names its scanned run kept.  The device
+    here is the CPU, which reports no limit: the test hands the v5e's."""
+    from deeplearning4j_tpu.models import EvaByteLM
+    from deeplearning4j_tpu.nn import scan_layers
+    from deeplearning4j_tpu.nn.conf.updaters import Adam
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(scan_layers, "_device_limit",
+                        lambda: V5E_BYTES_LIMIT)
+    kept = []
+    choose = fitting or scan_layers.fitting
+
+    def spy(sizes, room):
+        kept.append((choose(sizes, room), dict(sizes)))
+        return kept[-1][0]
+    monkeypatch.setattr(scan_layers, "fitting", spy)
+    held = {}
+
+    def built():
+        # 821 M parameters and Adam's moments: shapes alone
+        net = held["net"] = EvaByteLM(
+            vocab_size=320, seq_len=seq, embed=4096, n_layers=4, n_heads=32,
+            head_dim=128, ffn_hidden=11008, pred_heads=8, window=2048,
+            chunk=16, attn_impl="auto", cache_mode="remat",
+            compute_dtype="bfloat16",
+            updater=Adam(learning_rate=3e-4)).init()
+        return net.params, net.state, net.opt_state, net._rng
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(built))
+
+    def batch(dtype, *tail):
+        return jax.ShapeDtypeStruct((1, seq) + tail, dtype,
+                                    sharding=one_chip)
+    args += (batch(jnp.int32), batch(jnp.int32, 8), None,
+             batch(jnp.float32, 8))
+    lowered = held["net"]._get_jitted("train_step").audit_lower((args, {}))
+    (names, sizes), = kept
+    return lowered, names, sizes
+
+
+def test_evabyte_step_fits_with_the_names_its_remat_run_keeps(
+        one_chip, monkeypatch):
+    """At the cell's 8192 bytes the run keeps some names and not all: the
+    pooled three, q, k, v and the log-sum-exp; the MLP's two, each as large
+    as q, k and v together, are passed over, the kernel's output and the
+    stream after the first add no longer fit.  The step compiles,
+    arguments and program at least 0.1 GiB under the limit by the
+    compiler's own peak."""
+    lowered, names, sizes = _evabyte_step(one_chip, monkeypatch, 8192)
+    assert names == ("eva_ks", "eva_vs", "eva_a", "attn_q", "attn_k",
+                     "attn_v", "attn_lse")
+    assert 0.8e9 < sum(sizes[n] for n in names) < 0.9e9
+    assert sizes["attn_q"] == 4 * 8192 * 4096 * 2
+    assert sizes["mlp_up"] == 4 * 8192 * 11008 * 2
+    assert all(name in lowered.as_text() for name in F.KERNEL_NAMES)
+    memory = lowered.compile().memory_analysis()
+    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - SPARE
+    assert memory.argument_size_in_bytes > 9.8e9
+
+
+def test_evabyte_step_with_every_name_kept_does_not_fit(one_chip,
+                                                        monkeypatch):
+    """Why the policy is partial: at 8192 bytes the eleven names stack
+    3.1 GB, and the compiler refuses the step for memory."""
+    lowered, names, sizes = _evabyte_step(
+        one_chip, monkeypatch, 8192, fitting=lambda sizes, room: tuple(sizes))
+    assert len(names) == 11 and sum(sizes.values()) > 3.0e9
+    with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
+        lowered.compile()
+
+
+def test_evabyte_step_at_half_the_length_keeps_more_and_fits(one_chip,
+                                                             monkeypatch):
+    """The same derivation at 4096 bytes: the stacks are half as large
+    beside the same 9.86 GB of state, every name is kept, and the step
+    compiles with room to spare."""
+    lowered, names, sizes = _evabyte_step(one_chip, monkeypatch, 4096)
+    assert names == tuple(sizes) and len(names) == 11
+    memory = lowered.compile().memory_analysis()
+    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - SPARE

@@ -2,7 +2,9 @@
 (``nn/scan_layers.run_scan``): a layer that names the values dear to
 recompute (``TransformerBlock.SAVED_NAMES``) runs under a checkpoint policy
 that saves those and the block's input; a layer that names nothing keeps
-``lax.scan``'s own program; ``cache_mode='remat'`` saves the input alone.
+``lax.scan``'s own program; ``cache_mode='remat'`` saves the input and as
+many of the names, in their order of worth, as the device has room for:
+none where it reports no limit (the CPU), which is the program remat had.
 """
 import numpy as np
 import pytest
@@ -12,6 +14,7 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu import (InputType, MultiLayerNetwork,
                                 NeuralNetConfiguration)
+from deeplearning4j_tpu.nn import scan_layers
 from deeplearning4j_tpu.nn.conf.updaters import Sgd
 from deeplearning4j_tpu.nn.layers.attention import TransformerBlock
 from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer, OutputLayer
@@ -318,3 +321,291 @@ def test_counter_reads_one_run_of_each_kind_through_fit():
     assert _runs_since(before) == {("TransformerBlock", "named"): 1,
                                    ("DenseLayer", "all"): 1,
                                    ("TransformerBlock", "input"): 1}
+
+
+# ---- cache_mode="remat" keeps what fits ----------------------------------
+
+N_RUN = 6
+EVA = dict(attention="eva", window=4, chunk=2, gated=True, has_bias=False,
+           norm="rms", positions="rotary")
+
+
+def _run_and_walk(lc, room, x, key):
+    """Loss and gradients (input and every layer's parameters) of
+    ``N_RUN`` copies of ``lc`` through ``run_scan`` under remat with
+    ``room`` bytes, and through the unrolled walk's loop."""
+    itype = InputType.recurrent(x.shape[-1], x.shape[1])
+    lc.set_n_in(itype)
+    ps = [lc.init(jax.random.fold_in(jax.random.PRNGKey(3), i),
+                  itype)["params"] for i in range(N_RUN)]
+
+    def scanned(ps, x):
+        h, _ = scan_layers.run_scan(lc, ps, [{}] * N_RUN, x, key, 0,
+                                    train=True, mask=None, remat=True,
+                                    room=room)
+        return jnp.sum(h * h)
+
+    def unrolled(ps, x):
+        for i, p in enumerate(ps):
+            x, _ = lc.apply({"params": p, "state": {}}, x, train=True,
+                            key=jax.random.fold_in(key, i), mask=None)
+        return jnp.sum(x * x)
+    return (jax.jit(jax.value_and_grad(scanned, (0, 1)))(ps, x),
+            jax.jit(jax.value_and_grad(unrolled, (0, 1)))(ps, x), ps)
+
+
+def _sizes(lc, ps, x, key):
+    """``({name: bytes over the run}, own stacks, reserve)`` as
+    ``run_scan`` counts them for ``N_RUN`` copies of ``lc``."""
+    def body(c, per):
+        return lc.apply({"params": per[0], "state": per[1]}, c, train=True,
+                        key=per[2], mask=None)
+    per_layer, reads = scan_layers.body_census(body, x, (ps[0], {}, key))
+    own = 2 * N_RUN * scan_layers.tree_bytes(ps[0]) \
+        + N_RUN * scan_layers.tree_bytes(x)
+    return ({n: per_layer[n] * N_RUN for n in lc.SAVED_NAMES
+             if n in per_layer}, own, int(scan_layers.RESERVE * reads))
+
+
+@pytest.mark.parametrize("keeps,label", [
+    ((), "input"), (("attn_q", "attn_k"), "some"), (None, "named")],
+    ids=["empty", "partial", "full"])
+def test_remat_run_matches_unrolled_walk_whatever_it_keeps(keeps, label):
+    """Loss and every gradient leaf of six scanned blocks under remat,
+    keeping nothing, two names and every name, against the unrolled loop:
+    float32, dropout on, to 1e-6 of each leaf's largest element."""
+    x, _ = _seq_batch()
+    key = jax.random.PRNGKey(11)
+    lc = TransformerBlock(n_heads=2, dropout=0.8)
+    lc.set_n_in(InputType.recurrent(E, T))
+    probe = lc.init(jax.random.PRNGKey(0), InputType.recurrent(E, T))
+    sizes, own, reserve = _sizes(lc, [probe["params"]], x, key)
+    room = own + reserve + (sum(sizes.values()) if keeps is None
+                            else sum(sizes[n] for n in keeps))
+    before = _runs()
+    (loss_s, g_s), (loss_u, g_u), _ = _run_and_walk(lc, room, x, key)
+    assert _runs_since(before) == {("TransformerBlock", label): 1}
+    gauge = default_registry().get("scan_saved_stack_bytes")
+    assert gauge.labels("TransformerBlock").value == (
+        sum(sizes.values()) if keeps is None
+        else sum(sizes[n] for n in keeps))
+    assert float(loss_s) == pytest.approx(float(loss_u), rel=1e-6)
+    leaves_s = jax.tree_util.tree_leaves_with_path(g_s)
+    leaves_u = jax.tree_util.tree_leaves(g_u)
+    assert len(leaves_s) == len(leaves_u) > N_RUN * 12
+    sizes_u = [float(jnp.max(jnp.abs(b))) for b in leaves_u]
+    floor = float(np.median(sizes_u))
+    for (path, a), b, size in zip(leaves_s, leaves_u, sizes_u):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-6 * max(size, floor),
+                                   err_msg=str(path))
+
+
+def test_census_of_a_partial_remat_scan_is_the_input_plus_what_it_keeps(
+        monkeypatch):
+    """With room for q and k beside the reserve, the forward scan of the
+    train step stacks the block's input, those two and nothing else, byte
+    for byte what the census said."""
+    net = _blocks(cache_mode="remat")
+    x, y = _seq_batch()
+    lc = net.conf.layers[0]
+    sizes, own, reserve = _sizes(lc, [net.params["layer_0"]], x,
+                                 jax.random.PRNGKey(0))
+    room = own + reserve + sizes["attn_q"] + sizes["attn_k"]
+    monkeypatch.setattr(scan_layers, "free_bytes", lambda: room)
+    before = _runs()
+    fwd, _ = _scans(_step_jaxpr(net, x, y))
+    assert _runs_since(before) == {("TransformerBlock", "some"): 1}
+    saved = _stacked(fwd)
+
+    def nbytes(a):
+        return int(np.prod(a.shape)) * a.dtype.itemsize
+    assert sorted(nbytes(a) * N_RUN for a in saved) == sorted(
+        [scan_layers.tree_bytes(x) * N_RUN, sizes["attn_q"],
+         sizes["attn_k"]])
+
+
+@pytest.mark.parametrize("room,kept", [
+    (0, ()), (-5, ()), (3, ()), (9, ("attn_q", "attn_k")),
+    (10, ("mlp_up",)),
+    # the gate does not fit beside its twin: passed over, q taken
+    (15, ("mlp_up", "attn_q")), (19, ("mlp_up", "attn_q", "attn_k")),
+    (20, ("mlp_up", "mlp_gate")), (10 ** 6, ("mlp_up", "mlp_gate",
+                                              "attn_q", "attn_k"))])
+def test_greedy_choice_passes_over_what_does_not_fit(room, kept):
+    sizes = {"mlp_up": 10, "mlp_gate": 10, "attn_q": 4, "attn_k": 4}
+    assert scan_layers.fitting(sizes, room) == kept
+
+
+def test_an_eva_block_lists_the_pooled_three_first():
+    """The pooled keys, values and pooling weights are a sixteenth (here
+    1 / chunk) of k and v: cheapest to keep, so first in the order; a block
+    of full attention does not list them, and its trace does not hold
+    them."""
+    lc = TransformerBlock(n_heads=2, **EVA)
+    assert lc.SAVED_NAMES[:3] == ("eva_ks", "eva_vs", "eva_a")
+    assert lc.SAVED_NAMES[3:] == NAMES
+    assert NAMES == ("attn_q", "attn_k", "attn_v", "mlp_up", "mlp_gate",
+                     "attn_lse", "attn_out", "block_mid")
+    x, _ = _seq_batch()
+    itype = InputType.recurrent(E, T)
+    lc.set_n_in(itype)
+    p = lc.init(jax.random.PRNGKey(0), itype)["params"]
+    sizes, _, _ = _sizes(lc, [p], x, None)
+    assert sizes["eva_ks"] == sizes["eva_vs"] == sizes["attn_k"] // 2
+    assert sizes["eva_a"] * (E // 2) == sizes["attn_k"]
+    assert sizes["mlp_gate"] == sizes["mlp_up"]
+    assert list(sizes)[:3] == ["eva_ks", "eva_vs", "eva_a"]
+
+
+def test_a_layer_without_names_under_remat_keeps_the_bare_checkpoint(
+        monkeypatch):
+    """However much room there is, a Dense run under remat is one
+    ``jax.checkpoint`` with no policy: the input alone is stacked."""
+    monkeypatch.setattr(scan_layers, "free_bytes", lambda: 10 ** 12)
+    net = _dense(cache_mode="remat")
+    before = _runs()
+    closed = _step_jaxpr(net, *_flat_batch())
+    assert _runs_since(before) == {("DenseLayer", "input"): 1}
+    assert len(_stacked(_scans(closed)[0])) == 1
+    remats = [e for e in _walk(closed.jaxpr)
+              if e.primitive.name in CHECKPOINT]
+    assert remats and all(e.params["policy"] is None for e in remats)
+    assert all(e.params["prevent_cse"] for e in remats)
+
+
+def test_fit_counts_a_partial_run_and_gauges_its_bytes(monkeypatch):
+    """Through ``fit`` on a device that reports a limit:
+    ``scan_runs_traced_total{saved="some"}`` ticks once and
+    ``scan_saved_stack_bytes`` reads the bytes of the kept names; what the
+    step holds (parameters, updater state, batch) has come off the limit."""
+    net = _blocks(cache_mode="remat")
+    x, y = _seq_batch()
+    lc = net.conf.layers[0]
+    sizes, own, reserve = _sizes(lc, [net.params["layer_0"]], x,
+                                 jax.random.PRNGKey(0))
+    held = scan_layers.tree_bytes((net.params, net.state, net.opt_state,
+                                   net._rng, x, y))
+    want = sizes["attn_q"] + sizes["attn_k"]
+    monkeypatch.setattr(scan_layers, "_device_limit",
+                        lambda: held + own + reserve + want)
+    before = _runs()
+    net.fit(x, y)
+    assert _runs_since(before) == {("TransformerBlock", "some"): 1}
+    gauge = default_registry().get("scan_saved_stack_bytes")
+    assert gauge.labels("TransformerBlock").value == want
+    assert np.isfinite(net.score())
+
+
+def test_what_a_step_holds_comes_off_the_limit_and_nests(monkeypatch):
+    """``free_bytes`` is nought outside a step, the limit less the declared
+    arguments inside one, less again inside a nested program and after a
+    run has claimed its stacks; nought where the device reports none."""
+    a = jnp.zeros((4, 8), jnp.float32)                 # 128 bytes
+    assert scan_layers.free_bytes() == 0
+    with scan_layers.holding(a):
+        assert scan_layers.free_bytes() == 0           # the CPU: no limit
+    monkeypatch.setattr(scan_layers, "_device_limit", lambda: 1000)
+    assert scan_layers.free_bytes() == 0               # no step declared
+    with scan_layers.holding(a, {"b": a, "n": None, "k": 3}):
+        assert scan_layers.free_bytes() == 1000 - 256
+        with scan_layers.holding([a]):
+            assert scan_layers.free_bytes() == 1000 - 384
+            scan_layers._claim(500)
+            assert scan_layers.free_bytes() == 116
+            scan_layers._claim(500)
+            assert scan_layers.free_bytes() == 0
+        assert scan_layers.free_bytes() == 1000 - 256
+    assert scan_layers.free_bytes() == 0
+
+
+def test_with_no_limit_the_remat_step_is_the_bare_checkpoint_to_the_letter(
+        monkeypatch):
+    """Where the device reports no limit the lowered remat step is the text
+    of one bare ``jax.checkpoint`` around the body (what a block that names
+    nothing gets, and what remat was before it kept anything); with room it
+    is another program."""
+    net = _blocks(cache_mode="remat")
+    x, y = _seq_batch()
+
+    def text():
+        step = _build_train_step(net.conf, net._tx, False)
+        return jax.jit(step).lower(net.params, net.state, net.opt_state,
+                                   net._rng, x, y, None, None).as_text()
+    ours = text()
+    monkeypatch.setattr(scan_layers, "_device_limit", lambda: 10 ** 12)
+    roomy = text()
+    monkeypatch.setattr(TransformerBlock, "SAVED_NAMES", ())
+    bare = text()
+    assert ours == bare
+    assert roomy != bare
+
+
+def test_an_epoch_scan_declares_its_dataset_beside_the_step(monkeypatch):
+    """``fit_on_device`` keeps the whole dataset on the device: what a
+    remat run may spend is the limit less the step's arguments through
+    ``fit``, and less the dataset too inside the epoch's program."""
+    limit = 10 ** 9
+    monkeypatch.setattr(scan_layers, "_device_limit", lambda: limit)
+    free, real = [], scan_layers.free_bytes
+
+    def spy():
+        free.append(real())
+        return free[-1]
+    monkeypatch.setattr(scan_layers, "free_bytes", spy)
+    net = _blocks(cache_mode="remat")
+    x, y = _seq_batch(rows=12)
+    state = scan_layers.tree_bytes((net.params, net.state, net.opt_state,
+                                    net._rng))
+    net.fit(x[:3], y[:3])
+    assert free == [limit - state - scan_layers.tree_bytes((x[:3], y[:3]))]
+    net.fit_on_device(x, y, batch_size=4, epochs=1)
+    assert free[1:] == [limit - state
+                        - scan_layers.tree_bytes((x[:4], y[:4]))
+                        - scan_layers.tree_bytes((x, y))]
+
+
+def test_a_step_the_compiler_refuses_is_traced_again_with_inputs_alone(
+        monkeypatch, caplog):
+    """The room is arithmetic; whether a program fits is the compiler's
+    to say.  A train step that it refuses for memory after a remat run
+    kept names is traced once more with the run's input alone, logged and
+    counted, and stays so at the next batch shape; any other failure, and
+    a refusal of a step that kept nothing, pass through."""
+    monkeypatch.setattr(scan_layers, "_device_limit", lambda: 10 ** 12)
+    count = scan_layers._count_run
+    refusal = ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran "
+               "out of memory in memory space hbm. Used 15.89G of 15.75G")
+
+    def refusing(layer, saved, stack_bytes):
+        count(layer, saved, stack_bytes)
+        if saved != "input":
+            raise RuntimeError(refusal)
+    monkeypatch.setattr(scan_layers, "_count_run", refusing)
+    net = _blocks(cache_mode="remat")
+    x, y = _seq_batch()
+    before = _runs()
+    with caplog.at_level("WARNING", logger="deeplearning4j_tpu.nn"):
+        net.fit(x, y)
+    assert _runs_since(before) == {("TransformerBlock", "named"): 1,
+                                   ("TransformerBlock", "input"): 1}
+    assert "traced again with their inputs alone" in caplog.text
+    fallbacks = default_registry().get("scan_fallbacks_total")
+    assert fallbacks.labels("train_step").value == 1
+    assert np.isfinite(net.score())
+    # another batch shape: another trace of the same step, no new refusal
+    before = _runs()
+    net.fit(*_seq_batch(rows=2))
+    assert _runs_since(before) == {("TransformerBlock", "input"): 1}
+    assert fallbacks.labels("train_step").value == 1
+
+    # a step that kept nothing and is refused all the same: not ours
+    def always(layer, saved, stack_bytes):
+        raise RuntimeError(refusal)
+    monkeypatch.setattr(scan_layers, "_count_run", always)
+    monkeypatch.setattr(scan_layers, "_device_limit", lambda: None)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        _blocks(cache_mode="remat", l2=1e-4).fit(x, y)
+    assert fallbacks.labels("train_step").value == 1
+    assert not scan_layers.refused_for_memory(RuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer"))
