@@ -694,6 +694,97 @@ class EvaByteLM(ZooModel):
 ALL_MODELS.append(EvaByteLM)
 
 
+@dataclass
+class TrinityLM(ZooModel):
+    """Arcee Trinity's architecture (https://huggingface.co/arcee-ai/
+    Trinity-Mini, ``model_type`` ``afmoe``): a decoder of bias-free blocks
+    with four gain-only RMSNorms each (before and after each half), 32
+    query heads over 4 K/V heads, RMSNorm over each head of q and k, a
+    sigmoid gate on the attention's output, ``sliding_attention`` layers
+    (the last ``window`` keys, rotary positions) among ``full_attention``
+    ones (every earlier key, no positions) as ``layer_types`` lists them,
+    ``dense_layers`` leading layers with a gated SiLU MLP and then layers
+    whose FFN is routed: sigmoid scores over ``experts`` experts, the
+    ``top_k`` largest a token, weights normalised and scaled, a shared
+    expert beside them, no token dropped (``nn/layers/moe.
+    RoutedExperts``).  The embedding is scaled by ``sqrt(embed)``; a final
+    RMSNorm and an untied head follow.  ``experts_held = (first, count)``
+    and a ``vocab_size`` that is a slice of the published one make this one
+    chip's share of an expert-parallel deployment: the router keeps
+    ``experts`` outputs, the chip computes its own experts' part of each
+    token's result, and logits and loss are over the slice.  Integer
+    targets ``[b, t]``.  The defaults are the published 26B model's."""
+    model_type = "rnn"
+    vocab_size: int = 200192
+    seq_len: int = 131072
+    embed: int = 2048
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    ffn_hidden: int = 6144
+    moe_hidden: int = 1024
+    experts: int = 128
+    experts_held: Optional[Tuple[int, int]] = None
+    top_k: int = 8
+    shared_experts: int = 1
+    route_scale: float = 2.826
+    dense_layers: int = 2
+    layer_types: Tuple[str, ...] = ("sliding_attention",) * 3 + \
+        ("full_attention",)
+    n_layers: int = 32      # layer_types is repeated to this length
+    window: int = 2048
+    rope_theta: float = 1e4
+    eps: float = 1e-5
+    attn_impl: str = "auto"
+    cache_mode: str = "none"
+
+    def init(self):
+        from ..nn.layers.attention import RMSNormLayer, TransformerBlock
+        from ..nn.layers.feedforward import EmbeddingSequenceLayer
+        kinds = [self.layer_types[i % len(self.layer_types)]
+                 for i in range(self.n_layers)]
+        b = (self._builder()
+             .updater(self.updater or Adam(learning_rate=3e-4))
+             .weight_init("xavier")
+             .cache_mode(self.cache_mode)
+             .list()
+             .layer(EmbeddingSequenceLayer(n_out=self.embed,
+                                           scale=float(self.embed) ** 0.5)))
+        for i, kind in enumerate(kinds):
+            sliding = {"sliding_attention": True,
+                       "full_attention": False}[kind]
+            routed = i >= self.dense_layers
+            b = b.layer(TransformerBlock(
+                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                head_dim=self.head_dim, causal=True,
+                attn_impl=self.attn_impl, eps=self.eps, norm="rms",
+                post_norm=True, qk_norm=True, attn_gate=True,
+                gated=True, has_bias=False, ffn_hidden=self.ffn_hidden,
+                attention="sliding" if sliding else "full",
+                window=self.window if sliding else 0,
+                positions="rotary" if sliding else "none",
+                rope_theta=self.rope_theta,
+                moe_experts=self.experts if routed else 0,
+                moe_top_k=self.top_k if routed else 0,
+                moe_scoring="sigmoid", moe_route_norm=True,
+                moe_route_scale=self.route_scale,
+                moe_shared=self.shared_experts if routed else 0,
+                moe_hidden=self.moe_hidden,
+                moe_held=self.experts_held if routed else None))
+        conf = (b.layer(RMSNormLayer(eps=self.eps))
+                .layer(RnnOutputLayer(n_out=self.vocab_size, has_bias=False,
+                                      activation="softmax",
+                                      loss="sparse_mcxent"))
+                .set_input_type(InputType.recurrent(self.vocab_size,
+                                                    self.seq_len))
+                .build())
+        from ..nn.multilayer import MultiLayerNetwork
+        return MultiLayerNetwork(conf).init()
+
+
+ALL_MODELS.append(TrinityLM)
+
+
 class ModelSelector:
     """Select zoo models by name/type (reference
     ``deeplearning4j-zoo/.../ModelSelector.java``: select(ZooType) returns a

@@ -25,6 +25,7 @@ from . import scan_layers as _scan_layers
 from . import sparse as _sparse
 from .dispatch import DispatchWindow
 from .layers.base import BaseLayerConf, LayerConf
+from .layers.moe import publish_expert_tokens
 from ..data.pipeline import ETL_BUCKETS as _ETL_BUCKETS
 from ..observability.clock import monotonic_s, wall_s
 from ..observability.registry import default_registry
@@ -738,6 +739,7 @@ def fit_batches(model, batches_factory, epochs: int, prepare, step, *,
             win.drain()
             with span("dl4j.sync"):
                 model._score = float(model._score)
+                publish_expert_tokens(model)
             if prof is not None:
                 prof.materialized()
             for lst in model.listeners:
@@ -793,6 +795,7 @@ def fit_batches(model, batches_factory, epochs: int, prepare, step, *,
     # they must propagate
     with span("dl4j.sync"):
         model._score = float(model._score)
+        publish_expert_tokens(model)
     if obs and steady_s > 0:
         # steady-state throughput: the compile-dominated first step
         # is excluded (same convention as utils/benchmarks.py)

@@ -18,17 +18,18 @@ Attention impl tiers (select with ``attn_impl``):
                 ``CudnnAlgoMode`` role.
 
 Attention kinds (select with ``attention``): ``'full'``, every key (under
-``causal`` every earlier key), and ``'eva'``, EVA chunked linearized
-attention (``_eva_attention``): exact causal attention inside a window,
-learned summaries of key chunks for everything before it.  Both reach the
-flash kernels; any other key set (a key-padding mask) runs
-``sdpa_reference`` under 'auto' and is refused by 'flash'.
+``causal`` every earlier key); ``'sliding'``, causal, the last ``window``
+keys of each query (a band that moves with the query); and ``'eva'``, EVA
+chunked linearized attention (``_eva_attention``): exact causal attention
+inside a window, learned summaries of key chunks for everything before it.
+All three reach the flash kernels; any other key set (a key-padding mask)
+runs ``sdpa_reference`` under 'auto' and is refused by 'flash'.
 """
 from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -146,21 +147,25 @@ def auto_attention_impl(t_q: int, t_k: int, d: int, *, masked: bool,
 
 
 def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
-                   flash_min_seq: Optional[int] = None):
+                   flash_min_seq: Optional[int] = None,
+                   window: Optional[int] = None):
     """Dispatch [b,h,t,d] q/k/v to the selected attention implementation.
 
     ``impl='auto'`` resolves through :func:`auto_attention_impl`.  An
     explicit ``impl='flash'`` never falls back: a mask, shapes the kernel
-    cannot tile, or a backend that cannot run it all raise."""
+    cannot tile, or a backend that cannot run it all raise.  ``window``
+    (causal) keeps the last ``window`` keys of each query; the call then
+    runs under the scope ``attn_window``, else under ``attn_full``."""
     from ...ops.attention import sdpa_reference
     if impl not in _ATTN_IMPLS:
         raise ValueError(f"unknown attn_impl '{impl}'; expected one of "
                          f"{_ATTN_IMPLS}")
     if impl in ("ring", "ulysses"):
         from ...parallel.sequence import ring_self_attention, ulysses_attention
-        if mask is not None:
+        if mask is not None or window is not None:
             raise ValueError("sequence-parallel attention does not take "
-                             "key-padding masks (pad to shard boundary)")
+                             "key-padding masks (pad to shard boundary) "
+                             "or a window")
         fn = ring_self_attention if impl == "ring" else ulysses_attention
         return fn(q, k, v, axis_name=seq_axis, causal=causal)
     if impl == "auto":
@@ -171,11 +176,14 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
         if mask is not None:
             raise ValueError("attn_impl='flash' does not take key-padding "
                              "masks; use 'reference'/'auto' or pre-mask inputs")
-        # the kernel names its own output and log-sum-exp (_flash_fwd)
-        from ...ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal)
-    return checkpoint_name(sdpa_reference(q, k, v, mask=mask, causal=causal),
-                           "attn_out")
+    with jax.named_scope("attn_window" if window else "attn_full"):
+        if impl == "flash":
+            # the kernel names its own output and log-sum-exp (_flash_fwd)
+            from ...ops.flash_attention import flash_attention
+            return flash_attention(q, k, v, causal=causal, window=window)
+        return checkpoint_name(
+            sdpa_reference(q, k, v, mask=mask, causal=causal, window=window),
+            "attn_out")
 
 
 def _rotary(x, theta: float):
@@ -309,13 +317,20 @@ class MultiHeadAttention(BaseLayerConf):
 
     ``positions='rotary'`` turns q and k by their absolute position
     (``_rotary``, base ``rope_theta``).  ``attention`` chooses the key set:
-    ``'full'`` or ``'eva'`` (``_eva_attention``: ``window``, ``chunk``, and
-    two learned ``[h, d]`` leaves, ``phi`` and ``mu``; causal only).  Both
-    kinds reach the flash kernels under ``attn_impl`` 'auto' or 'flash'; a
-    key-padding mask sends 'auto' to the reference and makes 'flash' raise
-    (it refuses, it never falls back), and 'eva' takes none.  Rotary
-    positions and EVA attention train and run forward; the KV-cache path
-    (``attend_cached``) refuses them.
+    ``'full'``, ``'sliding'`` (causal, the last ``window`` keys) or
+    ``'eva'`` (``_eva_attention``: ``window``, ``chunk``, and two learned
+    ``[h, d]`` leaves, ``phi`` and ``mu``; causal only).  All reach the
+    flash kernels under ``attn_impl`` 'auto' or 'flash'; a key-padding
+    mask sends 'auto' to the reference and makes 'flash' raise (it
+    refuses, it never falls back), and 'eva' takes none.
+    ``n_kv_heads`` fewer than ``n_heads`` gives grouped K/V heads: ``Wk``,
+    ``Wv`` project to ``n_kv_heads * d`` and each K/V head serves
+    ``n_heads / n_kv_heads`` query heads (expanded before the attention:
+    the kernels see ``n_heads`` of each).  ``qk_norm`` normalises q and k
+    over each head's ``d`` (``_rms_norm``, gains ``q_norm``, ``k_norm``)
+    before the positions; ``out_gate`` multiplies the attention's output
+    by ``sigmoid(x Wg)`` before the output projection.  All of these train
+    and run forward; the KV-cache path (``attend_cached``) refuses them.
 
     HAS_CARRY: the carry is a KV cache ({k, v, pos}, capacity
     ``max_cache_len``) enabling incremental decoding through
@@ -325,7 +340,7 @@ class MultiHeadAttention(BaseLayerConf):
     """
     INPUT_KIND = "rnn"
     HAS_CARRY = True
-    _BIAS_PARAMS = ("bq", "bk", "bv", "bo", "phi", "mu")
+    _BIAS_PARAMS = ("bq", "bk", "bv", "bo", "phi", "mu", "q_norm", "k_norm")
 
     n_in: int = 0
     n_out: int = 0              # model/embed dim of the output projection
@@ -342,9 +357,13 @@ class MultiHeadAttention(BaseLayerConf):
     max_cache_len: int = 512    # KV-cache capacity for incremental decode
     positions: str = "none"     # none|rotary
     rope_theta: float = 10000.0
-    attention: str = "full"     # full|eva
-    window: int = 0             # eva: keys attended exactly
+    attention: str = "full"     # full|sliding|eva
+    window: int = 0             # sliding, eva: keys attended exactly
     chunk: int = 0              # eva: keys pooled into one summary
+    n_kv_heads: int = 0         # default n_heads
+    qk_norm: bool = False       # RMSNorm over each head of q and k
+    out_gate: bool = False      # sigmoid(x Wg) on the attention's output
+    eps: float = 1e-5           # of qk_norm
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:
@@ -362,21 +381,40 @@ class MultiHeadAttention(BaseLayerConf):
         d = self.head_dim or max(1, self.n_out // self.n_heads)
         return self.n_heads, d
 
+    def _kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
     def init(self, key, itype):
         h, d = self._dims()
+        kv = self._kv_heads()
+        if h % kv:
+            raise ValueError(f"layer '{self.name}': {h} query heads are no "
+                             f"multiple of {kv} K/V heads")
         ks = jax.random.split(key, 4)
         params = {
             "Wq": self.make_weight(ks[0], (self.n_in, h * d)),
-            "Wk": self.make_weight(ks[1], (self.n_in, h * d)),
-            "Wv": self.make_weight(ks[2], (self.n_in, h * d)),
+            "Wk": self.make_weight(ks[1], (self.n_in, kv * d)),
+            "Wv": self.make_weight(ks[2], (self.n_in, kv * d)),
             "Wo": self.make_weight(ks[3], (h * d, self.n_out)),
         }
         if self.has_bias:
             params.update(bq=self.make_bias((h * d,)),
-                          bk=self.make_bias((h * d,)),
-                          bv=self.make_bias((h * d,)),
+                          bk=self.make_bias((kv * d,)),
+                          bv=self.make_bias((kv * d,)),
                           bo=self.make_bias((self.n_out,)))
-        if self.attention == "eva":
+        if self.qk_norm:
+            # gains as offsets from one (_rms_norm)
+            params.update(q_norm=jnp.zeros((d,), self._dtype()),
+                          k_norm=jnp.zeros((d,), self._dtype()))
+        if self.out_gate:
+            params["Wg"] = self.make_weight(jax.random.fold_in(key, 5),
+                                            (self.n_in, h * d))
+        if self.attention == "sliding":
+            if not (self.causal and self.window > 0):
+                raise ValueError(
+                    f"layer '{self.name}': attention='sliding' is causal "
+                    "and needs a window")
+        elif self.attention == "eva":
             if not (self.causal and self.window and self.chunk):
                 raise ValueError(
                     f"layer '{self.name}': attention='eva' is causal and "
@@ -388,31 +426,40 @@ class MultiHeadAttention(BaseLayerConf):
                     k_, (h, d), self._dtype()), -1.0, 1.0) * d ** -0.5)
         elif self.attention != "full":
             raise ValueError(f"layer '{self.name}': unknown attention "
-                             f"'{self.attention}'; expected full or eva")
+                             f"'{self.attention}'; expected full, sliding "
+                             "or eva")
         if self.positions not in ("none", "rotary"):
             raise ValueError(f"layer '{self.name}': unknown positions "
                              f"'{self.positions}'; expected none or rotary")
         return {"params": params, "state": {}}
 
     def _heads(self, x, p, w, b):
-        h, d = self._dims()
+        _, d = self._dims()
         y = x @ p[w]
         if self.has_bias:
             y = y + p[b]
         btime = y.shape[:-1]
-        return y.reshape(*btime, h, d).transpose(0, 2, 1, 3)   # [b,h,t,d]
+        return y.reshape(*btime, -1, d).transpose(0, 2, 1, 3)  # [b,h,t,d]
 
     def attend(self, p, x, *, train=False, key=None, mask=None):
         """QKV projection → attention → output projection on [b,t,f] input."""
         q = self._heads(x, p, "Wq", "bq")
         k = self._heads(x, p, "Wk", "bk")
         v = self._heads(x, p, "Wv", "bv")
+        if self.qk_norm:
+            q = _rms_norm(q, p["q_norm"], self.eps)
+            k = _rms_norm(k, p["k_norm"], self.eps)
         if self.positions == "rotary":
             q, k = _rotary(q, self.rope_theta), _rotary(k, self.rope_theta)
         # as the attention takes them: the layout a backward reads
         q = checkpoint_name(q, "attn_q")
         k = checkpoint_name(k, "attn_k")
         v = checkpoint_name(v, "attn_v")
+        group = self.n_heads // self._kv_heads()
+        if group > 1:
+            # grouped K/V heads reach the kernels expanded: each K/V head
+            # beside the query heads it serves
+            k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
         if self.attention == "eva":
             if mask is not None:
                 raise ValueError("attention='eva' takes no key-padding mask")
@@ -424,9 +471,14 @@ class MultiHeadAttention(BaseLayerConf):
             o = _run_attention(q, k, v, impl=self.attn_impl,
                                causal=self.causal, mask=mask,
                                seq_axis=self.seq_axis,
-                               flash_min_seq=self.flash_min_seq)
+                               flash_min_seq=self.flash_min_seq,
+                               window=(self.window
+                                       if self.attention == "sliding"
+                                       else None))
         b_, h, t, d = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(b_, t, h * d)
+        if self.out_gate:
+            o = o * jax.nn.sigmoid(checkpoint_name(x @ p["Wg"], "attn_gate"))
         y = o @ p["Wo"]
         if self.has_bias:
             y = y + p["bo"]
@@ -480,10 +532,14 @@ class MultiHeadAttention(BaseLayerConf):
         :meth:`_attend_paged` instead — same contract, K/V gathered
         through a block table."""
         from ...ops.attention import sdpa_reference
-        if self.positions != "none" or self.attention != "full":
+        if self.positions != "none" or self.attention != "full" or \
+                self._kv_heads() != self.n_heads or self.qk_norm or \
+                self.out_gate:
             raise NotImplementedError(
-                "the KV-cache path has no rotary positions and no eva "
-                "attention yet: such a layer trains and runs forward only")
+                "the KV-cache path has no rotary positions, no sliding or "
+                "eva attention, no grouped K/V heads, no q/k norm and no "
+                "output gate yet: such a layer trains and runs forward "
+                "only")
         if isinstance(carry, dict) and "kp" in carry:
             return self._attend_paged(p, x, carry, mask=mask)
         q = self._heads(x, p, "Wq", "bq")                 # [b,h,t,d]
@@ -667,7 +723,16 @@ class TransformerBlock(BaseLayerConf):
     no shift), ``positions='rotary'``, an explicit ``head_dim``,
     ``gated=True`` (``W2 (silu(Wg x) * (W1 x))``), ``has_bias=False``
     (no bias in any projection), ``attention='eva'`` with ``window`` and
-    ``chunk``; ``residual_dtype='float32'`` keeps the residual stream
+    ``chunk`` or ``attention='sliding'`` with ``window``, ``n_kv_heads``,
+    ``qk_norm`` and ``attn_gate`` (``MultiHeadAttention``'s grouped K/V
+    heads, per-head q/k norm and output gate), ``post_norm=True`` (a norm
+    after each half as well as before it: ``x + N2(Attn(N1 x))``, ``x +
+    N4(FFN(N3 x))``; gains ``ln1p_g``, ``ln2p_g``), and with
+    ``moe_top_k > 0`` the routed FFN without dropped tokens
+    (``nn/layers/moe.RoutedExperts``: ``moe_experts`` routed over,
+    ``moe_held=(first, count)`` of them held here, ``moe_hidden`` wide,
+    ``moe_shared`` shared experts beside them, gated and biased as the
+    block is); ``residual_dtype='float32'`` keeps the residual stream
     (the block's input, its two adds and its output) in float32 under a
     lower compute type, as EvaByte's ``fp32_skip_add`` does: the walk then
     hands the block its input uncast (``PrecisionPolicy.input_dtype``) and
@@ -677,7 +742,9 @@ class TransformerBlock(BaseLayerConf):
     INPUT_KIND = "rnn"
     HAS_CARRY = True
     _BIAS_PARAMS = ("mha_bq", "mha_bk", "mha_bv", "mha_bo", "b1", "b2",
-                    "ln1_g", "ln1_b", "ln2_g", "ln2_b", "mha_phi", "mha_mu")
+                    "ln1_g", "ln1_b", "ln2_g", "ln2_b", "mha_phi", "mha_mu",
+                    "mha_q_norm", "mha_k_norm", "ln1p_g", "ln1p_b",
+                    "ln2p_g", "ln2p_b")
 
     n_in: int = 0
     n_heads: int = 4
@@ -700,14 +767,39 @@ class TransformerBlock(BaseLayerConf):
     ffn_hidden: int = 0         # default ffn_mult * n_in
     gated: bool = False         # silu(Wg x) * (W1 x) in place of gelu(W1 x)
     has_bias: bool = True
-    attention: str = "full"     # full|eva
+    attention: str = "full"     # full|sliding|eva
     window: int = 0
     chunk: int = 0
     residual_dtype: Optional[str] = None   # None: the compute type
+    n_kv_heads: int = 0         # default n_heads
+    qk_norm: bool = False
+    attn_gate: bool = False
+    post_norm: bool = False
+    # moe_top_k > 0: moe_experts are routed over top-k with no capacity
+    # and no dropped token (RoutedExperts) in place of the top-1 path
+    moe_top_k: int = 0
+    moe_scoring: str = "softmax"    # softmax|sigmoid
+    moe_route_norm: bool = False
+    moe_route_scale: float = 1.0
+    moe_shared: int = 0
+    moe_hidden: int = 0             # default ffn_hidden
+    moe_held: Optional[Tuple[int, int]] = None   # (first, count); None: all
 
     @property
     def AUX_LOSS(self):
         return self.moe_experts > 0
+
+    def _routed(self):
+        from .moe import RoutedExperts
+        f = self.moe_hidden or self.ffn_hidden or self.ffn_mult * self.n_in
+        return RoutedExperts(
+            n_in=self.n_in, hidden=f, experts_total=self.moe_experts,
+            top_k=self.moe_top_k, scoring=self.moe_scoring,
+            route_norm=self.moe_route_norm,
+            route_scale=self.moe_route_scale,
+            shared_experts=self.moe_shared,
+            experts_held=tuple(self.moe_held) if self.moe_held else None,
+            gated=self.gated, has_bias=self.has_bias)
 
     @property
     def SAVED_NAMES(self):
@@ -735,8 +827,10 @@ class TransformerBlock(BaseLayerConf):
             return ()
         eva = ("eva_ks", "eva_vs", "eva_a") if self.attention == "eva" \
             else ()
-        return eva + ("attn_q", "attn_k", "attn_v", "mlp_up", "mlp_gate",
-                      "attn_lse", "attn_out", "block_mid")
+        # the output gate's product, as large as q and as dear
+        gate = ("attn_gate",) if self.attn_gate else ()
+        return eva + ("attn_q", "attn_k", "attn_v") + gate + (
+            "mlp_up", "mlp_gate", "attn_lse", "attn_out", "block_mid")
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:
@@ -759,7 +853,9 @@ class TransformerBlock(BaseLayerConf):
             max_cache_len=self.max_cache_len, head_dim=self.head_dim,
             has_bias=self.has_bias, positions=self.positions,
             rope_theta=self.rope_theta, attention=self.attention,
-            window=self.window, chunk=self.chunk)
+            window=self.window, chunk=self.chunk,
+            n_kv_heads=self.n_kv_heads, qk_norm=self.qk_norm,
+            out_gate=self.attn_gate, eps=self.eps)
         return m
 
     def init(self, key, itype):
@@ -771,10 +867,17 @@ class TransformerBlock(BaseLayerConf):
         if self.norm not in ("layer", "rms"):
             raise ValueError(f"layer '{self.name}': unknown norm "
                              f"'{self.norm}'; expected layer or rms")
-        if self.moe_experts > 0 and (self.gated or not self.has_bias):
-            raise ValueError(f"layer '{self.name}': the routed experts "
-                             "are ungated and biased")
-        if self.moe_experts > 0:
+        state = {}
+        if self.moe_experts > 0 and self.moe_top_k > 0:
+            routed_p, state = self._routed().init(
+                kr, self.make_weight, self.make_bias)
+            params.update(routed_p)
+        elif self.moe_experts > 0:
+            if self.gated or not self.has_bias:
+                raise ValueError(
+                    f"layer '{self.name}': the top-1 capacity path "
+                    "(moe_top_k=0) has ungated, biased experts; a gated "
+                    "or bias-free block routes with moe_top_k > 0")
             E = self.moe_experts
             params.update({
                 "router": self.make_weight(kr, (e, E)),
@@ -796,17 +899,15 @@ class TransformerBlock(BaseLayerConf):
                                                 (e, f))
         if self.norm == "rms":
             # the gain is an offset from one
-            params.update(ln1_g=jnp.zeros((e,), self._dtype()),
-                          ln2_g=jnp.zeros((e,), self._dtype()))
+            for which in ("ln1", "ln2") + (("ln1p", "ln2p")
+                                           if self.post_norm else ()):
+                params[which + "_g"] = jnp.zeros((e,), self._dtype())
         else:
-            params.update({
-                "ln1_g": jnp.ones((e,), self._dtype()),
-                "ln1_b": jnp.zeros((e,), self._dtype()),
-                "ln2_g": jnp.ones((e,), self._dtype()),
-                "ln2_b": jnp.zeros((e,), self._dtype()),
-            })
-        state = {}
-        if self.moe_experts > 0:
+            for which in ("ln1", "ln2") + (("ln1p", "ln2p")
+                                           if self.post_norm else ()):
+                params[which + "_g"] = jnp.ones((e,), self._dtype())
+                params[which + "_b"] = jnp.zeros((e,), self._dtype())
+        if self.moe_experts > 0 and not self.moe_top_k:
             state["aux_loss"] = jnp.zeros((), self._dtype())
         return {"params": params, "state": state}
 
@@ -818,8 +919,14 @@ class TransformerBlock(BaseLayerConf):
         # a stream wider than the weights: the projections compute in theirs
         return y.astype(p["mha_Wq"].dtype) if self.residual_dtype else y
 
-    def _ffn(self, p, xn):
+    def _ffn(self, p, xn, state=None):
         """Dense or routed MLP; returns (out, state_update)."""
+        if self.moe_experts > 0 and self.moe_top_k > 0:
+            b, t, e = xn.shape
+            y, st = self._routed().apply(p, state or {},
+                                         xn.reshape(b * t, e), jax.nn.silu
+                                         if self.gated else jax.nn.gelu)
+            return y.reshape(b, t, e), st
         if self.moe_experts == 0:
             up = xn @ p["W1"]
             if self.has_bias:
@@ -841,21 +948,38 @@ class TransformerBlock(BaseLayerConf):
             "aux_loss": (self.aux_loss_weight * aux).astype(
                 jnp.result_type(xn))}
 
-    def apply(self, variables, x, *, train=False, key=None, mask=None):
-        p = self.maybe_noise_weights(key, variables["params"], train)
-        x = self.maybe_dropout_input(key, x, train)
+    def _attention_half(self, p, x, *, train, key, mask):
+        """The stream after the attention half, and the FFN's input."""
         mha_p = {k[4:]: v for k, v in p.items() if k.startswith("mha_")}
         if self.residual_dtype:
             x = x.astype(self.residual_dtype)
 
         xn = self._norm(p, x, "ln1")
-        x = checkpoint_name(
-            x + self._mha().attend(mha_p, xn, train=train, key=key,
-                                   mask=mask), "block_mid")
+        att = self._mha().attend(mha_p, xn, train=train, key=key, mask=mask)
+        if self.post_norm:
+            att = self._norm(p, att, "ln1p")
+        x = checkpoint_name(x + att, "block_mid")
+        return x, self._norm(p, x, "ln2")
 
-        xn = self._norm(p, x, "ln2")
-        ff, st = self._ffn(p, xn)
+    def apply(self, variables, x, *, train=False, key=None, mask=None):
+        p = self.maybe_noise_weights(key, variables["params"], train)
+        x = self.maybe_dropout_input(key, x, train)
+        x, xn = self._attention_half(p, x, train=train, key=key, mask=mask)
+        ff, st = self._ffn(p, xn, variables.get("state"))
+        if self.post_norm:
+            ff = self._norm(p, ff, "ln2p")
         return x + ff, st if st else variables.get("state", {})
+
+    def routing(self, variables, x, *, mask=None):
+        """``(idx [b * t, k], w [b * t, k])``: the experts the routed FFN
+        (``moe_top_k > 0``) chooses for each token of the block's input
+        ``x``, and their weights — what ``apply`` routes by."""
+        if not (self.moe_experts > 0 and self.moe_top_k > 0):
+            raise ValueError(f"layer '{self.name}' has no top-k routed FFN")
+        p = variables["params"]
+        _, xn = self._attention_half(p, x, train=False, key=None, mask=mask)
+        return self._routed().route(p, variables.get("state") or {},
+                                    xn.reshape(-1, xn.shape[-1]))
 
     # ---- KV-cache incremental decoding -----------------------------------
     def init_carry(self, batch: int, dtype=jnp.float32,
@@ -872,9 +996,13 @@ class TransformerBlock(BaseLayerConf):
         xn = self._norm(p, x, "ln1")
         attn, new_carry = self._mha().attend_cached(mha_p, xn, carry,
                                                     mask=mask)
+        if self.post_norm:
+            attn = self._norm(p, attn, "ln1p")
         x = x + attn
         xn = self._norm(p, x, "ln2")
-        ff, st = self._ffn(p, xn)
+        ff, st = self._ffn(p, xn, variables.get("state"))
+        if self.post_norm:
+            ff = self._norm(p, ff, "ln2p")
         if st:
             # thread the MoE aux loss out through the caller's mutable
             # variables dict (the MLN carry path reads state after the call)

@@ -375,6 +375,7 @@ class EmbeddingSequenceLayer(BaseLayerConf):
     one_hot_matmul: bool = False
     sparse_grad: bool = False
     sparse_grad_capacity: Optional[int] = None
+    scale: float = 1.0    # on the looked-up rows (sqrt(n_out) in some LMs)
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:
         if self.n_in == 0 or override:
@@ -425,4 +426,6 @@ class EmbeddingSequenceLayer(BaseLayerConf):
             z = _sparse.embedding_lookup(W, idx)
         else:
             z = W[idx]
+        if self.scale != 1.0:
+            z = z * jnp.asarray(self.scale, z.dtype)
         return self.act_fn(z), variables.get("state", {})

@@ -39,11 +39,15 @@ def causal_mask(t_q: int, t_k: int, q_offset: int = 0, k_offset: int = 0):
     return qi >= ki
 
 
-def _apply_masks(scores, mask, causal, q_offset, k_offset):
+def _apply_masks(scores, mask, causal, q_offset, k_offset, window=None):
     t_q, t_k = scores.shape[-2], scores.shape[-1]
     if causal:
         scores = jnp.where(causal_mask(t_q, t_k, q_offset, k_offset),
                            scores, NEG_INF)
+    if window is not None:
+        # the band that moves with the query: the last `window` keys
+        scores = jnp.where(causal_mask(t_k, t_q, k_offset + window - 1,
+                                       q_offset).T, scores, NEG_INF)
     if mask is not None:
         # mask: [b, t_k] key-padding (1=valid) or [b, 1, t_q, t_k] full.
         if mask.ndim == 2:
@@ -54,13 +58,16 @@ def _apply_masks(scores, mask, causal, q_offset, k_offset):
 
 def sdpa_reference(q, k, v, *, mask=None, causal: bool = False,
                    scale: Optional[float] = None,
-                   q_offset: int = 0, k_offset: int = 0):
-    """Reference scaled-dot-product attention.  q,k,v: [b, h, t, d]."""
+                   q_offset: int = 0, k_offset: int = 0,
+                   window: Optional[int] = None):
+    """Reference scaled-dot-product attention.  q,k,v: [b, h, t, d].
+    ``window`` keeps of each query's keys those less than ``window``
+    positions before it (with ``causal``: the last ``window``)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     acc_dt = jnp.promote_types(q.dtype, jnp.float32)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(acc_dt) * scale
-    scores = _apply_masks(scores, mask, causal, q_offset, k_offset)
+    scores = _apply_masks(scores, mask, causal, q_offset, k_offset, window)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 

@@ -23,6 +23,15 @@ step.  Few large steps and not many small ones, in the body and not in the
 grid: a product of 256 x 256 scores costs about as much to start as to
 run, a grid step more (``PERF.md`` section 6, PR 28).
 
+``window=W`` (causal only) is a band that moves with the query: query
+``i`` sees the keys ``i - W < j <= i``.  The grid then walks, for each held
+block, only the blocks the band can reach (``_band_walk``: the diagonal's
+block first, then the ones before it), so a call's work follows ``t x W``
+and not ``t^2``; inside a block the band cuts, the steps are again static,
+one a tile of queries over the key tiles it can see, the mask on the tiles
+an edge crosses only (``_band_steps``).  Such calls carry names of their
+own (``flash_win_*``), so a trace tells them from full calls.
+
 Products take their operands in the type they arrive in and accumulate in
 float32 (Mosaic at its default precision gives float32 operands one
 bfloat16 pass all the same); ``p`` and ``dS`` are cast to the operand type
@@ -162,6 +171,61 @@ def _run_block(step, causal: bool, qi, ki, q_rows: int, k_rows: int,
             step(*s)
 
 
+def _band_walk(window: int, rows: int, n_blocks: int) -> int:
+    """How many blocks of ``rows`` the band reaches from one held block:
+    its own and the ``(window + rows - 2) // rows`` next to it on the
+    band's side, at most all of them."""
+    return min((window + rows - 2) // rows + 1, n_blocks)
+
+
+def _band_steps(rows: int, block_q: int, block_k: int, shift: int,
+                window: int):
+    """The work of a block whose first query lies ``shift`` positions past
+    its first key, under the causal band ``q - window < k <= q``, as
+    static ``(q_start, q_size, k_start, k_size, cut, head)`` steps, one a
+    query tile: the keys of every tile it can see in one product, of which
+    the first ``head`` keys (the tiles the band's lower edge crosses) and
+    the last ``cut`` (those the diagonal crosses) take the mask; a block
+    the band covers whole is one unmasked step."""
+    steps, whole = [], True
+    for r in range(rows // block_q):
+        q_lo = shift + r * block_q
+        q_hi = q_lo + block_q - 1
+        tiles = []                      # (full?) of the live key tiles
+        first = None
+        for c in range(rows // block_k):
+            k_lo, k_hi = c * block_k, c * block_k + block_k - 1
+            if k_lo <= q_hi and k_hi > q_lo - window:
+                first = c if first is None else first
+                tiles.append(k_hi <= q_lo and k_lo > q_hi - window)
+        whole = whole and len(tiles) == rows // block_k and all(tiles)
+        if not tiles:
+            continue
+        head = tiles.index(True) if True in tiles else len(tiles)
+        cut = tiles[::-1].index(True) if True in tiles else 0
+        if False in tiles[head:len(tiles) - cut]:
+            raise AssertionError("a masked tile between two full ones")
+        steps.append((r * block_q, block_q, first * block_k,
+                      len(tiles) * block_k, cut * block_k, head * block_k))
+    return [(0, rows, 0, rows, 0, 0)] if whole else steps
+
+
+def _run_band(step, window: int, walk, live, n_walk: int, rows: int,
+              block_q: int, block_k: int):
+    """One step of a banded grid: the ``walk``-th block the band reaches
+    from the held one — the held block's own first (so a row's first live
+    step holds its own key and its running maximum is finite), then the
+    ones before it where keys are walked, after it where queries are.
+    ``live(delta)`` says whether that block lies on the sequence at all."""
+    for delta in range(n_walk):
+        steps = _band_steps(rows, block_q, block_k, delta * rows, window)
+
+        def block(steps=steps, shift=delta * rows):
+            for s in steps:
+                step(*s, shift=shift)
+        pl.when(jnp.logical_and(walk == delta, live(delta)))(block)
+
+
 def _scaled(x, scale: float):
     """scale·x in x's own type: the scale rides the ``[rows, d]`` operand,
     not every score tile.  Exact where the scale is a power of two (d=64:
@@ -171,26 +235,43 @@ def _scaled(x, scale: float):
 
 
 def _masked_scores(q, k, q0: int, k0: int, cut: int, *,
-                   transposed: bool = False):
+                   transposed: bool = False, head: int = 0, shift: int = 0,
+                   window: Optional[int] = None):
     """(scale·q)@kᵀ — q comes in scaled — with the causal mask on the last
     ``cut`` keys, the tiles the diagonal crosses: the one definition of
     the score tile used by forward and backward (replay must match
     exactly).  ``q0``, ``k0`` are the tile's offsets from the corner the
     diagonal enters at.  ``transposed`` gives k@(scale·q)ᵀ, keys down the
-    rows, for the dk/dv kernel."""
+    rows, for the dk/dv kernel.  Under a ``window`` the first ``head``
+    keys, the tiles the band's lower edge crosses, take the mask too, and
+    ``shift`` is how far the block's first query lies past its first key."""
     a, b = (k, q) if transposed else (q, k)
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    if not cut:
+    if not (cut or head):
         return s
     qdim, kdim = (1, 0) if transposed else (0, 1)
-    below = s.shape[kdim] - cut
-    head, tail = ((s[:below], s[below:]) if transposed
-                  else (s[:, :below], s[:, below:]))
-    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, tail.shape, qdim)
-    kpos = k0 + below + jax.lax.broadcasted_iota(jnp.int32, tail.shape, kdim)
-    tail = jnp.where(qpos >= kpos, tail, NEG_INF)
-    return jnp.concatenate([head, tail], axis=kdim) if below else tail
+    n = s.shape[kdim]
+    if head + cut >= n:
+        head, cut = 0, n
+    below = n - cut
+
+    def keys(lo, hi):
+        return s[lo:hi] if transposed else s[:, lo:hi]
+
+    def masked(part, start):
+        qpos = shift + q0 + jax.lax.broadcasted_iota(jnp.int32, part.shape,
+                                                     qdim)
+        kpos = k0 + start + jax.lax.broadcasted_iota(jnp.int32, part.shape,
+                                                     kdim)
+        seen = qpos >= kpos
+        if window is not None:
+            seen = jnp.logical_and(seen, kpos > qpos - window)
+        return jnp.where(seen, part, NEG_INF)
+    parts = ([masked(keys(0, head), 0)] if head else []) + \
+        ([keys(head, below)] if below > head else []) + \
+        ([masked(keys(below, n), below)] if cut else [])
+    return jnp.concatenate(parts, axis=kdim) if len(parts) > 1 else parts[0]
 
 
 def _lanes(x, n: int):
@@ -210,8 +291,10 @@ def _column(x):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                  *, scale: float, causal: bool, block_q: int, block_k: int):
-    # grid: (heads, blocks of queries, blocks of keys)
+                  *, scale: float, causal: bool, block_q: int, block_k: int,
+                  window: Optional[int] = None, n_walk: int = 0):
+    # grid: (heads, blocks of queries, blocks of keys); under a window the
+    # last is the walk along the band (_run_band)
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     q_rows, d = q_ref.shape
@@ -222,11 +305,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def step(q0, nq, k0, nkeys, cut):
+    def step(q0, nq, k0, nkeys, cut, head=0, shift=0):
         rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nq, d]
         v = v_ref[keys, :]                           # [nkeys, d]
-        s = _masked_scores(q, k_ref[keys, :], q0, k0, cut)
+        s = _masked_scores(q, k_ref[keys, :], q0, k0, cut, head=head,
+                           shift=shift, window=window)
         # m, l: lane-replicated [rows, 128], so the row statistics meet
         # the score tile vreg for vreg with no lane broadcast
         m_prev = m_ref[rows, :]
@@ -241,8 +325,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                 preferred_element_type=jnp.float32))
         m_ref[rows, :] = m_new
 
-    _run_block(step, causal, qi, ki, q_rows, k_ref.shape[0],
-               block_q, block_k)
+    if window is None:
+        _run_block(step, causal, qi, ki, q_rows, k_ref.shape[0],
+                   block_q, block_k)
+    else:
+        _run_band(step, window, ki, lambda delta: qi >= delta, n_walk,
+                  q_rows, block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -277,17 +365,32 @@ def _specs(d: int, held: int, walked: int, clamp=None):
                          lambda b, h, w: (b, 0, live(h, w))))
 
 
-def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
+def _band(window, t_k, rows):
+    """Of a windowed launch: the kernel's keyword arguments, and the block
+    walked at the ``w``-th step from held block ``h`` where keys are walked
+    and where queries are.  Of a full launch: nothing, ``None``, ``None``."""
+    if window is None:
+        return {}, None, None
+    n_blocks = t_k // rows
+    return ({"window": window,
+             "n_walk": _band_walk(window, rows, n_blocks)},
+            lambda w, h: jnp.maximum(h - w, 0),
+            lambda w, h: jnp.minimum(h + w, n_blocks - 1))
+
+
+def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
+                window=None):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  d * qr.dtype.itemsize)
+    band, keys_at, _ = _band(window, t_k, q_rows)
     q_spec, k_spec, row_spec, _ = _specs(
-        d, q_rows, k_rows, jnp.minimum if causal else None)
+        d, q_rows, k_rows, keys_at or (jnp.minimum if causal else None))
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, t_q // q_rows, t_k // k_rows),
+                          block_q=block_q, block_k=block_k, **band),
+        grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
         in_specs=[q_spec, k_spec, k_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[_like(qr, (bh, t_q, d)),
@@ -298,14 +401,14 @@ def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((q_rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_win_fwd" if window else "flash_fwd",
     )(qr, kr, vr)
     return out, lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                          dq_ref, dq_acc, *, scale, causal,
-                         block_q, block_k):
+                         block_q, block_k, window=None, n_walk=0):
     """dq of one block of queries: replay P from the saved logsumexp, form
     dS = P∘(dP − D) (FlashAttention-2 bwd) and add dS·k over the keys;
     the scale meets the ``[rows, d]`` sum once at the end."""
@@ -316,13 +419,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def step(q0, nq, k0, nkeys, cut):
+    def step(q0, nq, k0, nkeys, cut, head=0, shift=0):
         rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nq, d]
         k = k_ref[keys, :]                           # [nkeys, d]
         lse = _column(lse_ref[:, rows])              # [nq, 128]
         dd = _column(dd_ref[:, rows])
-        s = _masked_scores(q, k, q0, k0, cut)
+        s = _masked_scores(q, k, q0, k0, cut, head=head, shift=shift,
+                           window=window)
         p = jnp.exp(s - _lanes(lse, nkeys))
         dp = jax.lax.dot_general(do_ref[rows, :], v_ref[keys, :],
                                  (((1,), (1,)), ((), ())),
@@ -332,8 +436,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
-               block_q, block_k)
+    if window is None:
+        _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
+                   block_q, block_k)
+    else:
+        _run_band(step, window, ki, lambda delta: qi >= delta, n_walk,
+                  q_ref.shape[0], block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -342,7 +450,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          block_q, block_k):
+                          block_q, block_k, window=None, n_walk=0):
     """dk, dv of one block of keys, on the transposed score tile (keys
     down the rows, queries along the lanes): lse and D are used as the
     lane-major rows they are stored as, and Pᵀ·dO, dSᵀ·q are plain
@@ -355,12 +463,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def step(q0, nqs, k0, nkeys, cut):
+    def step(q0, nqs, k0, nkeys, cut, head=0, shift=0):
         rows, keys = pl.ds(q0, nqs), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nqs, d]
         do = do_ref[rows, :]
         st = _masked_scores(q, k_ref[keys, :], q0, k0, cut,
-                            transposed=True)         # [nkeys, nqs]
+                            transposed=True, head=head, shift=shift,
+                            window=window)           # [nkeys, nqs]
         pt = jnp.exp(st - lse_ref[:, rows])
         dv_acc[keys, :] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -373,8 +482,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
-               block_q, block_k)
+    if window is None:
+        _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
+                   block_q, block_k)
+    else:
+        _run_band(step, window, qi,
+                  lambda delta: ki + delta < pl.num_programs(1), n_walk,
+                  q_ref.shape[0], block_q, block_k)
 
     @pl.when(qi == nq - 1)
     def _done():
@@ -382,20 +496,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(qr, kr, vr, scale, causal, block_q, block_k, interpret,
-           with_lse=False):
+           with_lse=False, window=None):
     """The output, and with ``with_lse`` the rows' log-sum-exp
     ``(bh, 1, t_q)`` beside it, as a second differentiable result."""
     out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
-                           interpret)
+                           interpret, window)
     return (out, lse) if with_lse else out
 
 
 def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
-               with_lse=False):
+               with_lse=False, window=None):
     out, lse = _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k,
-                           interpret)
+                           interpret, window)
     # the backward's residuals are results of this rule, not of the
     # caller's code: named here, or a checkpoint policy that saves by name
     # (nn/scan_layers) reruns the forward kernel in the backward
@@ -405,23 +519,24 @@ def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
 
 
 def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
-                       block_k, interpret):
+                       block_k, interpret, window=None):
     bh, t_q, d = qr.shape
     t_k = kr.shape[1]
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  d * qr.dtype.itemsize)
+    band, keys_at, queries_at = _band(window, t_k, q_rows)
     q_spec, k_spec, row_spec, _ = _specs(
-        d, q_rows, k_rows, jnp.minimum if causal else None)
+        d, q_rows, k_rows, keys_at or (jnp.minimum if causal else None))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, t_q // q_rows, t_k // k_rows),
+                          block_q=block_q, block_k=block_k, **band),
+        grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_like(qr),
         scratch_shapes=[pltpu.VMEM((q_rows, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_win_bwd_dq" if window else "flash_bwd_dq",
     )(qr, kr, vr, do, lse, dd)
 
     # swapped roles: a block of keys held, queries walked, so dk/dv carry
@@ -431,24 +546,24 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     block_q, block_k = (_DKV_TILE if b % _DKV_TILE == 0 else b
                         for b in (block_q, block_k))
     k_spec2, q_spec2, _, row_spec2 = _specs(
-        d, k_rows, q_rows, jnp.maximum if causal else None)
+        d, k_rows, q_rows, queries_at or (jnp.maximum if causal else None))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, t_k // k_rows, t_q // q_rows),
+                          block_q=block_q, block_k=block_k, **band),
+        grid=(bh, t_k // k_rows, band.get("n_walk", t_q // q_rows)),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[k_spec2, k_spec2],
         out_shape=[_like(kr), _like(vr)],
         scratch_shapes=[pltpu.VMEM((k_rows, d), jnp.float32),
                         pltpu.VMEM((k_rows, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
     )(qr, kr, vr, do, lse, dd)
     return dq, dk, dv
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, with_lse, res,
-               do):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, with_lse,
+               window, res, do):
     qr, kr, vr, out, lse = res
     if with_lse:
         do, dlse = do
@@ -460,15 +575,19 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, with_lse, res,
         # dlse_i * p_ij to dS = P∘(dP − D): the kernels take it as D − dlse
         dd = dd - dlse
     return _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
-                       block_k, interpret)
+                       block_k, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 #: kernel names as they appear in the lowered program's ``tpu_custom_call``
-#: ops — what a caller greps ``as_text()`` for to prove the kernels are in
-KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+#: ops — what a caller greps ``as_text()`` for to prove the kernels are in:
+#: the three of a full call, the three of a windowed one
+FULL_KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+WINDOW_KERNEL_NAMES = ("flash_win_fwd", "flash_win_bwd_dq",
+                       "flash_win_bwd_dkv")
+KERNEL_NAMES = FULL_KERNEL_NAMES + WINDOW_KERNEL_NAMES
 
 
 def flash_blocks(t_q: int, t_k: int, d: int,
@@ -521,7 +640,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False, return_lse: bool = False):
+                    interpret: bool = False, return_lse: bool = False,
+                    window: Optional[int] = None):
     """Flash attention over [b, h, t, d] tensors — differentiable: the
     FlashAttention-2 style backward (saved logsumexp, softmax replayed per
     block, separate dq and dk/dv kernels) keeps training memory O(t).
@@ -531,6 +651,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (the same three kernels; a cotangent on it rides the backward's ``D``):
     what a caller needs to merge this softmax with one over further keys
     (``ops.attention.combine_blocks`` with ``m = lse``, ``l = 1``).
+
+    ``window=W`` (with ``causal``, queries and keys of one length) keeps
+    of each query's keys the last ``W``, itself among them: ``i - W < j <=
+    i``.  The kernels then skip every block outside that band (their names
+    become ``flash_win_*``); a window no shorter than the sequence is the
+    plain causal call.
 
     Never falls back: shapes the kernel cannot tile raise ``ValueError``
     (``flash_blocks``), and off a TPU backend the Pallas lowering itself
@@ -543,12 +669,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k)
     if scale is None:
         scale = d ** -0.5
+    if window is not None:
+        if not causal or t_q != t_k or window < 1:
+            raise ValueError(
+                f"a window ({window}) needs causal attention of queries "
+                f"and keys of one length: causal={causal}, t_q={t_q}, "
+                f"t_k={t_k}")
+        window = int(window) if window < t_k else None
 
     def run(q, k, v):
         rows = q.shape[0] * h
         out = _flash(q.reshape(rows, t_q, d), k.reshape(rows, t_k, d),
                      v.reshape(rows, t_k, d), scale, causal, block_q,
-                     block_k, interpret, return_lse)
+                     block_k, interpret, return_lse, window)
         if return_lse:
             return out[0].reshape(q.shape), out[1].reshape(q.shape[:3])
         return out.reshape(q.shape)

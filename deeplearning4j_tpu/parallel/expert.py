@@ -10,9 +10,20 @@ experts while tokens stay sharded over data — the collective rides ICI.
 
 Use under ``shard_map`` with mesh axes ("data", "expert"); see
 ``make_moe_train_step`` and ``tests/test_expert.py``.
+
+``routed_ffn`` is the other formulation, the one today's sparse decoders
+train with: top-k routing with no capacity and no dropped token.  The
+(token, slot) pairs are sorted by expert and the experts' groups go
+through grouped matrix products (``jax.lax.ragged_dot``), so there is no
+``[T, E, C]`` one-hot.  It is told which experts it holds
+(``held=(first, count)`` of the router's ``experts_total`` outputs): it
+routes over all of them, computes the part of the result that its own
+experts give, and spends no product on a pair routed elsewhere.  On one
+chip there is no exchange, and nothing stands in for the absent chips.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -20,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-__all__ = ["init_moe_params", "moe_ffn", "make_moe_train_step"]
+__all__ = ["init_moe_params", "moe_ffn", "make_moe_train_step",
+           "route_top_k", "routed_ffn"]
 
 
 def init_moe_params(key, n_experts: int, embed: int, hidden: int,
@@ -117,3 +129,158 @@ def make_moe_train_step(capacity: int, lr: float = 0.1,
         return new_params, loss
 
     return step
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing without dropped tokens: sort by expert, grouped products
+# ---------------------------------------------------------------------------
+
+def route_top_k(logits, top_k: int, *, scoring: str = "softmax",
+                bias=None, route_norm: bool = False,
+                route_scale: float = 1.0):
+    """``(idx [T, k] int32, w [T, k] float32)`` from router logits ``[T,
+    E]``, all in float32: scores by ``scoring`` (``softmax`` over the
+    experts or ``sigmoid`` of each), the ``top_k`` largest of ``scores +
+    bias`` chosen (``bias``: a balancing buffer that moves the choice and
+    not the weight), their own scores as weights, divided by their sum
+    under ``route_norm``, times ``route_scale``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown scoring '{scoring}'; expected softmax "
+                         "or sigmoid")
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * route_scale
+
+
+def _pairs_of(x, order, k: int):
+    """Row ``order[i] // k`` of ``x`` for each sorted pair ``i``."""
+    return x[order // k]
+
+
+def _sum_pairs(ys, inverse, here, k: int):
+    """Each token's ``k`` pairs, found again at ``inverse``, those routed
+    elsewhere (``here`` false: rows no product wrote) taken as nought."""
+    t = here.shape[0]
+    back = ys[inverse].reshape(t, k, ys.shape[-1])
+    return jnp.where(here[..., None], back, jnp.zeros((), ys.dtype))
+
+
+# The dispatch and its transpose are each other's derivative: a row
+# gather both ways, where autodiff's own transpose of a gather is a
+# scatter-add over 4 KB rows.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inverse, here, k):
+    return _pairs_of(x, order, k)
+
+
+def _dispatch_fwd(x, order, inverse, here, k):
+    return _pairs_of(x, order, k), (order, inverse, here)
+
+
+def _dispatch_bwd(k, res, g):
+    order, inverse, here = res
+    dx = jnp.sum(_sum_pairs(g, inverse, here, k), axis=1,
+                 dtype=jnp.float32).astype(g.dtype)
+    return dx, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _undispatch(ys, order, inverse, here, k):
+    """``[T, k, D]``: the sorted pairs' rows back beside their tokens."""
+    return _sum_pairs(ys, inverse, here, k)
+
+
+def _undispatch_fwd(ys, order, inverse, here, k):
+    return _sum_pairs(ys, inverse, here, k), (order, inverse, here)
+
+
+def _undispatch_bwd(k, res, g):
+    order, inverse, here = res
+    t = here.shape[0]
+    g = jnp.where(here[..., None], g, jnp.zeros((), g.dtype))
+    return g.reshape(t * k, g.shape[-1])[order], None, None, None
+
+
+_undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
+
+
+def routed_ffn(params: Dict[str, jax.Array], x: jax.Array, *, top_k: int,
+               scoring: str = "softmax", route_norm: bool = False,
+               route_scale: float = 1.0,
+               held: Optional[Tuple[int, int]] = None, bias=None,
+               act=jax.nn.silu) -> Tuple[jax.Array, jax.Array]:
+    """Routed FFN over tokens ``x [T, D]`` with no token dropped.
+
+    ``params``: ``router [D, E]`` over ALL ``E`` experts; ``w1 [H, D, F]``,
+    ``w2 [H, F, D]`` of the ``H`` experts held here, experts ``first ..
+    first + H - 1`` of the router's (``held=(first, H)``; all of them where
+    not given); with ``wg [H, D, F]`` the experts are gated, ``w2 (act(wg
+    x) * (w1 x))``, else ``w2 act(w1 x)``; ``b1 [H, 1, F]``, ``b2 [H, 1,
+    D]`` are added where present.  Returns ``(y [T, D], tokens [H]
+    int32)``: ``y_t = sum over the experts e chosen for t and held here of
+    w_te Expert_e(x_t)``, and how many pairs each held expert took.
+
+    Every token is routed (``route_top_k``); the ``T * top_k`` (token,
+    slot) pairs are sorted by expert, the held experts' first and in order,
+    pairs routed elsewhere last, and each held expert's group goes through
+    the grouped products.  The buffers hold all ``T * top_k`` pairs, what
+    no drop under any routing takes (``E / H`` times what an even routing
+    sends here); the products walk the held groups alone, the gathers into
+    and out of the experts' order move all of it.  (Recomputing the part
+    between the sort and the sum in the backward pass, under
+    ``jax.checkpoint``, was tried on the chip: the step that fits either
+    way is 1.5 % slower with it, ``PERF.md`` section 6, PR 33.)
+    """
+    t, d = x.shape
+    n_total = params["router"].shape[1]
+    first, n_held = held or (0, n_total)
+    if params["w1"].shape[0] != n_held or first + n_held > n_total:
+        raise ValueError(f"held experts {(first, n_held)} do not match "
+                         f"{params['w1'].shape[0]} expert weights of "
+                         f"{n_total} routed")
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x, params["router"],
+                         preferred_element_type=jnp.float32)
+        idx, w = route_top_k(logits, top_k, scoring=scoring, bias=bias,
+                             route_norm=route_norm, route_scale=route_scale)
+    with jax.named_scope("moe_dispatch"):
+        local = idx - first
+        here = jnp.logical_and(local >= 0, local < n_held)       # [T, k]
+        key = jnp.where(here, local, n_held).reshape(t * top_k)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
+            jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held,
+                                                   dtype=jnp.int32)[None],
+                        axis=0, dtype=jnp.int32)
+
+    gated, biased = "wg" in params, "b1" in params
+    with jax.named_scope("moe_dispatch"):
+        xs = _dispatch(x, order, inverse, here, top_k)           # [T*k, D]
+        if biased:
+            eid = jnp.minimum(key[order], n_held - 1)
+    with jax.named_scope("moe_experts"):
+        up = lax.ragged_dot(xs, params["w1"], sizes)
+        if biased:
+            up = up + params["b1"][eid, 0]
+        hidden = act(lax.ragged_dot(xs, params["wg"], sizes)) * up \
+            if gated else act(up)
+        ys = lax.ragged_dot(hidden, params["w2"], sizes)
+        if biased:
+            ys = ys + params["b2"][eid, 0]
+    with jax.named_scope("moe_combine"):
+        back = _undispatch(ys, order, inverse, here, top_k)      # [T, k, D]
+        y = jnp.einsum("tkd,tk->td", back, w.astype(back.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    return y, sizes

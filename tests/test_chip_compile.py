@@ -69,7 +69,7 @@ def _kernel_calls(compiled) -> dict:
     calls = [line for line in compiled.as_text().split("\n")
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name: sum(f"/{name}/pallas_call" in c for c in calls)
-            for name in F.KERNEL_NAMES}
+            for name in F.FULL_KERNEL_NAMES}
 
 
 def _while_stacks(compiled, shape: str) -> int:
@@ -116,7 +116,7 @@ def test_flash_backward_dq_and_dkv_compile_for_v5e(one_chip, t, rows):
         return jnp.sum(_flash_rows(q, k, v, t).astype(jnp.float32))
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
     text = lowered.as_text()
-    assert all(name in text for name in F.KERNEL_NAMES)
+    assert all(name in text for name in F.FULL_KERNEL_NAMES)
     assert _custom_calls(lowered.compile()) == 3
 
 
@@ -226,13 +226,13 @@ def test_lm_train_step_compiles_with_flash_kernels(topo, four_chips, chips,
             ids, ids, None, None)
     lowered = net._get_jitted("train_step").audit_lower((args, {}))
     text = lowered.as_text()
-    assert all(name in text for name in F.KERNEL_NAMES)
+    assert all(name in text for name in F.FULL_KERNEL_NAMES)
     compiled = lowered.compile()
     assert _custom_calls(compiled) == 3
     # one scan body each way, and each kernel once: the policy the scan
     # saves by (the block's names) does not replay the forward kernel in
     # the backward, on one chip or inside the shard_map of four
-    assert _kernel_calls(compiled) == dict.fromkeys(F.KERNEL_NAMES, 1)
+    assert _kernel_calls(compiled) == dict.fromkeys(F.FULL_KERNEL_NAMES, 1)
     if chips == 4:
         # parameters and optimizer state at about a quarter per chip
         whole = sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -249,7 +249,7 @@ def test_lm_train_step_compiles_with_flash_kernels(topo, four_chips, chips,
     monkeypatch.setattr(TransformerBlock, "SAVED_NAMES", ())
     everything = jax.jit(_build_train_step(net.conf, net._tx, False),
                          donate_argnums=(0, 1, 2, 3)).lower(*args).compile()
-    assert _kernel_calls(everything) == dict.fromkeys(F.KERNEL_NAMES, 1)
+    assert _kernel_calls(everything) == dict.fromkeys(F.FULL_KERNEL_NAMES, 1)
     named_temp = compiled.memory_analysis().temp_size_in_bytes
     all_temp = everything.memory_analysis().temp_size_in_bytes
     assert named_temp < 0.9 * all_temp, (named_temp, all_temp)
@@ -324,7 +324,7 @@ def test_evabyte_step_fits_with_the_names_its_remat_run_keeps(
     assert 0.8e9 < sum(sizes[n] for n in names) < 0.9e9
     assert sizes["attn_q"] == 4 * 8192 * 4096 * 2
     assert sizes["mlp_up"] == 4 * 8192 * 11008 * 2
-    assert all(name in lowered.as_text() for name in F.KERNEL_NAMES)
+    assert all(name in lowered.as_text() for name in F.FULL_KERNEL_NAMES)
     memory = lowered.compile().memory_analysis()
     assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - SPARE
     assert memory.argument_size_in_bytes > 9.8e9
@@ -350,3 +350,70 @@ def test_evabyte_step_at_half_the_length_keeps_more_and_fits(one_chip,
     assert names == tuple(sizes) and len(names) == 11
     memory = lowered.compile().memory_analysis()
     assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - SPARE
+
+
+# ---- the windowed kernels and the step that runs them (PR 33) -------------
+# [32 heads, 8192, 128] bfloat16 under a window of 2048 is the call of a
+# sliding layer of the benchmark's trinity-mini-5l.train-fit-8k; 1000 is a
+# window that is no multiple of the tile or of the grid's block
+@pytest.mark.parametrize("window", [2048, 1000])
+def test_windowed_flash_kernels_compile_for_v5e(one_chip, window):
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(F.flash_attention(q, k, v, causal=True,
+                                         window=window).astype(jnp.float32))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    text = lowered.as_text()
+    assert all(name in text for name in F.WINDOW_KERNEL_NAMES)
+    assert not any(name in text for name in F.FULL_KERNEL_NAMES)
+    assert _custom_calls(lowered.compile()) == 3
+
+
+def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
+                                                       monkeypatch):
+    """The benchmark's ``trinity-mini-5l`` train step (1 dense + 4 routed
+    layers at published widths, 16 of 128 experts, an eighth of the
+    vocabulary, bfloat16, ``cache_mode`` none) lowered from shapes for one
+    described v5e at one row of 8192 tokens: both kinds of kernel are in
+    it, a windowed one for each of the four sliding layers and a full one
+    for the fifth, the routed layers' grouped products are the TPU's own
+    (no ``[T, E, C]`` one-hot), and arguments and program fit the
+    compiler's limit with room."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import common
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = common.load_json("configs", "trinity-mini-5l.json")
+    traffic = common.load_module("traffic", "moe_lm_fit_stream")
+    held = {}
+
+    def built():
+        # 705 M parameters and Adam's moments: shapes alone
+        net = held["net"] = traffic.build(cfg)
+        return net.params, net.state, net.opt_state, net._rng
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(built))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    lowered = held["net"]._get_jitted("train_step").audit_lower(
+        (args + (ids, ids, None, None), {}))
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+
+    def count(name):
+        return sum(f"/{name}/pallas_call" in c for c in calls)
+    assert {n: count(n) for n in F.KERNEL_NAMES} == {
+        **dict.fromkeys(F.WINDOW_KERNEL_NAMES, 4),
+        **dict.fromkeys(F.FULL_KERNEL_NAMES, 1)}
+    # three grouped products a routed layer, once more where the backward
+    # rebuilds them, and two a product in the backward itself
+    assert sum("ragged-dot" in c and "metadata" not in c.split("=")[0]
+               for c in calls) >= 4 * 3
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(8.466e9, rel=1e-3)
+    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 8 * SPARE

@@ -162,7 +162,8 @@ def test_model_selector():
 def test_model_selector_type_filter():
     from deeplearning4j_tpu.models import ModelSelector
     rnn = ModelSelector.select("rnn")
-    assert set(rnn) == {"TextGenerationLSTM", "TransformerLM", "EvaByteLM"}
+    assert set(rnn) == {"TextGenerationLSTM", "TransformerLM", "EvaByteLM",
+                        "TrinityLM"}
     cnn = ModelSelector.select("cnn")
     assert "TextGenerationLSTM" not in cnn and "LeNet" in cnn
 
