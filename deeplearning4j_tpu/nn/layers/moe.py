@@ -9,8 +9,11 @@ call: top-k routing with softmax or sigmoid scores, no capacity and no
 dropped token, shared experts beside the routed ones, and told which of
 the router's experts it holds (``parallel/expert.routed_ffn``).  The
 tokens each held expert took are threaded through layer *state*
-(``expert_tokens``) as the auxiliary loss is, and published as the gauge
-``moe_expert_tokens{layer, expert}`` where ``fit`` reads the loss.
+(``expert_tokens``) as the auxiliary loss is, and beside them the count of
+calls whose routing overflowed the buffers' pair capacity
+(``expert_overflows``); both are published as gauges
+(``moe_expert_tokens{layer, expert}``, ``moe_overflow_steps{layer}``)
+where ``fit`` reads the loss.
 
 The top-1 Switch path (``top_k = 0``, the default): a stack of expert FFNs
 with a fixed capacity for static shapes, tokens over it dropped.  Its
@@ -50,9 +53,12 @@ class RoutedExperts:
     ``w2`` (``wg``; ``b1``, ``b2``) stacked over the held experts, ``s1``,
     ``s2`` (``sg``; ``sb1``, ``sb2``) of the shared ones.  State:
     ``route_bias [experts_total]``, the balancing buffer added to the
-    scores for the choice alone (zero, and nothing here moves it), and
+    scores for the choice alone (zero, and nothing here moves it),
     ``expert_tokens [count]``, the pairs each held expert took in the
-    last call."""
+    last call, and ``expert_overflows``, how many calls so far sent the
+    held experts more pairs than ``pair_capacity`` (every expert held:
+    never): those calls computed every pair all the same, on whole-size
+    buffers, and a layer where the count keeps rising pays for them."""
     n_in: int
     hidden: int
     experts_total: int
@@ -97,7 +103,8 @@ class RoutedExperts:
                 params.update(sb1=make_bias((fs,)), sb2=make_bias((out,)))
         state = {"route_bias": jnp.zeros((self.experts_total,),
                                          jnp.float32),
-                 "expert_tokens": jnp.zeros((n,), jnp.int32)}
+                 "expert_tokens": jnp.zeros((n,), jnp.int32),
+                 "expert_overflows": jnp.zeros((), jnp.int32)}
         return params, state
 
     def route(self, p, state, x):
@@ -112,17 +119,24 @@ class RoutedExperts:
         """``(y [T, n_out], new state)`` of tokens ``x [T, n_in]``: the
         shared experts' output and the held routed experts' part."""
         from ...observability.registry import default_registry
-        from ...parallel.expert import routed_ffn
+        from ...parallel.expert import (overflowed, pair_capacity,
+                                        routed_ffn)
         first, n = self.held()
+        pairs = x.shape[0] * self.top_k
         reg = default_registry()
         if reg.enabled:
             # trace-time, like scan_runs_traced_total
+            labels = (str(n), str(self.experts_total), str(self.top_k))
             reg.counter("moe_layers_traced_total",
                         "Routed FFNs traced into a program, by the experts "
                         "held, the experts routed over and the experts a "
                         "token", ("held", "total", "top_k")).labels(
-                            str(n), str(self.experts_total),
-                            str(self.top_k)).inc()
+                            *labels).inc()
+            reg.gauge("moe_pair_capacity",
+                      "(token, slot) pairs the buffers of the routed FFN "
+                      "last traced hold", ("held", "total", "top_k")).labels(
+                          *labels).set(
+                              pair_capacity(pairs, n, self.experts_total))
         routed = {k: p[k] for k in ("router", "w1", "w2", "wg", "b1", "b2")
                   if k in p}
         y, tokens = routed_ffn(
@@ -139,20 +153,23 @@ class RoutedExperts:
                 if self.has_bias:
                     shared = shared + p["sb2"]
             y = y + shared
-        new_state = {"expert_tokens": tokens}
+        overflows = state.get("expert_overflows", jnp.zeros((), jnp.int32))
+        new_state = {"expert_tokens": tokens,
+                     "expert_overflows": overflows + overflowed(
+                         tokens, pairs, self.experts_total).astype(jnp.int32)}
         if "route_bias" in state:
             new_state["route_bias"] = state["route_bias"]
         return y, new_state
 
 
 def publish_expert_tokens(model) -> None:
-    """Gauge ``moe_expert_tokens{layer, expert}`` from the state of every
-    layer that threads ``expert_tokens``: called where ``fit`` has just
-    read the loss, so the step that wrote them is done and the read waits
-    for nothing."""
+    """Gauges ``moe_expert_tokens{layer, expert}`` and
+    ``moe_overflow_steps{layer}`` from the state of every layer that
+    threads ``expert_tokens``: called where ``fit`` has just read the loss,
+    so the step that wrote them is done and the read waits for nothing."""
     from ...observability.registry import default_registry
     reg = default_registry()
-    layers = {name: st["expert_tokens"]
+    layers = {name: (st["expert_tokens"], st.get("expert_overflows", 0))
               for name, st in (getattr(model, "state", None) or {}).items()
               if isinstance(st, dict) and "expert_tokens" in st}
     if not layers or not reg.enabled:
@@ -160,10 +177,16 @@ def publish_expert_tokens(model) -> None:
     gauge = reg.gauge("moe_expert_tokens",
                       "(token, slot) pairs each held expert took in the "
                       "last step", ("layer", "expert"))
+    steps = reg.gauge("moe_overflow_steps",
+                      "Steps so far whose routing sent the held experts more "
+                      "pairs than moe_pair_capacity (each computed in full, "
+                      "on whole-size buffers)", ("layer",))
     host = jax.device_get(layers)
     children = [(gauge.labels(name, str(i)), float(v))
-                for name, counts in host.items()
+                for name, (counts, _) in host.items()
                 for i, v in enumerate(counts)]
+    children += [(steps.labels(name), float(over))
+                 for name, (_, over) in host.items()]
     for child, value in children:
         child.set(value)
 

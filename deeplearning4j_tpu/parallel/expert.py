@@ -24,6 +24,7 @@ chip there is no exchange, and nothing stands in for the absent chips.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -32,7 +33,7 @@ import numpy as np
 from jax import lax
 
 __all__ = ["init_moe_params", "moe_ffn", "make_moe_train_step",
-           "route_top_k", "routed_ffn"]
+           "overflowed", "pair_capacity", "route_top_k", "routed_ffn"]
 
 
 def init_moe_params(key, n_experts: int, embed: int, hidden: int,
@@ -160,59 +161,119 @@ def route_top_k(logits, top_k: int, *, scoring: str = "softmax",
     return idx.astype(jnp.int32), w * route_scale
 
 
-def _pairs_of(x, order, k: int):
-    """Row ``order[i] // k`` of ``x`` for each sorted pair ``i``."""
-    return x[order // k]
+# Pair capacity of a layer that holds some of the experts it routes over,
+# as a multiple of what an even routing sends it, in whole tiles of rows.
+# (1.25 and 1.5 read 1-3 % faster on the benchmark's one routing, a router
+# nothing balances, and overflowed there in up to an eighth of the
+# layer-steps where 2 does in one of a hundred: PERF.md section 6, PR 34.)
+SLACK = 2
+_PAIR_TILE = 1024
 
 
-def _sum_pairs(ys, inverse, here, k: int):
-    """Each token's ``k`` pairs, found again at ``inverse``, those routed
-    elsewhere (``here`` false: rows no product wrote) taken as nought."""
-    t = here.shape[0]
-    back = ys[inverse].reshape(t, k, ys.shape[-1])
-    return jnp.where(here[..., None], back, jnp.zeros((), ys.dtype))
+def pair_capacity(pairs: int, held: int, total: int) -> int:
+    """Rows of the routed FFN's buffers where ``held`` of the ``total``
+    experts routed over live here and ``pairs`` (token, slot) pairs are
+    routed in all: every pair where all experts are held, else ``SLACK``
+    times the even routing's share, rounded up to a tile of rows."""
+    if held >= total:
+        return pairs
+    tiles = math.ceil(SLACK * pairs * held / total / _PAIR_TILE)
+    return min(pairs, tiles * _PAIR_TILE)
+
+
+def overflowed(sizes, pairs: int, total: int):
+    """Whether the held experts, which took ``sizes [H]`` of the ``pairs``
+    routed over ``total`` experts, took more than their buffers hold: the
+    one rule for the branch ``routed_ffn`` takes and for who counts it."""
+    return jnp.sum(sizes) > pair_capacity(pairs, sizes.shape[0], total)
+
+
+def _token_sum(rows, at, mine, scale=None):
+    """``[T, D]`` float32: each token's own rows of ``rows [C, D]``, found
+    at ``at [T, k]`` where ``mine``, weighted by ``scale [T, k]``; the
+    other slots (pairs routed elsewhere, rows no product wrote) nought.
+    An inverse gather into ``[T, k, D]`` summed over ``k``: on the chip the
+    fastest of four ways to the same sum (``PERF.md`` section 5, PR 34)."""
+    back = jnp.where(mine[..., None], rows[at], jnp.zeros((), rows.dtype))
+    if scale is None:
+        return jnp.sum(back, axis=1, dtype=jnp.float32)
+    return jnp.einsum("tkd,tk->td", back, scale.astype(back.dtype),
+                      preferred_element_type=jnp.float32)
 
 
 # The dispatch and its transpose are each other's derivative: a row
 # gather both ways, where autodiff's own transpose of a gather is a
-# scatter-add over 4 KB rows.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, order, inverse, here, k):
-    return _pairs_of(x, order, k)
+# scatter-add over 4 KB rows.  ``pairs [C]``: the (token, slot) pair of
+# each buffer row; ``at``, ``mine [T, k]``: the buffer row of each of a
+# token's pairs, where it has one.
+@jax.custom_vjp
+def _dispatch(x, pairs, at, mine):
+    return x[pairs // mine.shape[1]]
 
 
-def _dispatch_fwd(x, order, inverse, here, k):
-    return _pairs_of(x, order, k), (order, inverse, here)
+def _dispatch_fwd(x, pairs, at, mine):
+    return _dispatch(x, pairs, at, mine), (at, mine)
 
 
-def _dispatch_bwd(k, res, g):
-    order, inverse, here = res
-    dx = jnp.sum(_sum_pairs(g, inverse, here, k), axis=1,
-                 dtype=jnp.float32).astype(g.dtype)
-    return dx, None, None, None
+def _dispatch_bwd(res, g):
+    return _token_sum(g, *res).astype(g.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _undispatch(ys, order, inverse, here, k):
-    """``[T, k, D]``: the sorted pairs' rows back beside their tokens."""
-    return _sum_pairs(ys, inverse, here, k)
+@jax.custom_vjp
+def _combine(ys, w, pairs, live, at, mine):
+    """``[T, D]`` float32: each token's weighted sum of its rows of ``ys
+    [C, D]``, ``live`` where a product wrote them."""
+    return _token_sum(ys, at, mine, w)
 
 
-def _undispatch_fwd(ys, order, inverse, here, k):
-    return _sum_pairs(ys, inverse, here, k), (order, inverse, here)
+def _combine_fwd(ys, w, pairs, live, at, mine):
+    return _combine(ys, w, pairs, live, at, mine), \
+        (ys, w, pairs, live, at, mine)
 
 
-def _undispatch_bwd(k, res, g):
-    order, inverse, here = res
-    t = here.shape[0]
-    g = jnp.where(here[..., None], g, jnp.zeros((), g.dtype))
-    return g.reshape(t * k, g.shape[-1])[order], None, None, None
+def _combine_bwd(res, g):
+    # in the buffer's rows: one gather of g, and no [T, k, D] of either
+    ys, w, pairs, live, at, mine = res
+    gt = g[pairs // w.shape[1]].astype(jnp.float32)              # [C, D]
+    wc = w.astype(ys.dtype).reshape(-1)[pairs].astype(jnp.float32)
+    g_ys = jnp.where(live[:, None], gt * wc[:, None], 0.0).astype(ys.dtype)
+    g_wc = jnp.sum(ys.astype(jnp.float32) * gt, axis=1)
+    g_w = jnp.where(mine, g_wc[at], 0.0).astype(w.dtype)
+    return (g_ys, g_w) + (None,) * 4
 
 
-_undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _held_part(experts, x, w, key, order, inverse, here, sizes, *,
+               rows: int, act):
+    """``y [T, D]`` through buffers of the first ``rows`` sorted pairs:
+    exact where the held experts took no more than that."""
+    t, k = here.shape
+    n_held = sizes.shape[0]
+    gated, biased = "wg" in experts, "b1" in experts
+    with jax.named_scope("moe_dispatch"):
+        pairs = order[:rows]
+        live = jnp.arange(rows, dtype=jnp.int32) < jnp.sum(sizes)
+        # the held experts' pairs sort first: theirs lie under ``rows``
+        at = jnp.minimum(inverse.reshape(t, k), rows - 1)
+        xs = _dispatch(x, pairs, at, here)                       # [C, D]
+        if biased:
+            eid = jnp.minimum(key[pairs], n_held - 1)
+    with jax.named_scope("moe_experts"):
+        up = lax.ragged_dot(xs, experts["w1"], sizes)
+        if biased:
+            up = up + experts["b1"][eid, 0]
+        hidden = act(lax.ragged_dot(xs, experts["wg"], sizes)) * up \
+            if gated else act(up)
+        ys = lax.ragged_dot(hidden, experts["w2"], sizes)
+        if biased:
+            ys = ys + experts["b2"][eid, 0]
+    with jax.named_scope("moe_combine"):
+        return _combine(ys, w, pairs, live, at, here).astype(x.dtype)
 
 
 def routed_ffn(params: Dict[str, jax.Array], x: jax.Array, *, top_k: int,
@@ -234,13 +295,19 @@ def routed_ffn(params: Dict[str, jax.Array], x: jax.Array, *, top_k: int,
     Every token is routed (``route_top_k``); the ``T * top_k`` (token,
     slot) pairs are sorted by expert, the held experts' first and in order,
     pairs routed elsewhere last, and each held expert's group goes through
-    the grouped products.  The buffers hold all ``T * top_k`` pairs, what
-    no drop under any routing takes (``E / H`` times what an even routing
-    sends here); the products walk the held groups alone, the gathers into
-    and out of the experts' order move all of it.  (Recomputing the part
-    between the sort and the sum in the backward pass, under
-    ``jax.checkpoint``, was tried on the chip: the step that fits either
-    way is 1.5 % slower with it, ``PERF.md`` section 6, PR 33.)
+    the grouped products.  The buffers hold ``pair_capacity`` sorted pairs:
+    all of them where every expert is held (then there is one path and no
+    conditional), else ``SLACK`` times what an even routing sends here, so
+    the gather into the experts' order, the products' operands and what the
+    backward keeps have that many rows; only the per-token sum still
+    gathers ``[T, top_k, D]`` on its way, out of the small buffer, and keeps
+    none of it.  A routing that sends the held experts more is computed all
+    the same, no pair dropped, by the same code on buffers of all ``T *
+    top_k`` pairs under one ``lax.cond``; that branch is checkpointed, so it
+    keeps nothing whole-size for the backward either, and costs about what
+    every step cost before the buffers were sized (``PERF.md`` section 6,
+    PRs 33 and 34).  ``overflowed(tokens, T * top_k, E)`` says which of the
+    two a step took.
     """
     t, d = x.shape
     n_total = params["router"].shape[1]
@@ -254,33 +321,25 @@ def routed_ffn(params: Dict[str, jax.Array], x: jax.Array, *, top_k: int,
                          preferred_element_type=jnp.float32)
         idx, w = route_top_k(logits, top_k, scoring=scoring, bias=bias,
                              route_norm=route_norm, route_scale=route_scale)
+    n_pairs = t * top_k
     with jax.named_scope("moe_dispatch"):
         local = idx - first
         here = jnp.logical_and(local >= 0, local < n_held)       # [T, k]
-        key = jnp.where(here, local, n_held).reshape(t * top_k)
+        key = jnp.where(here, local, n_held).reshape(n_pairs)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
-            jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
+        inverse = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
+            jnp.arange(n_pairs, dtype=jnp.int32), unique_indices=True)
         sizes = jnp.sum(key[:, None] == jnp.arange(n_held,
                                                    dtype=jnp.int32)[None],
                         axis=0, dtype=jnp.int32)
-
-    gated, biased = "wg" in params, "b1" in params
-    with jax.named_scope("moe_dispatch"):
-        xs = _dispatch(x, order, inverse, here, top_k)           # [T*k, D]
-        if biased:
-            eid = jnp.minimum(key[order], n_held - 1)
-    with jax.named_scope("moe_experts"):
-        up = lax.ragged_dot(xs, params["w1"], sizes)
-        if biased:
-            up = up + params["b1"][eid, 0]
-        hidden = act(lax.ragged_dot(xs, params["wg"], sizes)) * up \
-            if gated else act(up)
-        ys = lax.ragged_dot(hidden, params["w2"], sizes)
-        if biased:
-            ys = ys + params["b2"][eid, 0]
-    with jax.named_scope("moe_combine"):
-        back = _undispatch(ys, order, inverse, here, top_k)      # [T, k, D]
-        y = jnp.einsum("tkd,tk->td", back, w.astype(back.dtype),
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+    experts = {k: v for k, v in params.items() if k != "router"}
+    rows = pair_capacity(n_pairs, n_held, n_total)
+    operands = (experts, x, w, key, order, inverse, here, sizes)
+    if rows == n_pairs:
+        return _held_part(*operands, rows=rows, act=act), sizes
+    y = lax.cond(
+        overflowed(sizes, n_pairs, n_total),
+        jax.checkpoint(functools.partial(_held_part, rows=n_pairs, act=act)),
+        functools.partial(_held_part, rows=rows, act=act),
+        *operands)
     return y, sizes

@@ -21,12 +21,14 @@ from benchmark import common  # noqa: E402
 from deeplearning4j_tpu.nn.layers.attention import (MultiHeadAttention,  # noqa: E402
                                                     TransformerBlock)
 from deeplearning4j_tpu.nn.layers.moe import (MixtureOfExpertsLayer,  # noqa: E402
-                                              RoutedExperts)
+                                              RoutedExperts,
+                                              publish_expert_tokens)
 from deeplearning4j_tpu.nn.multilayer import _stack_loss  # noqa: E402
 from deeplearning4j_tpu.observability.registry import (MetricsRegistry,  # noqa: E402
                                                        default_registry,
                                                        set_default_registry)
-from deeplearning4j_tpu.parallel.expert import routed_ffn  # noqa: E402
+from deeplearning4j_tpu.parallel.expert import (pair_capacity,  # noqa: E402
+                                                routed_ffn)
 
 ref = common.load_module("reference", "trinity")
 traffic = common.load_module("traffic", "moe_lm_fit_stream")
@@ -240,29 +242,62 @@ def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
     assert int(state["expert_tokens"].sum()) == 64 * 4
 
 
-def test_no_token_is_dropped_under_a_router_biased_to_one_expert():
-    """Every token sends a pair to expert 3 (its router column dominates),
-    64 pairs onto one expert of 16 where an even routing gives it 16: each
-    is computed, none dropped; the top-1 capacity path at the same load
-    drops three quarters of them."""
+# Which buffers a routing lands on (``pair_capacity``: twice the even
+# routing's share in whole tiles of 1024 rows, at most every pair): all 256
+# pairs of 64 tokens ("whole": the one path of a small layer, as of a layer
+# that holds every expert); of 512 tokens' 2048 pairs onto 2 or 3 of 16
+# experts a capacity of 1024, with under it the pairs one favoured expert
+# draws ("compact"), exactly it where every token chooses both held experts
+# ("boundary"), and over it where every token chooses all three held
+# ("overflow": computed in full by the whole-size branch).
+PATHS = {"whole": (64, (2, 2), (3,)), "compact": (512, (2, 2), (3,)),
+         "boundary": (512, (2, 2), (2, 3)), "overflow": (512, (2, 3),
+                                                         (2, 3, 4))}
+
+
+def _share(whole, first, n):
+    """The router whole and experts ``first .. first + n - 1`` of a layer."""
+    return {"router": whole["router"], "wg": whole["eg"][first:first + n],
+            "w1": whole["e1"][first:first + n],
+            "w2": whole["e2"][first:first + n]}
+
+
+def _lands_on(path, tokens, top_k, took, n_held, total=16):
+    capacity = pair_capacity(tokens * top_k, n_held, total)
+    sent = int(jnp.sum(took))
+    if path == "whole":
+        assert capacity == tokens * top_k
+    else:
+        assert capacity == 1024 < tokens * top_k
+        assert {"compact": sent < capacity, "boundary": sent == capacity,
+                "overflow": sent > capacity}[path], (sent, capacity)
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_no_token_is_dropped_under_a_router_biased_to_one_expert(path):
+    """Every token sends a pair to each favoured expert (its router column
+    dominates), 64 pairs onto one expert of 16 where an even routing gives
+    it 16: each is computed, none dropped; the top-1 capacity path at the
+    same load drops three quarters of them.  So on every path of
+    ``PATHS``, the overflowing one among them."""
+    tokens, (first, n), favoured = PATHS[path]
     whole = _layer_params(jax.random.PRNGKey(7))
-    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(8), (64, 32))) + 0.1
-    whole["router"] = whole["router"].at[:, 3].set(5.0)
-    mine = {"router": whole["router"], "wg": whole["eg"][2:4],
-            "w1": whole["e1"][2:4], "w2": whole["e2"][2:4]}
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(8), (tokens, 32))) + 0.1
+    whole["router"] = whole["router"].at[:, np.asarray(favoured)].set(5.0)
+    mine = _share(whole, first, n)
     y, took = routed_ffn(mine, x, top_k=4, scoring="sigmoid",
-                         route_norm=True, route_scale=1.0, held=(2, 2))
-    assert int(took[1]) == 64
+                         route_norm=True, route_scale=1.0, held=(first, n))
+    assert all(int(took[e - first]) == tokens for e in favoured)
+    _lands_on(path, tokens, 4, took, n)
     uncut = {**SMALL, "num_experts": 16}
     _, chosen = ref.routed_ffn(uncut, whole, x, 1.0)
-    assert (np.asarray(chosen) == 3).any(axis=1).all()
-    # the held experts' part of every token, against a loop over the two
-    idx, w = np.asarray(chosen), None
+    assert all((np.asarray(chosen) == e).any(axis=1).all() for e in favoured)
+    # the held experts' part of every token, against a loop over them
     scores = jax.nn.sigmoid(x @ whole["router"])
     sel = jnp.take_along_axis(scores, chosen, axis=1)
     w = sel / jnp.sum(sel, axis=1, keepdims=True)
     want = 0.0
-    for e in (2, 3):
+    for e in range(first, first + n):
         we = jnp.sum(jnp.where(chosen == e, w, 0.0), axis=1)
         want = want + we[:, None] * ref.mlp(ref._highest, x, whole["eg"][e],
                                             whole["e1"][e], whole["e2"][e])
@@ -270,30 +305,200 @@ def test_no_token_is_dropped_under_a_router_biased_to_one_expert():
     assert float(jnp.min(jnp.linalg.norm(y, axis=1))) > 0
 
 
-def test_the_gradient_of_the_routed_part_is_the_loops():
-    whole = _layer_params(jax.random.PRNGKey(9), total=8)
-    x = jax.random.normal(jax.random.PRNGKey(10), (48, 32))
-    mine = {"router": whole["router"], "wg": whole["eg"][4:8],
-            "w1": whole["e1"][4:8], "w2": whole["e2"][4:8]}
+@pytest.mark.parametrize("path", ["all-held"] + list(PATHS))
+def test_the_gradient_of_the_routed_part_is_the_loops(path):
+    """Every gradient (router, the three expert matrices, the input)
+    against the loop over the held experts, 1e-5 absolute; ``all-held`` is
+    the case this test had before the buffers were sized (4 of 8 experts,
+    top-3, softmax scores, no favoured expert, 48 tokens), the others are
+    ``PATHS`` under a balancing bias that favours experts in the choice and
+    not in the weights.  Their loss is weighted by 48 over the tokens, so
+    that a weight's gradient, a sum over 512 tokens, has entries of the
+    order 48 tokens give and the one tolerance means what it meant."""
+    if path == "all-held":
+        tokens, total, (first, n), k, favoured = 48, 8, (4, 4), 3, ()
+    else:
+        (tokens, (first, n), favoured), total, k = PATHS[path], 16, 4
+    whole = _layer_params(jax.random.PRNGKey(9), total=total)
+    x = jax.random.normal(jax.random.PRNGKey(10), (tokens, 32))
+    mine = _share(whole, first, n)
+    bias = jnp.zeros((total,)).at[np.asarray(favoured, int)].set(2.0)
+    weight = 48 / tokens
 
     def program(p, x):
-        return jnp.sum(jnp.sin(routed_ffn(
-            p, x, top_k=3, scoring="softmax", held=(4, 4))[0]))
+        y, took = routed_ffn(p, x, top_k=k, scoring="softmax",
+                             held=(first, n), bias=bias)
+        return weight * jnp.sum(jnp.sin(y)), took
 
     def loop(p, x):
         probs = jax.nn.softmax(x @ p["router"], axis=-1)
-        w, idx = jax.lax.top_k(probs, 3)
+        _, idx = jax.lax.top_k(probs + bias, k)
+        w = jnp.take_along_axis(probs, idx, axis=1)
         y = 0.0
-        for e in range(4):
-            we = jnp.sum(jnp.where(idx == 4 + e, w, 0.0), axis=1)
+        for e in range(n):
+            we = jnp.sum(jnp.where(idx == first + e, w, 0.0), axis=1)
             y = y + we[:, None] * ((jax.nn.silu(x @ p["wg"][e])
                                     * (x @ p["w1"][e])) @ p["w2"][e])
-        return jnp.sum(jnp.sin(y))
-    got = jax.grad(program, argnums=(0, 1))(mine, x)
+        return weight * jnp.sum(jnp.sin(y))
+    got, took = jax.grad(program, argnums=(0, 1), has_aux=True)(mine, x)
+    if path != "all-held":
+        _lands_on(path, tokens, k, took, n)
     want = jax.grad(loop, argnums=(0, 1))(mine, x)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+# -------------------------------------------- what the sized buffers hold
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _held_subset_vjp(tokens=512, held=(2, 2), total=16, k=4):
+    """The jaxpr of ``routed_ffn``'s forward and backward at a size whose
+    capacity (1024) is half its 2048 pairs."""
+    whole = _layer_params(jax.random.PRNGKey(11), total=total)
+    x = jax.random.normal(jax.random.PRNGKey(12), (tokens, 32))
+    first, n = held
+    mine = _share(whole, first, n)
+
+    def both(p, x):
+        y, back = jax.vjp(lambda p, x: routed_ffn(
+            p, x, top_k=k, scoring="sigmoid", held=held)[0], p, x)
+        return back(jnp.ones_like(y))
+    return jax.make_jaxpr(both)(mine, x).jaxpr
+
+
+def test_a_held_subset_keeps_and_multiplies_capacity_rows_alone():
+    """512 tokens x 4 onto 2 of 16 experts: one conditional each way, and
+    what its forward hands the backward (the step's saved buffers) has
+    1024 rows or the tokens' 512, never the 2048 pairs; on the branch a
+    routing within the capacity takes, every product's operands have 1024
+    rows, and nothing of 2048 rows is 32 wide but what the per-token sum
+    gathers on its way (``[512, 4, 32]``, read once and kept nowhere); the
+    other branch is the whole-size code, checkpointed."""
+    jaxpr = _held_subset_vjp()
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 2
+    forward = conds[0]
+    whole_size = {(2048, 32), (2048, 16), (512, 4, 32), (512, 4, 16)}
+    assert not whole_size & {v.aval.shape for v in forward.outvars}
+    assert (1024, 32) in {v.aval.shape for v in forward.outvars}
+    # branches are (false, true): the predicate is ``overflowed``
+    compact, overflow = forward.params["branches"]
+    products = [e for e in _eqns(compact.jaxpr)
+                if e.primitive.name == "ragged_dot_general"]
+    assert len(products) == 3
+    assert all(e.invars[0].aval.shape[0] == 1024 for e in products)
+    wide = [e.outvars[0].aval.shape for e in _eqns(compact.jaxpr)
+            if e.outvars and e.outvars[0].aval.shape in ((2048, 32),
+                                                         (512, 4, 32))]
+    assert wide and set(wide) == {(512, 4, 32)}
+    # the overflow branch multiplies all 2048 and saves none of it: its
+    # backward is one checkpointed replay
+    assert all(e.invars[0].aval.shape[0] == 2048
+               for e in _eqns(overflow.jaxpr)
+               if e.primitive.name == "ragged_dot_general")
+    replayed = conds[1].params["branches"][1].jaxpr.eqns
+    assert [e.primitive.name for e in replayed] == ["remat2"]
+
+
+def test_a_layer_holding_all_its_experts_lowers_with_no_conditional():
+    whole = _layer_params(jax.random.PRNGKey(11))
+    x = jax.random.normal(jax.random.PRNGKey(12), (512, 32))
+    p = {"router": whole["router"], "wg": whole["eg"], "w1": whole["e1"],
+         "w2": whole["e2"]}
+    assert pair_capacity(2048, 16, 16) == 2048
+
+    def loss(p, x):
+        return jnp.sum(routed_ffn(p, x, top_k=4, scoring="sigmoid")[0])
+    step = jax.grad(loss, argnums=(0, 1))
+    assert not any(e.primitive.name == "cond"
+                   for e in _eqns(jax.make_jaxpr(step)(p, x).jaxpr))
+    text = jax.jit(step).lower(p, x).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    # the same layer holding 2 of the 16 does have one
+    mine = {**p, **{k: p[k][2:4] for k in ("wg", "w1", "w2")}}
+    text = jax.jit(lambda p, x: routed_ffn(
+        p, x, top_k=4, scoring="sigmoid", held=(2, 2))[0]).lower(
+            mine, x).as_text()
+    assert "stablehlo.case" in text or "stablehlo.if" in text
+
+
+def test_pair_capacity_is_twice_the_even_share_in_whole_tiles():
+    # the benchmark's share: 8192 tokens x 8 onto 16 of 128 experts
+    assert pair_capacity(8192 * 8, 16, 128) == 16384
+    assert pair_capacity(8192 * 8, 128, 128) == 8192 * 8
+    assert pair_capacity(2048, 2, 16) == pair_capacity(2048, 3, 16) == 1024
+    assert pair_capacity(2048, 7, 16) == 2048      # 1792 -> two tiles: all
+    assert pair_capacity(256, 2, 16) == 256        # under a tile: all
+
+
+def test_overflowing_steps_are_counted_and_published():
+    """``expert_overflows`` rises by one a call whose routing sends the held
+    experts more pairs than the capacity, and by none otherwise; the gauges
+    read what the state and the trace hold."""
+    module = RoutedExperts(n_in=32, hidden=16, experts_total=16, top_k=4,
+                           scoring="sigmoid", route_norm=True,
+                           experts_held=(2, 3), gated=True, has_bias=False)
+    make = lambda key, shape: 0.3 * jax.random.normal(key, shape)  # noqa: E731
+    p, state = module.init(jax.random.PRNGKey(0), make, jnp.zeros)
+    assert int(state["expert_overflows"]) == 0
+    x = jax.random.normal(jax.random.PRNGKey(1), (512, 32))
+    before = set_default_registry(MetricsRegistry())
+    try:
+        pushed = {**state, "route_bias": state["route_bias"].at[2:5].set(9.)}
+        apply = jax.jit(lambda st: module.apply(p, st, x, jax.nn.silu)[1])
+        one = apply(pushed)
+        two = apply({**one, "route_bias": pushed["route_bias"]})
+        assert int(one["expert_overflows"]) == 1
+        assert int(two["expert_overflows"]) == 2
+        assert int(two["expert_tokens"].sum()) == 3 * 512 > 1024
+        calm = apply({**two, "route_bias": state["route_bias"]})
+        assert int(calm["expert_overflows"]) == 2
+        assert int(calm["expert_tokens"].sum()) < 1024
+        # a state from before the key was there counts from nought
+        old = {k: v for k, v in pushed.items() if k != "expert_overflows"}
+        assert int(module.apply(p, old, x, jax.nn.silu)[1][
+            "expert_overflows"]) == 1
+
+        class Model:
+            state = {"layer_7": two, "layer_8": calm, "layer_9": {}}
+        publish_expert_tokens(Model())
+        steps = default_registry().get("moe_overflow_steps")
+        assert {k: c.value for k, c in steps.samples()} == {
+            ("layer_7",): 2.0, ("layer_8",): 2.0}
+        capacity = default_registry().get("moe_pair_capacity")
+        assert capacity.labels("3", "16", "4").value == 1024
+    finally:
+        set_default_registry(before)
+
+
+def test_a_state_tree_saved_before_the_overflow_count_loads(tmp_path):
+    """A model file whose routed layers' state has no ``expert_overflows``
+    (one written before PR 34) loads into today's network: the count starts
+    at nought and training goes on."""
+    from deeplearning4j_tpu.utils import model_serializer
+    net = traffic.build(SMALL)
+    keys = set(net.state["layer_2"])
+    assert keys == {"route_bias", "expert_tokens", "expert_overflows"}
+    net.state = {name: ({k: v for k, v in st.items()
+                         if k != "expert_overflows"}
+                        if isinstance(st, dict) else st)
+                 for name, st in net.state.items()}
+    path = str(tmp_path / "old.zip")
+    model_serializer.write_model(net, path)
+    again = model_serializer.restore_model(path)
+    assert set(again.state["layer_2"]) == keys
+    assert int(again.state["layer_2"]["expert_overflows"]) == 0
+    ids = np.random.default_rng(1).integers(0, 48, (2, 33)).astype(np.int32)
+    again.fit([(ids[:, :-1], ids[:, 1:])])
+    assert np.isfinite(again.get_score())
+    assert int(again.state["layer_5"]["expert_overflows"]) == 0
 
 
 # ----------------------------------------------- the layers that call it
@@ -326,7 +531,8 @@ def test_a_gated_bias_free_block_routes_and_the_top1_path_says_how():
                              attn_impl="reference")
     v = block.init(jax.random.PRNGKey(0), itype)
     assert v["params"]["wg"].shape == (8, 16, 12)
-    assert set(v["state"]) == {"route_bias", "expert_tokens"}
+    assert set(v["state"]) == {"route_bias", "expert_tokens",
+                               "expert_overflows"}
     y, state = block.apply(v, jnp.ones((2, 8, 16)))
     assert y.shape == (2, 8, 16) and int(state["expert_tokens"].sum()) == 32
     with pytest.raises(ValueError, match="moe_top_k"):
