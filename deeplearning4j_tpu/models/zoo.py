@@ -785,6 +785,135 @@ class TrinityLM(ZooModel):
 ALL_MODELS.append(TrinityLM)
 
 
+@dataclass
+class JoyAIFlashLM(ZooModel):
+    """JoyAI-LLM-Flash's architecture (https://huggingface.co/jdopensource/
+    JoyAI-LLM-Flash, ``model_type`` ``joyai_llm_flash``; DeepSeek-V3's
+    design, arXiv:2412.19437): a decoder of bias-free pre-norm blocks with
+    two gain-only RMSNorms each, multi-head latent attention
+    (``nn/layers/attention.LatentAttention``: low-rank q, a joint latent
+    for K and V, heads 192 wide in q and k and 128 in v, rotary positions
+    on adjacent pairs of 64 of the 192), ``dense_layers`` leading layers
+    with a gated SiLU MLP and then layers whose FFN is routed (sigmoid
+    scores over ``experts``, the ``top_k`` largest, weights normalised and
+    scaled, a shared expert, no token dropped), a final RMSNorm and an
+    untied head; and ONE multi-token-prediction module: the embedding of
+    the next token beside the trunk's last hidden state, merged
+    (``NextTokenMerge``), one more routed block, a norm of its own, scored
+    by the trunk's embedding and head for the token after next.
+
+    A ``ComputationGraph``, because it has two outputs from one embedding
+    and one head: the row of ``seq_len + 1`` token ids is embedded once
+    and sliced into the two streams (``TimeSliceVertex``), the head runs
+    once over both streams laid end to end (``TimeConcatVertex``), and the
+    label mask carries the module's weight, so the step's loss is ``L_main
+    + mtp_weight * L_mtp`` (``batch`` builds ids, targets and mask).  The
+    module's vertices are traced under the scope ``mtp``.
+    ``experts_held = (first, count)`` and a ``vocab_size`` that is a slice
+    of the published one make this one chip's share of an expert-parallel
+    deployment, as ``TrinityLM``'s do.  The defaults are the published
+    48B-A2.7B model's."""
+    model_type = "rnn"
+    vocab_size: int = 129280
+    seq_len: int = 131072
+    embed: int = 2048
+    n_heads: int = 32
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    ffn_hidden: int = 7168
+    moe_hidden: int = 768
+    experts: int = 256
+    experts_held: Optional[Tuple[int, int]] = None
+    top_k: int = 8
+    shared_experts: int = 1
+    route_scale: float = 2.5
+    dense_layers: int = 1
+    n_layers: int = 40
+    rope_theta: float = 32e6
+    eps: float = 1e-6
+    attn_impl: str = "auto"
+    cache_mode: str = "none"
+
+    def _block(self, routed: bool):
+        from ..nn.layers.attention import TransformerBlock
+        return TransformerBlock(
+            n_heads=self.n_heads, attention="latent",
+            head_dim=self.nope_dim, rope_dim=self.rope_dim,
+            v_head_dim=self.v_dim, latent_q_rank=self.q_rank,
+            latent_kv_rank=self.kv_rank, rope_theta=self.rope_theta,
+            causal=True, attn_impl=self.attn_impl, eps=self.eps, norm="rms",
+            gated=True, has_bias=False, ffn_hidden=self.ffn_hidden,
+            moe_experts=self.experts if routed else 0,
+            moe_top_k=self.top_k if routed else 0, moe_scoring="sigmoid",
+            moe_route_norm=True, moe_route_scale=self.route_scale,
+            moe_shared=self.shared_experts if routed else 0,
+            moe_hidden=self.moe_hidden,
+            moe_held=self.experts_held if routed else None)
+
+    def init(self) -> ComputationGraph:
+        from ..nn.conf.computation_graph import (TimeConcatVertex,
+                                                 TimeSliceVertex)
+        from ..nn.layers.attention import NextTokenMerge, RMSNormLayer
+        from ..nn.layers.feedforward import EmbeddingSequenceLayer
+        g = (self._builder()
+             .updater(self.updater or Adam(learning_rate=3e-4))
+             .weight_init("xavier").cache_mode(self.cache_mode)
+             .graph_builder())
+        g.add_inputs("ids").set_input_types(
+            InputType.recurrent(self.vocab_size, self.seq_len + 1))
+        # one row of seq_len + 1 ids, embedded once: tokens 0..T-1 feed the
+        # trunk, tokens 1..T the module
+        g.add_layer("embed", EmbeddingSequenceLayer(n_out=self.embed), "ids")
+        g.add_vertex("trunk_in", TimeSliceVertex(0, -1), "embed")
+        g.add_vertex("next_in", TimeSliceVertex(1, None), "embed")
+        h = "trunk_in"
+        for i in range(self.n_layers):
+            g.add_layer(f"block_{i}", self._block(i >= self.dense_layers), h)
+            h = f"block_{i}"
+        g.add_layer("norm", RMSNormLayer(eps=self.eps), h)
+        g.add_vertex("mtp_in", MergeVertex(), "next_in", h, scope="mtp")
+        g.add_layer("mtp_merge", NextTokenMerge(n_out=self.embed,
+                                                eps=self.eps),
+                    "mtp_in", scope="mtp")
+        g.add_layer("mtp_block", self._block(True), "mtp_merge", scope="mtp")
+        g.add_layer("mtp_norm", RMSNormLayer(eps=self.eps), "mtp_block",
+                    scope="mtp")
+        # the one head, over both streams laid end to end
+        g.add_vertex("streams", TimeConcatVertex(), "norm", "mtp_norm")
+        g.add_layer("head", RnnOutputLayer(n_out=self.vocab_size,
+                                           has_bias=False,
+                                           activation="softmax",
+                                           loss="sparse_mcxent"), "streams")
+        g.set_outputs("head")
+        return ComputationGraph(g.build()).init()
+
+    @staticmethod
+    def batch(ids, mtp_weight: float = 0.3):
+        """One training batch from rows of ``t + 1`` token ids ``[b, t +
+        1]``: ``([ids], [y], None, [mask])`` with ``y [b, 2 t]`` the trunk's
+        targets (the next token of positions ``0..t-1``) then the module's
+        (the token after next; the last position has none) and ``mask [b, 2
+        t]`` one on the trunk's positions, ``mtp_weight`` on the module's
+        and nought on its last: the loss the graph then computes is
+        ``L_main + mtp_weight * L_mtp``, each a sum over a row's tokens."""
+        import numpy as np
+        ids = np.asarray(ids)
+        b, t = ids.shape[0], ids.shape[1] - 1
+        y = np.concatenate([ids[:, 1:], ids[:, 2:],
+                            np.zeros((b, 1), ids.dtype)], axis=1)
+        mask = np.concatenate(
+            [np.ones((b, t), np.float32),
+             np.full((b, t - 1), mtp_weight, np.float32),
+             np.zeros((b, 1), np.float32)], axis=1)
+        return [ids], [y], None, [mask]
+
+
+ALL_MODELS.append(JoyAIFlashLM)
+
+
 class ModelSelector:
     """Select zoo models by name/type (reference
     ``deeplearning4j-zoo/.../ModelSelector.java``: select(ZooType) returns a

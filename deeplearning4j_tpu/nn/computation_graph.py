@@ -13,6 +13,7 @@ layers (reference computeGradientAndScore, ComputationGraph.java:1310-1320).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -95,8 +96,11 @@ def _graph_forward(conf, params, state, inputs: List[Array], *, train: bool,
         variables = {"params": params.get(name, {}),
                      "state": state.get(name, {})}
         # one scope per vertex, named by its layer conf's class (see
-        # nn/multilayer._stack_forward)
-        with jax.named_scope(type(getattr(v, "layer", None) or v).__name__):
+        # nn/multilayer._stack_forward); a vertex the builder gave a scope
+        # of its own (a module of several vertices) runs under that first
+        scope = conf.vertex_scopes.get(name)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext(), \
+                jax.named_scope(type(getattr(v, "layer", None) or v).__name__):
             if train and conf.defaults.get("cache_mode") == "remat" and \
                     isinstance(v, LayerVertex):
                 # rematerialize per-vertex activations on the backward pass

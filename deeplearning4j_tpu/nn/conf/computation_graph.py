@@ -5,7 +5,10 @@ the vertex configs in ``nn/conf/graph/`` (ElementWiseVertex, MergeVertex,
 SubsetVertex, StackVertex/UnstackVertex, ScaleVertex/ShiftVertex,
 L2NormalizeVertex, L2Vertex, ReshapeVertex, PreprocessorVertex, PoolHelper,
 plus the rnn vertices ``nn/conf/graph/rnn/LastTimeStepVertex`` and
-``DuplicateToTimeSeriesVertex``).
+``DuplicateToTimeSeriesVertex``), and two rnn vertices the reference has
+no need of, ``TimeSliceVertex`` and ``TimeConcatVertex``: a range of a
+sequence's steps, and sequences laid end to end (one embedding and one
+output layer serving two streams of one row of tokens).
 
 Design: the graph is data — a dict of named vertex configs plus an input-name
 map.  Topological order and all shapes (InputTypes) are resolved at
@@ -428,6 +431,66 @@ class DuplicateToTimeSeriesVertex(GraphVertexConf):
         return jnp.repeat(x[:, None, :], t, axis=1), variables.get("state", {})
 
 
+@register_serde
+@dataclass
+class TimeSliceVertex(GraphVertexConf):
+    """Steps ``start`` up to (not including) ``stop`` of a sequence, as a
+    Python slice reads them (``stop`` ``None``: to the end; negative: from
+    the end): RNN ``[b, t, f]`` -> ``[b, t', f]``.  The mask is cut the
+    same way."""
+    start: int = 0
+    stop: Optional[int] = None
+
+    def _cut(self, x):
+        return x[:, self.start:self.stop]
+
+    def output_type(self, itypes):
+        t = itypes[0]
+        steps = t.timesteps
+        if steps is not None and steps > 0:
+            steps = len(range(steps)[self.start:self.stop])
+        return InputType.recurrent(t.size, steps)
+
+    def apply(self, variables, inputs, *, train=False, key=None, masks=None):
+        return self._cut(inputs[0]), variables.get("state", {})
+
+    def feed_forward_mask(self, masks, inputs=None):
+        m = masks[0] if masks else None
+        return None if m is None else self._cut(m)
+
+
+@register_serde
+@dataclass
+class TimeConcatVertex(GraphVertexConf):
+    """Sequences of one width laid end to end along TIME: ``[b, t1, f]``,
+    ``[b, t2, f]``, ... -> ``[b, t1 + t2 + ..., f]``, so that one layer
+    (an output layer, say) serves several streams in one call.  An input
+    without a mask counts as all ones where another brings one."""
+
+    def n_inputs(self):
+        return (1, -1)
+
+    def output_type(self, itypes):
+        steps = [t.timesteps for t in itypes]
+        known = all(s is not None and s > 0 for s in steps)
+        return InputType.recurrent(itypes[0].size,
+                                   sum(steps) if known else -1)
+
+    def apply(self, variables, inputs, *, train=False, key=None, masks=None):
+        return jnp.concatenate(inputs, axis=1), variables.get("state", {})
+
+    def feed_forward_mask(self, masks, inputs=None):
+        if all(m is None for m in masks):
+            return None
+        if inputs is None:
+            raise ValueError("TimeConcatVertex: mixed masked/unmasked "
+                             "inputs need runtime shapes")
+        proto = next(m for m in masks if m is not None)
+        return jnp.concatenate(
+            [jnp.ones(x.shape[:2], proto.dtype) if m is None else m
+             for m, x in zip(masks, inputs)], axis=1)
+
+
 # ---------------------------------------------------------------------------
 # configuration + builder
 # ---------------------------------------------------------------------------
@@ -446,6 +509,9 @@ class ComputationGraphConfiguration:
     tbptt_back_length: int = 20
     defaults: Dict[str, Any] = field(default_factory=dict)
     seed: int = 12345
+    # vertex -> the name scope its operations are traced under, outside the
+    # scope of its class: a module made of several vertices reads as one
+    vertex_scopes: Dict[str, str] = field(default_factory=dict)
     # resolved:
     topological_order: List[str] = field(default_factory=list)
     vertex_input_types: Dict[str, List[Any]] = field(default_factory=dict)
@@ -564,6 +630,7 @@ class GraphBuilder:
         self._backprop_type = "standard"
         self._tbptt_fwd = 20
         self._tbptt_back = 20
+        self._scopes: Dict[str, str] = {}
 
     def add_inputs(self, *names: str) -> "GraphBuilder":
         self._inputs.extend(names)
@@ -574,21 +641,27 @@ class GraphBuilder:
         return self
 
     def add_layer(self, name: str, layer: LayerConf, *inputs: str,
-                  preprocessor: Optional[InputPreProcessor] = None) -> "GraphBuilder":
+                  preprocessor: Optional[InputPreProcessor] = None,
+                  scope: Optional[str] = None) -> "GraphBuilder":
         if layer.name is None:
             layer.name = name
         return self.add_vertex(name, LayerVertex(layer=layer,
                                                  preprocessor=preprocessor),
-                               *inputs)
+                               *inputs, scope=scope)
 
-    def add_vertex(self, name: str, vertex: GraphVertexConf, *inputs: str
-                   ) -> "GraphBuilder":
+    def add_vertex(self, name: str, vertex: GraphVertexConf, *inputs: str,
+                   scope: Optional[str] = None) -> "GraphBuilder":
+        """``scope`` names the ``jax.named_scope`` the vertex is traced
+        under (outside its class's own): vertices that share one read as
+        one module in a profile."""
         if name in self._vertices:
             raise ValueError(f"duplicate vertex name '{name}'")
         if not inputs:
             raise ValueError(f"vertex '{name}' needs at least one input")
         self._vertices[name] = vertex
         self._vertex_inputs[name] = list(inputs)
+        if scope:
+            self._scopes[name] = scope
         return self
 
     def set_outputs(self, *names: str) -> "GraphBuilder":
@@ -613,6 +686,7 @@ class GraphBuilder:
             tbptt_back_length=self._tbptt_back,
             defaults=dict(self._defaults),
             seed=self._seed,
+            vertex_scopes=dict(self._scopes),
         )
         conf.resolve()
         return conf

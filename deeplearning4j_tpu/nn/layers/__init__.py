@@ -1,7 +1,7 @@
 """Layer configs/implementations (reference ``nn/conf/layers`` + ``nn/layers``)."""
-from .attention import (LayerNormLayer, MultiHeadAttention,
-                        PositionalEncodingLayer, RMSNormLayer,
-                        TransformerBlock)
+from .attention import (LatentAttention, LayerNormLayer, MultiHeadAttention,
+                        NextTokenMerge, PositionalEncodingLayer,
+                        RMSNormLayer, TransformerBlock)
 from .base import BaseLayerConf, LayerConf
 from .convolution import (Convolution1DLayer, ConvolutionLayer,
                           Subsampling1DLayer, SubsamplingLayer, Upsampling1D,
@@ -24,9 +24,10 @@ __all__ = [
     "ConvolutionLayer", "DenseLayer", "DropoutLayer", "EmbeddingLayer",
     "EmbeddingSequenceLayer",
     "FrozenLayer", "GlobalPoolingLayer", "GravesBidirectionalLSTM",
-    "GravesLSTM", "LastTimeStep", "LayerConf", "LayerNormLayer",
+    "GravesLSTM", "LastTimeStep", "LatentAttention", "LayerConf",
+    "LayerNormLayer",
     "LocalResponseNormalization", "LossLayer", "LSTM",
-    "MixtureOfExpertsLayer", "MultiHeadAttention",
+    "MixtureOfExpertsLayer", "MultiHeadAttention", "NextTokenMerge",
     "OutputLayer", "PositionalEncodingLayer", "RBM", "RMSNormLayer", "RnnOutputLayer",
     "SimpleRnn", "TransformerBlock",
     "Subsampling1DLayer", "SubsamplingLayer", "Upsampling1D", "Upsampling2D",

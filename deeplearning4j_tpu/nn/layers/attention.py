@@ -24,6 +24,9 @@ chunked linearized attention (``_eva_attention``): exact causal attention
 inside a window, learned summaries of key chunks for everything before it.
 All three reach the flash kernels; any other key set (a key-padding mask)
 runs ``sdpa_reference`` under 'auto' and is refused by 'flash'.
+``TransformerBlock(attention='latent')`` is a layer of its own,
+``LatentAttention``: low-rank q, a joint latent for K and V, heads wider in
+q and k than in v, rotary positions on part of a head.
 """
 from __future__ import annotations
 
@@ -120,7 +123,8 @@ DEFAULT_FLASH_MIN_SEQ = int(os.environ.get("DL4J_TPU_FLASH_MIN_SEQ", 128))
 
 
 def auto_attention_impl(t_q: int, t_k: int, d: int, *, masked: bool,
-                        flash_min_seq: Optional[int] = None) -> str:
+                        flash_min_seq: Optional[int] = None,
+                        d_v: Optional[int] = None) -> str:
     """What ``attn_impl='auto'`` runs for these shapes: ``'flash'`` when
     the backend is a TPU, the input is unmasked, the sequence is at or
     above the crossover (``flash_min_seq``, default
@@ -140,7 +144,7 @@ def auto_attention_impl(t_q: int, t_k: int, d: int, *, masked: bool,
         return "reference"
     from ...ops.flash_attention import flash_blocks
     try:
-        flash_blocks(t_q, t_k, d)
+        flash_blocks(t_q, t_k, d, d_v=d_v)
     except ValueError:
         return "reference"
     return "flash"
@@ -155,7 +159,8 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
     explicit ``impl='flash'`` never falls back: a mask, shapes the kernel
     cannot tile, or a backend that cannot run it all raise.  ``window``
     (causal) keeps the last ``window`` keys of each query; the call then
-    runs under the scope ``attn_window``, else under ``attn_full``."""
+    runs under the scope ``attn_window``, else under ``attn_full``.  ``v``
+    may be narrower or wider a head than q and k."""
     from ...ops.attention import sdpa_reference
     if impl not in _ATTN_IMPLS:
         raise ValueError(f"unknown attn_impl '{impl}'; expected one of "
@@ -169,9 +174,9 @@ def _run_attention(q, k, v, *, impl: str, causal: bool, mask, seq_axis: str,
         fn = ring_self_attention if impl == "ring" else ulysses_attention
         return fn(q, k, v, axis_name=seq_axis, causal=causal)
     if impl == "auto":
-        impl = auto_attention_impl(q.shape[2], k.shape[2], q.shape[3],
-                                   masked=mask is not None,
-                                   flash_min_seq=flash_min_seq)
+        impl = auto_attention_impl(
+            q.shape[2], k.shape[2], q.shape[3], masked=mask is not None,
+            flash_min_seq=flash_min_seq, d_v=v.shape[3])
     if impl == "flash":
         if mask is not None:
             raise ValueError("attn_impl='flash' does not take key-padding "
@@ -198,6 +203,23 @@ def _rotary(x, theta: float):
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
     half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
     return (x * cos + half * sin).astype(x.dtype)
+
+
+def _rotary_pairs(x, theta: float):
+    """Rotary positions on ``[b, h, t, d]`` by adjacent pairs (feature
+    ``2i`` with ``2i + 1``, the published ``rope_interleave``): the pair as
+    a complex number times ``exp(i * pos * theta^(-2i/d))``.  Absolute
+    positions ``0..t-1``, angles in float32, the result in x's type.  ``x``
+    is the part of a head that carries positions, and ``d`` its width."""
+    t, d = x.shape[2], x.shape[3]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
+    # each feature's partner, signed: (-x1, x0, -x3, x2, ...)
+    partner = jnp.where(jnp.arange(d) % 2 == 0, -jnp.roll(x, -1, axis=-1),
+                        jnp.roll(x, 1, axis=-1))
+    return (x * cos + partner * sin).astype(x.dtype)
 
 
 def _eva_attention(q, k, v, phi, mu, *, window: int, chunk: int, impl: str,
@@ -708,6 +730,152 @@ class MultiHeadAttention(BaseLayerConf):
 
 @register_serde
 @dataclass
+class LatentAttention(BaseLayerConf):
+    """Multi-head latent attention (DeepSeek-V2/V3, arXiv:2412.19437
+    section 2.1) over RNN-typed input ``[b, t, n_in]``, in the expanded form
+    training runs; causal, no bias anywhere.
+
+    ``c_q = N(x Wqa)`` (``q_rank`` wide) and ``q = c_q Wqb`` as ``n_heads``
+    heads of ``[head_dim | rope_dim]``; ``[c_kv | k_r] = x Wkva``
+    (``kv_rank`` and ``rope_dim`` wide); ``N(c_kv) Wkvb`` as ``n_heads``
+    heads of ``[k_nope head_dim | v v_dim]``.  ``N`` is a gain-only RMSNorm
+    (``qa_norm``, ``kva_norm``; gains stored as offsets from one).  The
+    ``rope_dim`` part of q and the one ``k_r``, shared by every head, are
+    turned by rotary positions on adjacent pairs (``_rotary_pairs``, base
+    ``rope_theta``); the ``head_dim`` part carries no positions.  A head of
+    q and k is ``head_dim + rope_dim`` wide (the softmax scale follows
+    that), a head of v ``v_dim``: the flash kernels take both widths as
+    they are.  The output is ``concat(o_h) Wo``, ``n_heads * v_dim`` to
+    ``n_out``.
+
+    The five products, the two norms, the rotary part and the assembling of
+    q and k run under the scope ``mla_project``; the attention itself under
+    ``attn_full`` (``_run_attention``).  Trains and runs forward; there is
+    no KV-cache path: a cache of the latent (``kv_rank + rope_dim`` a token)
+    with the absorbed products is not written, so ``attend_cached`` raises,
+    as ``MultiHeadAttention``'s does for its newer choices."""
+    INPUT_KIND = "rnn"
+    _BIAS_PARAMS = ("qa_norm", "kva_norm")
+
+    n_in: int = 0
+    n_out: int = 0
+    n_heads: int = 4
+    head_dim: int = 0           # of q and k, the part without positions
+    rope_dim: int = 0           # of q and k, the rotary part
+    v_dim: int = 0              # of v; default head_dim
+    q_rank: int = 0
+    kv_rank: int = 0
+    attn_impl: str = "auto"     # reference|flash|auto
+    flash_min_seq: Optional[int] = None
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+
+    def set_n_in(self, itype: InputType, override: bool = False) -> None:
+        if self.n_in == 0 or override:
+            if itype.kind != "rnn":
+                raise ValueError(f"layer '{self.name}': LatentAttention "
+                                 f"expects RNN input, got {itype}")
+            self.n_in = itype.size
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+    def output_type(self, itype: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, itype.timesteps)
+
+    def _widths(self):
+        """``(heads, nope, rope, v)``."""
+        return (self.n_heads, self.head_dim, self.rope_dim,
+                self.v_dim or self.head_dim)
+
+    def init(self, key, itype):
+        h, dn, dr, dv = self._widths()
+        if min(dn, self.q_rank, self.kv_rank) <= 0 or dr <= 0 or dr % 2:
+            raise ValueError(
+                f"layer '{self.name}': latent attention needs head_dim, "
+                f"q_rank, kv_rank and an even rope_dim: {dn}, "
+                f"{self.q_rank}, {self.kv_rank}, {dr}")
+        if self.attn_impl not in ("auto", "reference", "flash"):
+            raise ValueError(f"layer '{self.name}': latent attention runs "
+                             "'auto', 'flash' or 'reference', not "
+                             f"attn_impl='{self.attn_impl}'")
+        ks = jax.random.split(key, 5)
+        params = {
+            "Wqa": self.make_weight(ks[0], (self.n_in, self.q_rank)),
+            "Wqb": self.make_weight(ks[1], (self.q_rank, h * (dn + dr))),
+            "Wkva": self.make_weight(ks[2], (self.n_in, self.kv_rank + dr)),
+            "Wkvb": self.make_weight(ks[3], (self.kv_rank, h * (dn + dv))),
+            "Wo": self.make_weight(ks[4], (h * dv, self.n_out)),
+            # gains as offsets from one (_rms_norm)
+            "qa_norm": jnp.zeros((self.q_rank,), self._dtype()),
+            "kva_norm": jnp.zeros((self.kv_rank,), self._dtype()),
+        }
+        return {"params": params, "state": {}}
+
+    def project(self, p, x):
+        """``(q, k, v)`` as the attention takes them: ``[b, h, t, head_dim
+        + rope_dim]`` twice and ``[b, h, t, v_dim]``."""
+        h, dn, dr, dv = self._widths()
+        b, t, _ = x.shape
+
+        def heads(y, d):
+            return y.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+        q = heads(_rms_norm(x @ p["Wqa"], p["qa_norm"], self.eps)
+                  @ p["Wqb"], dn + dr)
+        kva = x @ p["Wkva"]
+        kv = heads(_rms_norm(kva[..., :self.kv_rank], p["kva_norm"],
+                             self.eps) @ p["Wkvb"], dn + dv)
+        # one rotary key a position, for every head
+        k_r = _rotary_pairs(kva[:, None, :, self.kv_rank:], self.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :dn], _rotary_pairs(q[..., dn:], self.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
+        return q, k, kv[..., dn:]
+
+    def attend(self, p, x, *, train=False, key=None, mask=None):
+        from ...observability.registry import default_registry
+        reg = default_registry()
+        if reg.enabled:
+            # trace-time, like moe_layers_traced_total
+            h, dn, dr, dv = self._widths()
+            reg.counter("mla_layers_traced_total",
+                        "Latent-attention layers traced into a program, by "
+                        "heads and the width of a head of q/k and of v",
+                        ("heads", "qk", "v")).labels(
+                            str(h), str(dn + dr), str(dv)).inc()
+        with jax.named_scope("mla_project"):
+            q, k, v = self.project(p, x)
+            # as the attention takes them: the layout a backward reads
+            q = checkpoint_name(q, "attn_q")
+            k = checkpoint_name(k, "attn_k")
+            v = checkpoint_name(v, "attn_v")
+        o = _run_attention(q, k, v, impl=self.attn_impl, causal=True,
+                           mask=mask, seq_axis="seq",
+                           flash_min_seq=self.flash_min_seq)
+        with jax.named_scope("mla_project"):
+            b_, h, t, dv = o.shape
+            return o.transpose(0, 2, 1, 3).reshape(b_, t, h * dv) @ p["Wo"]
+
+    def apply(self, variables, x, *, train=False, key=None, mask=None):
+        p = self.maybe_noise_weights(key, variables["params"], train)
+        x = self.maybe_dropout_input(key, x, train)
+        y = self.attend(p, x, train=train, key=key, mask=mask)
+        return self.act_fn(y), variables.get("state", {})
+
+    def init_carry(self, batch: int, dtype=jnp.float32,
+                   max_len: Optional[int] = None):
+        return {"pos": jnp.zeros((), jnp.int32)}
+
+    def attend_cached(self, p, x, carry, *, mask=None):
+        raise NotImplementedError(
+            "latent attention has no KV-cache path (no latent cache, no "
+            "absorbed decode form yet): such a layer trains and runs "
+            "forward only")
+
+
+@register_serde
+@dataclass
 class TransformerBlock(BaseLayerConf):
     """Pre-norm transformer block: norm→MHA→residual, norm→MLP→residual.
 
@@ -725,7 +893,12 @@ class TransformerBlock(BaseLayerConf):
     (no bias in any projection), ``attention='eva'`` with ``window`` and
     ``chunk`` or ``attention='sliding'`` with ``window``, ``n_kv_heads``,
     ``qk_norm`` and ``attn_gate`` (``MultiHeadAttention``'s grouped K/V
-    heads, per-head q/k norm and output gate), ``post_norm=True`` (a norm
+    heads, per-head q/k norm and output gate), ``attention='latent'`` (the
+    attention half is then a ``LatentAttention``, a layer of its own:
+    ``latent_q_rank``, ``latent_kv_rank``, ``head_dim`` the part of a q/k
+    head without positions, ``rope_dim`` the rotary part, ``v_head_dim``;
+    ``positions``, ``n_kv_heads``, ``qk_norm`` and ``attn_gate`` do not
+    apply to it), ``post_norm=True`` (a norm
     after each half as well as before it: ``x + N2(Attn(N1 x))``, ``x +
     N4(FFN(N3 x))``; gains ``ln1p_g``, ``ln2p_g``), and with
     ``moe_top_k > 0`` the routed FFN without dropped tokens
@@ -738,13 +911,19 @@ class TransformerBlock(BaseLayerConf):
     hands the block its input uncast (``PrecisionPolicy.input_dtype``) and
     each norm's output goes to the projections in their own type.  The
     defaults trace the program they always did.
+
+    The KV-cache path (``apply_with_carry`` -> ``attend_cached``) serves the
+    GPT-2 block alone: it raises ``NotImplementedError`` for rotary
+    positions, sliding and EVA attention, grouped K/V heads, q/k norm, the
+    output gate, and for latent attention (no latent cache and no absorbed
+    decode form is written).  Such a block trains and runs forward only.
     """
     INPUT_KIND = "rnn"
     HAS_CARRY = True
     _BIAS_PARAMS = ("mha_bq", "mha_bk", "mha_bv", "mha_bo", "b1", "b2",
                     "ln1_g", "ln1_b", "ln2_g", "ln2_b", "mha_phi", "mha_mu",
                     "mha_q_norm", "mha_k_norm", "ln1p_g", "ln1p_b",
-                    "ln2p_g", "ln2p_b")
+                    "ln2p_g", "ln2p_b", "mha_qa_norm", "mha_kva_norm")
 
     n_in: int = 0
     n_heads: int = 4
@@ -767,7 +946,7 @@ class TransformerBlock(BaseLayerConf):
     ffn_hidden: int = 0         # default ffn_mult * n_in
     gated: bool = False         # silu(Wg x) * (W1 x) in place of gelu(W1 x)
     has_bias: bool = True
-    attention: str = "full"     # full|sliding|eva
+    attention: str = "full"     # full|sliding|eva|latent
     window: int = 0
     chunk: int = 0
     residual_dtype: Optional[str] = None   # None: the compute type
@@ -784,6 +963,12 @@ class TransformerBlock(BaseLayerConf):
     moe_shared: int = 0
     moe_hidden: int = 0             # default ffn_hidden
     moe_held: Optional[Tuple[int, int]] = None   # (first, count); None: all
+    # attention='latent' (LatentAttention); head_dim is then the part of a
+    # q/k head that carries no positions
+    latent_q_rank: int = 0
+    latent_kv_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0             # default head_dim
 
     @property
     def AUX_LOSS(self):
@@ -842,7 +1027,22 @@ class TransformerBlock(BaseLayerConf):
     def output_type(self, itype: InputType) -> InputType:
         return InputType.recurrent(self.n_in, itype.timesteps)
 
-    def _mha(self) -> MultiHeadAttention:
+    def _mha(self):
+        """The attention half's layer: ``MultiHeadAttention``, or for
+        ``attention='latent'`` a ``LatentAttention``."""
+        if self.attention == "latent":
+            if not self.causal:
+                raise ValueError(f"layer '{self.name}': latent attention "
+                                 "is causal; causal=False is not written")
+            return LatentAttention(
+                n_in=self.n_in, n_out=self.n_in, n_heads=self.n_heads,
+                head_dim=self.head_dim, rope_dim=self.rope_dim,
+                v_dim=self.v_head_dim, q_rank=self.latent_q_rank,
+                kv_rank=self.latent_kv_rank,
+                attn_impl=self.attn_impl, flash_min_seq=self.flash_min_seq,
+                rope_theta=self.rope_theta, eps=self.eps,
+                activation="identity", weight_init=self.weight_init,
+                weight_dist=self.weight_dist, dtype=self.dtype)
         m = MultiHeadAttention(
             n_in=self.n_in, n_out=self.n_in, n_heads=self.n_heads,
             causal=self.causal, attn_impl=self.attn_impl,
@@ -917,7 +1117,7 @@ class TransformerBlock(BaseLayerConf):
         else:
             y = _layer_norm(x, p[which + "_g"], p[which + "_b"], self.eps)
         # a stream wider than the weights: the projections compute in theirs
-        return y.astype(p["mha_Wq"].dtype) if self.residual_dtype else y
+        return y.astype(p["mha_Wo"].dtype) if self.residual_dtype else y
 
     def _ffn(self, p, xn, state=None):
         """Dense or routed MLP; returns (out, state_update)."""
@@ -1008,6 +1208,60 @@ class TransformerBlock(BaseLayerConf):
             # variables dict (the MLN carry path reads state after the call)
             variables["state"] = st
         return x + ff, new_carry
+
+
+@register_serde
+@dataclass
+class NextTokenMerge(BaseLayerConf):
+    """The merge that opens a multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2): input ``[b, t, 2 * n_out]``, the
+    embedding of the token after next-to-predict laid beside the trunk's
+    hidden state of the same position (a ``MergeVertex`` of the two, the
+    embedding first); each half gets a gain-only RMSNorm of its own
+    (``enorm``, ``hnorm``; gains as offsets from one) and the pair one
+    projection without bias, ``[N_e e ; N_h h] W``, to ``n_out``."""
+    INPUT_KIND = "rnn"
+    _BIAS_PARAMS = ("enorm", "hnorm")
+
+    n_in: int = 0
+    n_out: int = 0
+    eps: float = 1e-6
+
+    def set_n_in(self, itype: InputType, override: bool = False) -> None:
+        if self.n_in == 0 or override:
+            self.n_in = itype.size
+        if self.n_out == 0:
+            self.n_out = self.n_in // 2
+
+    def output_type(self, itype: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, itype.timesteps)
+
+    def init(self, key, itype):
+        if self.n_in != 2 * self.n_out:
+            raise ValueError(
+                f"layer '{self.name}': the merge takes an embedding and a "
+                f"hidden state of its output's width side by side, got "
+                f"{self.n_in} for {self.n_out}")
+        return {"params": {
+            "W": self.make_weight(key, (self.n_in, self.n_out)),
+            "enorm": jnp.zeros((self.n_out,), self._dtype()),
+            "hnorm": jnp.zeros((self.n_out,), self._dtype())},
+            "state": {}}
+
+    def apply(self, variables, x, *, train=False, key=None, mask=None):
+        from ...observability.registry import default_registry
+        reg = default_registry()
+        if reg.enabled:
+            # trace-time, like moe_layers_traced_total
+            reg.counter("mtp_modules_traced_total",
+                        "Multi-token-prediction modules traced into a "
+                        "program, by width", ("width",)).labels(
+                            str(self.n_out)).inc()
+        p, e = variables["params"], self.n_out
+        both = jnp.concatenate(
+            [_rms_norm(x[..., :e], p["enorm"], self.eps),
+             _rms_norm(x[..., e:], p["hnorm"], self.eps)], axis=-1)
+        return both @ p["W"], variables.get("state", {})
 
 
 @register_serde

@@ -60,7 +60,9 @@ def sdpa_reference(q, k, v, *, mask=None, causal: bool = False,
                    scale: Optional[float] = None,
                    q_offset: int = 0, k_offset: int = 0,
                    window: Optional[int] = None):
-    """Reference scaled-dot-product attention.  q,k,v: [b, h, t, d].
+    """Reference scaled-dot-product attention.  q,k,v: [b, h, t, d]; ``v``
+    may be of another last dimension than q and k (the output is as wide as
+    ``v``, the default scale follows q's width).
     ``window`` keeps of each query's keys those less than ``window``
     positions before it (with ``causal``: the last ``window``)."""
     if scale is None:

@@ -297,7 +297,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     # last is the walk along the band (_run_band)
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
-    q_rows, d = q_ref.shape
+    q_rows, d_v = q_ref.shape[0], v_ref.shape[1]     # v's width, and o's
 
     @pl.when(ki == 0)
     def _init():
@@ -307,8 +307,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     def step(q0, nq, k0, nkeys, cut, head=0, shift=0):
         rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
-        q = _scaled(q_ref[rows, :], scale)           # [nq, d]
-        v = v_ref[keys, :]                           # [nkeys, d]
+        q = _scaled(q_ref[rows, :], scale)           # [nq, d_qk]
+        v = v_ref[keys, :]                           # [nkeys, d_v]
         s = _masked_scores(q, k_ref[keys, :], q0, k0, cut, head=head,
                            shift=shift, window=window)
         # m, l: lane-replicated [rows, 128], so the row statistics meet
@@ -319,7 +319,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[rows, :] = alpha * l_ref[rows, :] + jnp.sum(
             p, axis=-1, keepdims=True)
-        acc_ref[rows, :] = _lanes(alpha, d) * acc_ref[rows, :] + (
+        acc_ref[rows, :] = _lanes(alpha, d_v) * acc_ref[rows, :] + (
             jax.lax.dot_general(p.astype(v.dtype), v,
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32))
@@ -335,7 +335,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     @pl.when(ki == nk - 1)
     def _finalize():
         l = l_ref[:]
-        o_ref[...] = (acc_ref[:] / _lanes(l, d)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[:] / _lanes(l, d_v)).astype(o_ref.dtype)
         # logsumexp per row — the backward's softmax replay key, stored
         # lane-major: [1, rows] of a (heads, 1, t_q) array
         lse_ref[...] = (m_ref[:] + jnp.log(l))[:, 0][None, :]
@@ -354,7 +354,8 @@ def _specs(d: int, held: int, walked: int, clamp=None):
     walked block wholly above the diagonal repeats the live one next to
     it (``clamp``: ``jnp.minimum`` where keys are walked, ``jnp.maximum``
     where queries are), so it is not copied — and each one's slice of the
-    lane-major row statistics."""
+    lane-major row statistics.  ``d`` is the operands' width: q's and k's
+    in one call, v's (o's, dO's) in another where the two differ."""
     def live(h, w):
         return clamp(w, h) if clamp else w
     return (pl.BlockSpec((None, held, d), lambda b, h, w: (b, h, 0)),
@@ -363,6 +364,12 @@ def _specs(d: int, held: int, walked: int, clamp=None):
             pl.BlockSpec((None, 1, held), lambda b, h, w: (b, 0, h)),
             pl.BlockSpec((None, 1, walked),
                          lambda b, h, w: (b, 0, live(h, w))))
+
+
+def _row_bytes(qr, vr) -> int:
+    """Bytes of the widest operand row a block holds: q's (and k's) or
+    v's (and o's)."""
+    return max(qr.shape[2], vr.shape[2]) * qr.dtype.itemsize
 
 
 def _band(window, t_k, rows):
@@ -381,22 +388,23 @@ def _band(window, t_k, rows):
 def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
                 window=None):
     bh, t_q, d = qr.shape
-    t_k = kr.shape[1]
+    t_k, d_v = kr.shape[1], vr.shape[2]
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
-                                 d * qr.dtype.itemsize)
+                                 _row_bytes(qr, vr))
     band, keys_at, _ = _band(window, t_k, q_rows)
-    q_spec, k_spec, row_spec, _ = _specs(
-        d, q_rows, k_rows, keys_at or (jnp.minimum if causal else None))
+    clamp = keys_at or (jnp.minimum if causal else None)
+    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
+    o_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, **band),
         grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=[q_spec, row_spec],
-        out_shape=[_like(qr, (bh, t_q, d)),
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, row_spec],
+        out_shape=[_like(qr, (bh, t_q, d_v)),
                    _like(qr, (bh, 1, t_q), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((q_rows, d), jnp.float32),
+            pltpu.VMEM((q_rows, d_v), jnp.float32),
             pltpu.VMEM((q_rows, _LANES), jnp.float32),
             pltpu.VMEM((q_rows, _LANES), jnp.float32),
         ],
@@ -521,17 +529,18 @@ def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
 def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
                        block_k, interpret, window=None):
     bh, t_q, d = qr.shape
-    t_k = kr.shape[1]
+    t_k, d_v = kr.shape[1], vr.shape[2]
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
-                                 d * qr.dtype.itemsize)
+                                 _row_bytes(qr, vr))
     band, keys_at, queries_at = _band(window, t_k, q_rows)
-    q_spec, k_spec, row_spec, _ = _specs(
-        d, q_rows, k_rows, keys_at or (jnp.minimum if causal else None))
+    clamp = keys_at or (jnp.minimum if causal else None)
+    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
+    do_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, **band),
         grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_like(qr),
         scratch_shapes=[pltpu.VMEM((q_rows, d), jnp.float32)],
@@ -545,17 +554,18 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     # the caller's.
     block_q, block_k = (_DKV_TILE if b % _DKV_TILE == 0 else b
                         for b in (block_q, block_k))
-    k_spec2, q_spec2, _, row_spec2 = _specs(
-        d, k_rows, q_rows, queries_at or (jnp.maximum if causal else None))
+    clamp2 = queries_at or (jnp.maximum if causal else None)
+    k_spec2, q_spec2, _, row_spec2 = _specs(d, k_rows, q_rows, clamp2)
+    v_spec2, do_spec2, _, _ = _specs(d_v, k_rows, q_rows, clamp2)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, **band),
         grid=(bh, t_k // k_rows, band.get("n_walk", t_q // q_rows)),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[k_spec2, k_spec2],
+        in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, row_spec2, row_spec2],
+        out_specs=[k_spec2, v_spec2],
         out_shape=[_like(kr), _like(vr)],
         scratch_shapes=[pltpu.VMEM((k_rows, d), jnp.float32),
-                        pltpu.VMEM((k_rows, d), jnp.float32)],
+                        pltpu.VMEM((k_rows, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
     )(qr, kr, vr, do, lse, dd)
@@ -592,19 +602,21 @@ KERNEL_NAMES = FULL_KERNEL_NAMES + WINDOW_KERNEL_NAMES
 
 def flash_blocks(t_q: int, t_k: int, d: int,
                  block_q: Optional[int] = None,
-                 block_k: Optional[int] = None):
+                 block_k: Optional[int] = None, d_v: Optional[int] = None):
     """``(block_q, block_k)``, the score tile the kernels run these shapes
-    with.  Raises ``ValueError`` naming the reason when it cannot tile
+    with: ``d`` the width of a head of q and k, ``d_v`` of v (default
+    ``d``).  Raises ``ValueError`` naming the reason when it cannot tile
     them — the one support check, shared by ``flash_attention`` (which
     raises) and ``attn_impl='auto'`` (which then chooses the reference
     path)."""
     auto_q, auto_k = _auto_blocks(t_q, t_k, d)
     block_q = min(block_q, t_q) if block_q else auto_q
     block_k = min(block_k, t_k) if block_k else auto_k
-    if d % 64:
+    d_v = d_v or d
+    if d % 64 or d_v % 64:
         # head_dim must fill whole MXU lanes for the kernel's tiling
         raise ValueError(f"flash attention needs head_dim % 64 == 0, "
-                         f"got {d}")
+                         f"got {d}" + (f" and {d_v}" if d_v != d else ""))
     if t_q % block_q or t_k % block_k:
         raise ValueError(
             f"flash attention needs sequence lengths divisible by its "
@@ -645,6 +657,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """Flash attention over [b, h, t, d] tensors — differentiable: the
     FlashAttention-2 style backward (saved logsumexp, softmax replayed per
     block, separate dq and dk/dv kernels) keeps training memory O(t).
+    ``v`` may have a last dimension of its own (latent attention: q and k
+    192 wide, v 128): the output is as wide as ``v``, the scale follows
+    q's width, and nothing is padded.
 
     ``return_lse`` gives ``(out, lse)``, ``lse`` the float32 ``[b, h, t]``
     log-sum-exp of each row's scaled scores, differentiable like ``out``
@@ -665,8 +680,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     between this and ``sdpa_reference``.
     """
     _, h, t_q, d = q.shape
-    t_k = k.shape[2]
-    block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k)
+    t_k, d_v = k.shape[2], v.shape[3]
+    block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k, d_v)
     if scale is None:
         scale = d ** -0.5
     if window is not None:
@@ -680,11 +695,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     def run(q, k, v):
         rows = q.shape[0] * h
         out = _flash(q.reshape(rows, t_q, d), k.reshape(rows, t_k, d),
-                     v.reshape(rows, t_k, d), scale, causal, block_q,
+                     v.reshape(rows, t_k, d_v), scale, causal, block_q,
                      block_k, interpret, return_lse, window)
+        o_shape = q.shape[:3] + (d_v,)
         if return_lse:
-            return out[0].reshape(q.shape), out[1].reshape(q.shape[:3])
-        return out.reshape(q.shape)
+            return out[0].reshape(o_shape), out[1].reshape(q.shape[:3])
+        return out.reshape(o_shape)
 
     mesh, spec = _kernel_partitioning(q)
     if mesh is not None:

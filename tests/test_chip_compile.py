@@ -422,3 +422,67 @@ def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(8.466e9, rel=1e-3)
     assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 20 * SPARE
+
+
+def test_flash_kernels_compile_at_192_wide_keys_and_128_wide_values(one_chip):
+    """Latent attention's call at the benchmark's size: q and k [32, 8192,
+    192], v [32, 8192, 128], bfloat16, causal; the forward and both
+    backward kernels, unpadded: the output and dV are 128 wide."""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(F.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v)
+    assert [o.shape[-1] for o in jax.tree_util.tree_leaves(
+        lowered.out_info)] == [192, 192, 128]
+    text = lowered.as_text()
+    assert all(name in text for name in F.FULL_KERNEL_NAMES)
+    assert _custom_calls(lowered.compile()) == 3
+
+
+def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
+    """The benchmark's ``joyai-llm-flash-5l`` train step (1 dense + 4
+    routed layers and the multi-token-prediction module at published
+    widths, 16 of 256 experts, an eighth of the vocabulary, bfloat16,
+    ``cache_mode`` none) lowered from shapes through the GRAPH container for
+    one described v5e at one row of 8193 ids: each full kernel once a
+    latent-attention layer, six in all, no windowed one, and arguments and
+    program fit the compiler's limit with room (a peak of 14.27 GiB of
+    15.75, PR 35)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import common
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = common.load_json("configs", "joyai-llm-flash-5l.json")
+    traffic = common.load_module("traffic", "mtp_lm_fit_stream")
+    held = {}
+
+    def built():
+        # 680 M parameters and Adam's moments: shapes alone
+        net = held["net"] = traffic.build(cfg)
+        return net.params, net.state, net.opt_state, net._rng
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(built))
+
+    def batch(shape, dtype):
+        return [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)]
+    lowered = held["net"]._get_jitted("train_step").audit_lower(
+        (args + (batch((1, 8193), jnp.int32), batch((1, 16384), jnp.int32),
+                 None, batch((1, 16384), jnp.float32)), {}))
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert {n: sum(f"/{n}/pallas_call" in c for c in calls)
+            for n in F.KERNEL_NAMES} == {
+        **dict.fromkeys(F.FULL_KERNEL_NAMES, 6),
+        **dict.fromkeys(F.WINDOW_KERNEL_NAMES, 0)}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(8.165e9, rel=1e-3)
+    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 10 * SPARE
