@@ -52,9 +52,14 @@ class DenseLayer(BaseLayerConf):
             params["b"] = self.make_bias((self.n_out,))
         return {"params": params, "state": {}}
 
-    def pre_output(self, variables, x, *, train=False, key=None):
+    def _operands(self, variables, x, train, key):
+        """``(x, params)`` as the product takes them: dropout on the
+        input, noise on the weights."""
         params = self.maybe_noise_weights(key, variables["params"], train)
-        x = self.maybe_dropout_input(key, x, train)
+        return self.maybe_dropout_input(key, x, train), params
+
+    def pre_output(self, variables, x, *, train=False, key=None):
+        x, params = self._operands(variables, x, train, key)
         z = x @ params["W"]
         if self.has_bias:
             z = z + params["b"]
@@ -98,11 +103,51 @@ class OutputLayer(DenseLayer):
         return (self.act_fn(self._by_head(z)).reshape(z.shape),
                 variables.get("state", {}))
 
+    def _chunk_rows(self, x, labels, mask, act):
+        """Time steps a chunk where this head's loss runs chunk by chunk
+        (``losses.head_rows_per_chunk``), by what the call shows: a
+        softmax over integer labels ``[b, t]``, one head, no column
+        weights, logits that float16's loss scale need not protect, and
+        float32 logits too large to form whole.  ``None``: the plain
+        product and loss."""
+        if not (isinstance(self.loss, str)
+                and self.loss.lower() == "sparse_mcxent"
+                and isinstance(act, str) and act.lower() == "softmax"
+                and self.pred_heads == 1 and self.loss_weights is None
+                and x.ndim == 3 and labels.shape == x.shape[:2]
+                and (mask is None or mask.shape == labels.shape)
+                and x.dtype != jnp.float16):
+            return None
+        return _losses.head_rows_per_chunk(*labels.shape, self.n_out)
+
     def compute_loss(self, variables, x, labels, *, train=False, key=None,
                      mask=None, average=True):
+        """The head's score.  As a rule the whole ``[.., n_out]``
+        pre-activation is formed and handed to the loss.  A softmax head
+        over integer labels whose float32 logits would pass
+        ``losses.HEAD_CHUNK_BYTES`` forms no whole logits, neither here
+        nor in the backward pass: ``losses.chunked_softmax_xent`` walks
+        chunks of time steps (counter ``head_chunks_traced_total``)."""
+        act = self.resolved("activation", "identity")
+        rows = self._chunk_rows(x, labels, mask, act)
+        if rows is not None:
+            from ...observability.registry import default_registry
+            reg = default_registry()
+            if reg.enabled:
+                # trace-time, like mla_layers_traced_total
+                reg.counter("head_chunks_traced_total",
+                            "Output layers traced with their logits formed "
+                            "chunk by chunk, by the logits' rows and "
+                            "classes and the number of chunks",
+                            ("rows", "classes", "chunks")).labels(
+                                str(labels.size), str(self.n_out),
+                                str(labels.shape[1] // rows)).inc()
+            x, params = self._operands(variables, x, train, key)
+            return _losses.chunked_softmax_xent(
+                x, params["W"], params["b"] if self.has_bias else None, labels,
+                _losses.position_weights(mask, labels.shape), rows)
         z = self._by_head(self.pre_output(variables, x, train=train,
                                           key=key))
-        act = self.resolved("activation", "identity")
         if self.loss_weights is not None:
             w = jnp.asarray(self.loss_weights, z.dtype)
             if w.shape[-1] != self.n_out:

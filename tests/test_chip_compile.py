@@ -10,6 +10,8 @@ library, and every xdist worker imports every test file).  All of these
 tests stay in this ONE file, so the worker that describes the topology is
 the worker that runs them.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from deeplearning4j_tpu.observability.registry import default_registry
 from deeplearning4j_tpu.ops import flash_attention as F
 from deeplearning4j_tpu.ops import pallas_bn, pallas_lstm
 
@@ -70,6 +73,21 @@ def _kernel_calls(compiled) -> dict:
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name: sum(f"/{name}/pallas_call" in c for c in calls)
             for name in F.FULL_KERNEL_NAMES}
+
+
+def _head_repeats(compiled) -> list:
+    """The instructions of an output layer that XLA rematerialized: those
+    it named ``.remat`` whose scope holds the layer's class."""
+    return [line.split(" = ")[0].strip()
+            for line in compiled.as_text().split("\n")
+            if re.match(r"\s*(ROOT )?%?[\w.\-]*\.remat[\w.]* = ", line)
+            and "OutputLayer/" in line]
+
+
+def _head_chunks():
+    c = default_registry().get("head_chunks_traced_total")
+    return {} if c is None else {labels: child.value
+                                 for labels, child in c.samples()}
 
 
 def _while_stacks(compiled, shape: str) -> int:
@@ -382,8 +400,11 @@ def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
     (no ``[T, E, C]`` one-hot), each routed layer chooses once each way
     between buffers of its pair capacity and the whole-size branch, and
     arguments and program fit the compiler's limit with room: a peak of
-    13.66 GiB of 15.75 since the buffers hold 16 384 pairs a layer (PR 34;
-    14.36 when they held all 65 536)."""
+    13.53 GiB of 15.75 since the head walks its logits in four chunks (PRs
+    36, 37; 13.66 with the logits whole, PR 34; 14.36 when the routed
+    buffers held all 65 536 pairs), no higher than with the logits whole,
+    and of the head nothing is rematerialized (the whole-array head's
+    forward and backward products both were)."""
     import os
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -402,9 +423,12 @@ def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(built))
     ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    before = _head_chunks().get(("8192", "25024", "4"), 0)
     lowered = held["net"]._get_jitted("train_step").audit_lower(
         (args + (ids, ids, None, None), {}))
+    assert _head_chunks()[("8192", "25024", "4")] == before + 1
     compiled = lowered.compile()
+    assert _head_repeats(compiled) == []
     calls = [line for line in compiled.as_text().split("\n")
              if 'custom_call_target="tpu_custom_call"' in line]
 
@@ -421,7 +445,8 @@ def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
     assert compiled.as_text().count(" conditional(") == 2 * 4
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(8.466e9, rel=1e-3)
-    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 20 * SPARE
+    # no higher than with the logits whole (13.661 GiB; compile-only, PR 34)
+    assert memory.peak_memory_in_bytes <= 13.661 * 2 ** 30
 
 
 def test_flash_kernels_compile_at_192_wide_keys_and_128_wide_values(one_chip):
@@ -450,9 +475,10 @@ def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
     widths, 16 of 256 experts, an eighth of the vocabulary, bfloat16,
     ``cache_mode`` none) lowered from shapes through the GRAPH container for
     one described v5e at one row of 8193 ids: each full kernel once a
-    latent-attention layer, six in all, no windowed one, and arguments and
-    program fit the compiler's limit with room (a peak of 14.27 GiB of
-    15.75, PR 35)."""
+    latent-attention layer, six in all, no windowed one, the one head over
+    both streams walked in four chunks with nothing of it rematerialized,
+    and arguments and program fit the compiler's limit with room (a peak of
+    13.70 GiB of 15.75, PR 36; 14.27 with the logits whole, PR 35)."""
     import os
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -473,10 +499,13 @@ def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
 
     def batch(shape, dtype):
         return [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)]
+    before = _head_chunks().get(("16384", "16160", "4"), 0)
     lowered = held["net"]._get_jitted("train_step").audit_lower(
         (args + (batch((1, 8193), jnp.int32), batch((1, 16384), jnp.int32),
                  None, batch((1, 16384), jnp.float32)), {}))
+    assert _head_chunks()[("16384", "16160", "4")] == before + 1
     compiled = lowered.compile()
+    assert _head_repeats(compiled) == []
     calls = [line for line in compiled.as_text().split("\n")
              if 'custom_call_target="tpu_custom_call"' in line]
     assert {n: sum(f"/{n}/pallas_call" in c for c in calls)
