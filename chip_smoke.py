@@ -478,7 +478,9 @@ def main(argv=None) -> int:
             phase_conv(devices)
         phase = "epilogue"
         from deeplearning4j_tpu import persistent_cache_status
+        from deeplearning4j_tpu.observability import startup_report
         say(f"compile cache at end: {persistent_cache_status()}")
+        say(f"start-up by parts: {startup_report()}")
         # nothing the phases started may outlive them: a thread that
         # prints after the last line would break it
         left = [t for t in threading.enumerate() if t not in threads_before]

@@ -7,6 +7,13 @@ ComputationGraph) while the execution model is idiomatic TPU: one jitted XLA
 program per train step, pytree params, mesh-sharded scale-out.
 """
 
+import time as _time
+
+# the first line that runs: the import's own seconds are measured from here
+# (``time.perf_counter`` is what ``observability.clock.monotonic_s`` reads;
+# importing that module first would run most of the import before the read)
+_IMPORT_BEGAN = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from . import observability
@@ -39,3 +46,7 @@ __all__ = [
     "persistent_cache_status",
     "wire_persistent_cache",
 ]
+
+# the last line: gauges ``package_import_seconds`` and
+# ``process_age_at_import_seconds`` (``observability.startup_report()``)
+observability.startup.note_import(_IMPORT_BEGAN)

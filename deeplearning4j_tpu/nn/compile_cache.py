@@ -20,7 +20,12 @@ Three mechanisms make that the framework default:
    records trace+compile wall time in ``training_compile_seconds{fn}``,
    so recompile storms show up in /metrics instead of as mystery
    latency.  Every call is the span ``dl4j.call.<name>``: a dispatch,
-   and the trace and compile when the call has to.
+   and the trace and compile when the call has to.  Which of those a
+   call paid for, and for how long, JAX says itself: the listeners that
+   ``wire_persistent_cache`` registers keep its seconds of tracing,
+   lowering, loading from the persistent cache and compiling in the six
+   ``jit_*`` counters (``observability/startup.JIT_COUNTERS``), by the
+   ``InstrumentedJit`` whose call is open on the thread.
 
 3. **Persistent compile cache** (`wire_persistent_cache`): JAX's on-disk
    compilation cache, wired at package init, so a restarted process
@@ -44,7 +49,8 @@ import jax
 from . import scan_layers as _scan_layers
 from ..observability.clock import monotonic_s
 from ..observability.registry import default_registry
-from ..observability.tracer import get_tracer
+from ..observability.startup import JIT_COUNTERS, log_startup_once
+from ..observability.tracer import get_tracer, open_entry
 
 log = logging.getLogger(__name__)
 
@@ -319,22 +325,31 @@ class InstrumentedJit:
         reg = default_registry()
         if reg.enabled:
             reg.counter("training_compile_total",
-                        "XLA traces (each implies a compile unless the "
-                        "persistent cache hits)", ("fn",)
+                        "XLA traces (whether one was then compiled or "
+                        "loaded from the persistent cache: "
+                        "jit_programs_compiled_total, "
+                        "jit_programs_loaded_total)", ("fn",)
                         ).labels(self.name).inc()
 
     def __call__(self, *args, **kwargs):
         self._tls.traced = False
         t0 = monotonic_s()
         kept = _scan_layers.kept_runs()
-        with get_tracer().span(self._span_name):
-            try:
-                out = self.fn(*args, **kwargs)
-            except Exception as e:
-                if _scan_layers.kept_runs() == kept or \
-                        not _scan_layers.refused_for_memory(e):
-                    raise
-                out = self._inputs_alone(e, args, kwargs)
+        # what JAX traces, lowers, loads or compiles on this thread until
+        # the call returns is this program's (the ``fn`` of the jit_*
+        # counters)
+        outer, open_entry.fn = open_entry.fn, self.name
+        try:
+            with get_tracer().span(self._span_name):
+                try:
+                    out = self.fn(*args, **kwargs)
+                except Exception as e:
+                    if _scan_layers.kept_runs() == kept or \
+                            not _scan_layers.refused_for_memory(e):
+                        raise
+                    out = self._inputs_alone(e, args, kwargs)
+        finally:
+            open_entry.fn = outer
         if _AUDIT_MODE == "all" or (_AUDIT_MODE == "trace"
                                     and self._tls.traced):
             self._record_spec(args, kwargs)
@@ -345,8 +360,12 @@ class InstrumentedJit:
                 reg.histogram(
                     "training_compile_seconds",
                     "Wall time of calls that (re)traced, i.e. trace + "
-                    "compile + first dispatch", ("fn",),
+                    "lowering + cache load or compile + first dispatch "
+                    "(by parts: the jit_* counters)", ("fn",),
                     buckets=_COMPILE_BUCKETS).labels(self.name).observe(dt)
+            # (a step traced inside an epoch's program is not yet counted)
+            if outer is None and self.name.startswith(_TRAINING_PROGRAMS):
+                log_startup_once()
         return out
 
     @property
@@ -500,12 +519,44 @@ DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "_compile_cache")
 
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
-                 "/jax/compilation_cache/cache_misses": "misses"}
 _PERSISTENT_STATUS: Dict[str, Any] = {"enabled": False}
-_PERSISTENT_COUNTS = {"hits": 0, "misses": 0}
 _PERSISTENT_LISTENING = False
 _PERSISTENT_LOCK = threading.Lock()
+
+# ------------------------------------------------- JAX's own durations
+# Where the host's seconds went whenever JAX made a program: the six
+# registry counters of ``observability/startup.JIT_COUNTERS`` by ``fn``, the
+# name of the ``InstrumentedJit`` whose call is open on the thread, ``init``
+# inside a container's ``init()``, else ``eager`` (the caller's own
+# ``jax.jit``s and loose operations).  Written only when a program is traced,
+# lowered, loaded or compiled: a steady step touches none.
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JIT_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _BACKEND_COMPILE: "compile_s",
+}
+#: programs whose first call ends a process's start-up (the report is
+#: logged once, when one of them has traced and returned)
+_TRAINING_PROGRAMS = ("train_step", "epoch")
+
+
+class _ThreadEvents(threading.local):
+    """What the listeners keep for one thread between JAX's events."""
+
+    def __init__(self):
+        # the cache has served the program whose backend_compile_duration
+        # comes next
+        self.served = False
+        # one entry an interval open on the thread (JAX says when one
+        # begins and when it ends): the seconds of those it has held
+        self.open = []
+        # (counter, fn) -> what to add once the outermost interval ends
+        self.own = {}
+
+
+_EVENTS = _ThreadEvents()
 
 
 def _cache_entries(path: str) -> int:
@@ -516,10 +567,64 @@ def _cache_entries(path: str) -> int:
 
 
 def _on_cache_event(event: str, **_kw) -> None:
-    key = _CACHE_EVENTS.get(event)
-    if key is not None:
-        with _PERSISTENT_LOCK:
-            _PERSISTENT_COUNTS[key] += 1
+    if event == _CACHE_HIT:
+        _EVENTS.served = True
+
+
+def _on_begin(event: str, _started: float, **_kw) -> None:
+    """An interval of tracing, lowering or compiling begins on this thread
+    (JAX records its start as a scalar under the duration event's name)."""
+    if event in _JIT_DURATIONS:
+        _EVENTS.open.append(0.0)
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    """JAX's duration events into the ``jit_*`` counters, each second
+    once.  A trace sends an event for every jitted function traced inside
+    it (the step of a scanned stack: thousands), each inside its interval,
+    and so does an operation that runs eagerly under a trace, with its own
+    lowering and compile: an interval is counted less what it held.  The
+    registry is written when the outermost interval ends."""
+    which = _JIT_DURATIONS.get(event)
+    if which is None:
+        return
+    events = _EVENTS
+    # no entry: the listeners were registered while the interval was open
+    held = events.open.pop() if events.open else 0.0
+    if events.open:
+        events.open[-1] += seconds
+    own, fn = events.own, open_entry.fn or "eager"
+    if event == _BACKEND_COMPILE:
+        served, events.served = events.served, False
+        which = "cache_load_s" if served else "compile_s"
+        programs = ("programs_loaded" if served else "programs_compiled", fn)
+        own[programs] = own.get(programs, 0) + 1
+    own[which, fn] = own.get((which, fn), 0.0) + max(seconds - held, 0.0)
+    if events.open:
+        return
+    reg = default_registry()
+    if reg.enabled:
+        for (counter, fn), amount in own.items():
+            name, text = JIT_COUNTERS[counter]
+            reg.counter(name, text, ("fn",)).labels(fn).inc(amount)
+    own.clear()
+
+
+def _listen() -> None:
+    """Register the three listeners, once a process; the six counters stand
+    at nought from then on (a warm run says ``compiled 0``, not nothing)."""
+    global _PERSISTENT_LISTENING
+    reg = default_registry()
+    if reg.enabled:
+        for name, text in JIT_COUNTERS.values():
+            reg.counter(name, text, ("fn",))
+    with _PERSISTENT_LOCK:
+        if not _PERSISTENT_LISTENING:
+            jax.monitoring.register_event_listener(_on_cache_event)
+            jax.monitoring.register_scalar_listener(_on_begin)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _PERSISTENT_LISTENING = True
 
 
 def wire_persistent_cache() -> Dict[str, Any]:
@@ -536,10 +641,12 @@ def wire_persistent_cache() -> Dict[str, Any]:
     differs from a cached one only in its name scopes is served the old
     executable, and a profile then reads the old scopes.  A checkout
     that cannot be written (read-only install) leaves the cache off and
-    says so in the returned status; nothing else is caught.  Returns the
-    status dict, including how many entries a previous process left behind
+    says so in the returned status; nothing else is caught.  Either way
+    the listeners that keep JAX's own seconds of tracing, lowering, loading
+    and compiling are registered (``_listen``).  Returns the status dict, including how many entries a previous process left behind
     (``existing_entries``)."""
-    global _PERSISTENT_STATUS, _PERSISTENT_LISTENING
+    global _PERSISTENT_STATUS
+    _listen()
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     placed_by = "JAX_COMPILATION_CACHE_DIR" if path else "checkout"
     if not path:
@@ -561,9 +668,6 @@ def wire_persistent_cache() -> Dict[str, Any]:
                   "Entries found in the persistent XLA compile cache dir "
                   "at wiring time").set(existing)
     with _PERSISTENT_LOCK:
-        if not _PERSISTENT_LISTENING:
-            jax.monitoring.register_event_listener(_on_cache_event)
-            _PERSISTENT_LISTENING = True
         _PERSISTENT_STATUS = {"enabled": True, "dir": path,
                               "placed_by": placed_by,
                               "existing_entries": existing}
@@ -572,11 +676,18 @@ def wire_persistent_cache() -> Dict[str, Any]:
 
 def persistent_cache_status() -> Dict[str, Any]:
     """The wiring status plus what has happened since: ``entries`` now in
-    the directory, and the ``hits``/``misses`` JAX has reported for this
-    process's compiles (a hit is an executable loaded from disk instead
-    of compiled)."""
+    the directory, and this process's programs by where they came from:
+    ``hits`` loaded from the directory (``jit_programs_loaded_total``),
+    ``misses`` compiled (``jit_programs_compiled_total``: every program
+    while the cache is off)."""
     with _PERSISTENT_LOCK:
-        status = dict(_PERSISTENT_STATUS, **_PERSISTENT_COUNTS)
+        status = dict(_PERSISTENT_STATUS)
+    reg = default_registry()
+    for key, which in (("hits", "programs_loaded"),
+                       ("misses", "programs_compiled")):
+        counter = reg.get(JIT_COUNTERS[which][0])
+        status[key] = int(sum(child.value for _fn, child in
+                              counter.samples())) if counter else 0
     if status.get("enabled"):
         status["enabled"] = bool(jax.config.jax_enable_compilation_cache)
         status["entries"] = _cache_entries(status["dir"])

@@ -32,7 +32,7 @@ from .conf.computation_graph import (ComputationGraphConfiguration,
 from .conf.updaters import Sgd, UpdaterConf
 from .layers.base import BaseLayerConf
 from ..data.shapes import default_shape_policy
-from ..observability.tracer import training_entry
+from ..observability.tracer import init_entry, training_entry
 from ..train.listeners import TrainingListener
 
 Array = jax.Array
@@ -256,6 +256,7 @@ class ComputationGraph:
         self.shape_policy = default_shape_policy()
 
     # ------------------------------------------------------------------ init
+    @init_entry
     def init(self) -> "ComputationGraph":
         key = jax.random.PRNGKey(self.conf.seed)
         self.params, self.state = {}, {}

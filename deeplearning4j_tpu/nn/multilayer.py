@@ -40,7 +40,7 @@ from .conf.schedules import resolve as resolve_schedule
 from .conf.updaters import Sgd, UpdaterConf
 from .layers.base import BaseLayerConf
 from ..data.shapes import _pad_time, default_shape_policy
-from ..observability.tracer import training_entry
+from ..observability.tracer import init_entry, training_entry
 from ..train.listeners import TrainingListener
 
 
@@ -381,6 +381,7 @@ class MultiLayerNetwork:
         self._stepprof = None
 
     # ------------------------------------------------------------------ init
+    @init_entry
     def init(self) -> "MultiLayerNetwork":
         key = jax.random.PRNGKey(self.conf.seed)
         self.params, self.state = {}, {}

@@ -14,7 +14,7 @@ OpProfiler, UI stats storage — SURVEY §5):
   a profiler trace holds the program's own spans (``dl4j.fit``,
   ``dl4j.input_wait``, ``dl4j.h2d``, ``dl4j.call.<name>``,
   ``dl4j.window_wait``, ``dl4j.profiler_fence``, ``dl4j.sync``,
-  ``dl4j.gc``; the table is in the README) beside the device's
+  ``dl4j.gc``, ``dl4j.init``; the table is in the README) beside the device's
   operations, which the step program's name scopes (``forward``,
   ``grad_post``, ``optimizer``, one per layer class) name; an enabled
   tracer also records nested spans on monotonic clocks with
@@ -25,7 +25,13 @@ OpProfiler, UI stats storage — SURVEY §5):
 - :mod:`listener` — ``MetricsListener`` publishing score/throughput/
   grad-norm/device-memory from the ``TrainingListener`` hook points;
 - :mod:`clock` — the monotonic/wall helpers everything above (and the
-  benchmarks) source timings from;
+  benchmarks) source timings from, and ``process_age_s()``;
+- :mod:`startup` — ``startup_report()``: where the host's seconds went
+  between the start of the process and the first steady step (the
+  process's age at the package's import, the import, the containers'
+  ``init()`` under the span ``dl4j.init``, and JAX's own seconds of
+  tracing, lowering, loading from the persistent cache and compiling, by
+  program);
 - :mod:`quantiles` — sliding-window exact quantiles (``LatencyWindow``),
   the live p50/p99 read the serving tier's SLO admission control gates
   on (registry histograms answer scrape-interval questions, not
@@ -57,7 +63,7 @@ sampled fence apart (default on, every 16th step of a ``fit``).
 """
 from __future__ import annotations
 
-from .clock import monotonic_s, wall_s
+from .clock import monotonic_s, process_age_s, wall_s
 from .events import EventLog, configure_event_log, emit_event, get_event_log
 from .exposition import CONTENT_TYPE, escape_label_value, render_text
 from .health import (Detection, HealthConfig, HealthMonitor,
@@ -72,6 +78,7 @@ from .recorder import (FlightRecorder, get_flight_recorder, load_dump,
 from .registry import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                        MetricsRegistry, default_registry,
                        set_default_registry)
+from .startup import startup_report
 from .tracer import Span, SpanContext, Tracer, get_tracer, set_default_tracer
 
 __all__ = [
@@ -85,10 +92,10 @@ __all__ = [
     "emit_event", "escape_label_value", "get_event_log",
     "get_flight_recorder", "get_health_monitor", "get_tracer",
     "load_chrome_trace", "load_dump",
-    "monotonic_s", "phase_summary", "record_slices", "render_text",
-    "set_default_registry",
+    "monotonic_s", "phase_summary", "process_age_s", "record_slices",
+    "render_text", "set_default_registry",
     "set_default_tracer", "set_flight_recorder", "set_health_monitor",
-    "step_profiler_for", "stepprof_enabled", "wall_s",
+    "startup_report", "step_profiler_for", "stepprof_enabled", "wall_s",
 ]
 
 

@@ -44,8 +44,8 @@ import jax
 from .clock import monotonic_s, wall_s
 from .registry import MetricsRegistry, default_registry
 
-__all__ = ["Span", "SpanContext", "Tracer", "get_tracer",
-           "set_default_tracer", "training_entry"]
+__all__ = ["Span", "SpanContext", "Tracer", "get_tracer", "init_entry",
+           "open_entry", "set_default_tracer", "training_entry"]
 
 # span-duration histogram bounds: phase timings range from sub-ms host
 # work to multi-second aggregation rounds
@@ -366,3 +366,36 @@ def training_entry(name: str):
                 _collector_watch.release()
         return traced
     return decorate
+
+
+# ---------------------------------------------------------------- start-up
+class _OpenEntry(threading.local):
+    """The innermost of the program's entries open on this thread that
+    make jitted programs: the name of an ``InstrumentedJit`` inside its
+    call, ``"init"`` inside a container's ``init()``, else None.  The
+    ``fn`` label of the ``jit_*`` counters (``nn/compile_cache``)."""
+    fn = None
+
+
+open_entry = _OpenEntry()
+
+
+def init_entry(init):
+    """Decorator for a container's ``init()``: the call is the span
+    ``dl4j.init``, its wall time goes to ``model_init_seconds_total``,
+    and the programs JAX makes inside count under ``fn="init"``."""
+    @functools.wraps(init)
+    def traced(*args, **kwargs):
+        began = monotonic_s()
+        outer, open_entry.fn = open_entry.fn, "init"
+        try:
+            with get_tracer().span("dl4j.init"):
+                return init(*args, **kwargs)
+        finally:
+            open_entry.fn = outer
+            reg = default_registry()
+            if reg.enabled:
+                reg.counter("model_init_seconds_total",
+                            "Wall seconds inside the containers' init()"
+                            ).inc(monotonic_s() - began)
+    return traced
