@@ -748,9 +748,23 @@ class LatentAttention(BaseLayerConf):
     they are.  The output is ``concat(o_h) Wo``, ``n_heads * v_dim`` to
     ``n_out``.
 
+    **The keys reach the flash kernels as the projections wrote them**
+    (``project``: ``q``, ``kv``, ``k_r``): ``kv`` is the up-projection's
+    product, ``[k_nope | v]`` a head, which the kernels read as two column
+    blocks, and ``k_r`` the one rotary key, which every head's kernel row
+    reads through its index map (``flash_attention(q, kv=, k_shared=)``).
+    No ``[b, h, t, head_dim + rope_dim]`` key is assembled, nothing is
+    copied to the heads, and ``kv``'s gradient comes back as ``kv`` lies.
+    That form needs ``head_dim == v_dim``, a multiple of 128
+    (``flash_blocks``); at other widths, and wherever the reference
+    attention runs (``attn_impl='reference'``, 'auto' under
+    ``flash_min_seq`` or off the TPU, a key mask), ``whole_keys``
+    assembles ``k`` and ``v`` from the same three arrays.
+
     The five products, the two norms, the rotary part and the assembling of
-    q and k run under the scope ``mla_project``; the attention itself under
-    ``attn_full`` (``_run_attention``).  Trains and runs forward; there is
+    q (and of k and v where they are assembled) run under the scope
+    ``mla_project``; the attention itself under
+    ``attn_full``.  Trains and runs forward; there is
     no KV-cache path: a cache of the latent (``kv_rank + rope_dim`` a token)
     with the absorbed products is not written, so ``attend_cached`` raises,
     as ``MultiHeadAttention``'s does for its newer choices."""
@@ -812,8 +826,11 @@ class LatentAttention(BaseLayerConf):
         return {"params": params, "state": {}}
 
     def project(self, p, x):
-        """``(q, k, v)`` as the attention takes them: ``[b, h, t, head_dim
-        + rope_dim]`` twice and ``[b, h, t, v_dim]``."""
+        """``(q, kv, k_r)`` as the projections write them: q ``[b, h, t,
+        head_dim + rope_dim]``, its rotary part turned; ``kv`` ``[b, h, t,
+        head_dim + v_dim]``, each head's ``k_nope`` beside its ``v``, the
+        up-projection's product and nothing done to it; ``k_r`` ``[b, 1, t,
+        rope_dim]``, the one rotary key a position, turned."""
         h, dn, dr, dv = self._widths()
         b, t, _ = x.shape
 
@@ -829,9 +846,38 @@ class LatentAttention(BaseLayerConf):
         q = jnp.concatenate(
             [q[..., :dn], _rotary_pairs(q[..., dn:], self.rope_theta)],
             axis=-1)
+        return q, kv, k_r
+
+    def whole_keys(self, kv, k_r):
+        """``(k, v)`` a head, assembled for an attention that takes them
+        whole: ``k`` is ``k_nope`` beside the rotary key copied to every
+        head, ``[b, h, t, head_dim + rope_dim]``, ``v`` the rest of
+        ``kv``."""
+        dn = self.head_dim
         k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
-        return q, k, kv[..., dn:]
+            [kv[..., :dn],
+             jnp.broadcast_to(k_r, kv.shape[:3] + k_r.shape[3:])], axis=-1)
+        return k, kv[..., dn:]
+
+    def _flash_in_parts(self, t: int, mask) -> bool:
+        """Whether this call runs the flash kernels on the keys as
+        ``project`` leaves them: the kernels are what runs (asked for, or
+        what 'auto' resolves to here) and they take these widths in
+        parts."""
+        from ...ops.flash_attention import flash_blocks
+        _, dn, dr, dv = self._widths()
+        impl = self.attn_impl
+        if impl == "auto":
+            impl = auto_attention_impl(
+                t, t, dn + dr, masked=mask is not None,
+                flash_min_seq=self.flash_min_seq, d_v=dv)
+        if impl != "flash" or mask is not None:
+            return False
+        try:
+            flash_blocks(t, t, dn + dr, d_v=dv, d_shared=dr)
+        except ValueError:
+            return False
+        return True
 
     def attend(self, p, x, *, train=False, key=None, mask=None):
         from ...observability.registry import default_registry
@@ -844,15 +890,27 @@ class LatentAttention(BaseLayerConf):
                         "heads and the width of a head of q/k and of v",
                         ("heads", "qk", "v")).labels(
                             str(h), str(dn + dr), str(dv)).inc()
+        parts = self._flash_in_parts(x.shape[1], mask)
         with jax.named_scope("mla_project"):
-            q, k, v = self.project(p, x)
+            q, kv, k_r = self.project(p, x)
             # as the attention takes them: the layout a backward reads
             q = checkpoint_name(q, "attn_q")
-            k = checkpoint_name(k, "attn_k")
-            v = checkpoint_name(v, "attn_v")
-        o = _run_attention(q, k, v, impl=self.attn_impl, causal=True,
-                           mask=mask, seq_axis="seq",
-                           flash_min_seq=self.flash_min_seq)
+            if parts:
+                kv = checkpoint_name(kv, "attn_kv")
+                k_r = checkpoint_name(k_r, "attn_k_shared")
+            else:
+                k, v = self.whole_keys(kv, k_r)
+                k = checkpoint_name(k, "attn_k")
+                v = checkpoint_name(v, "attn_v")
+        if parts:
+            from ...ops.flash_attention import flash_attention
+            with jax.named_scope("attn_full"):
+                # the kernel names its own output and log-sum-exp
+                o = flash_attention(q, kv=kv, k_shared=k_r, causal=True)
+        else:
+            o = _run_attention(q, k, v, impl=self.attn_impl, causal=True,
+                               mask=mask, seq_axis="seq",
+                               flash_min_seq=self.flash_min_seq)
         with jax.named_scope("mla_project"):
             b_, h, t, dv = o.shape
             return o.transpose(0, 2, 1, 3).reshape(b_, t, h * dv) @ p["Wo"]
@@ -1014,7 +1072,11 @@ class TransformerBlock(BaseLayerConf):
             else ()
         # the output gate's product, as large as q and as dear
         gate = ("attn_gate",) if self.attn_gate else ()
-        return eva + ("attn_q", "attn_k", "attn_v") + gate + (
+        # a latent block names its keys as the attention takes them: the
+        # [k | v] product and the one rotary key, or k and v assembled
+        keys = ("attn_kv", "attn_k_shared") if self.attention == "latent" \
+            else ()
+        return eva + ("attn_q",) + keys + ("attn_k", "attn_v") + gate + (
             "mlp_up", "mlp_gate", "attn_lse", "attn_out", "block_mid")
 
     def set_n_in(self, itype: InputType, override: bool = False) -> None:

@@ -32,6 +32,20 @@ one a tile of queries over the key tiles it can see, the mask on the tiles
 an edge crosses only (``_band_steps``).  Such calls carry names of their
 own (``flash_win_*``), so a trace tells them from full calls.
 
+The keys come whole, ``k`` and ``v`` a head, or **in parts, as latent
+attention's projections write them** (``flash_attention(q, kv=,
+k_shared=)``): ``kv`` is one product, ``[k | v]`` a head, of which the
+kernels' BlockSpecs read ``k`` and ``v`` as column blocks 0 and 1, and
+``k_shared`` the part of the key every head shares (the one rotary key),
+one array a batch row that every head's grid rows read through an index
+map that leaves the head out.  A step lays ``[k | k_shared]`` side by side
+in VMEM and scores them with one product against the whole q
+(``_key_tile``); the dk/dv kernel writes ``[dk | dv]`` as ``kv`` lies and
+each head's share of ``k_shared``'s gradient, which one reduction outside
+sums over the heads.  So no sliced, broadcast or assembled key exists
+outside the kernels.  A call with whole keys traces the kernel bodies it
+always traced.
+
 Products take their operands in the type they arrive in and accumulate in
 float32 (Mosaic at its default precision gives float32 operands one
 bfloat16 pass all the same); ``p`` and ``dS`` are cast to the operand type
@@ -290,9 +304,26 @@ def _column(x):
     return jnp.broadcast_to(x[0][:, None], (x.shape[1], _LANES))
 
 
+def _key_tile(k_ref, ks_ref, keys):
+    """The keys of a step as the score takes them: the rows of ``k_ref``,
+    and where the key came in parts (``ks_ref``: the part every head
+    shares) the two laid side by side in VMEM, ``[k | k_shared]``, as wide
+    as q: one product then scores them, as it scores a whole key.  (The
+    other way, ``q[:, :d_k] kᵀ + q[:, d_k:] k_sharedᵀ`` as two products
+    summed, costs the MXU the same passes and the forward a float32 add a
+    score tile: 6.04 ms a call against 5.72 at [32, 8192, 128 + 64 | 128]
+    on a v5e, dq and dkv the same; PERF.md section 6, PR 39.)  No
+    ``ks_ref``: ``k_ref``'s rows and nothing else."""
+    k = k_ref[keys, :]
+    if ks_ref is None:
+        return k
+    return jnp.concatenate([k, ks_ref[keys, :]], axis=1)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                   *, scale: float, causal: bool, block_q: int, block_k: int,
-                  window: Optional[int] = None, n_walk: int = 0):
+                  window: Optional[int] = None, n_walk: int = 0,
+                  ks_ref=None):
     # grid: (heads, blocks of queries, blocks of keys); under a window the
     # last is the walk along the band (_run_band)
     qi, ki = pl.program_id(1), pl.program_id(2)
@@ -309,8 +340,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nq, d_qk]
         v = v_ref[keys, :]                           # [nkeys, d_v]
-        s = _masked_scores(q, k_ref[keys, :], q0, k0, cut, head=head,
-                           shift=shift, window=window)
+        s = _masked_scores(q, _key_tile(k_ref, ks_ref, keys), q0, k0, cut,
+                           head=head, shift=shift, window=window)
         # m, l: lane-replicated [rows, 128], so the row statistics meet
         # the score tile vreg for vreg with no lane broadcast
         m_prev = m_ref[rows, :]
@@ -348,6 +379,14 @@ def _like(x, shape=None, dtype=None):
                                 dtype or x.dtype, vma=jax.typeof(x).vma)
 
 
+def _walked(clamp):
+    """The block a walked operand is at, at step ``w`` beside held block
+    ``h``: ``w``, or under ``clamp`` the live block next to a dead one."""
+    def live(h, w):
+        return clamp(w, h) if clamp else w
+    return live
+
+
 def _specs(d: int, held: int, walked: int, clamp=None):
     """Block specs of a kernel whose grid is (heads, held blocks, walked
     blocks): the held operand, the walked operand — under ``causal`` a
@@ -356,8 +395,7 @@ def _specs(d: int, held: int, walked: int, clamp=None):
     where queries are), so it is not copied — and each one's slice of the
     lane-major row statistics.  ``d`` is the operands' width: q's and k's
     in one call, v's (o's, dO's) in another where the two differ."""
-    def live(h, w):
-        return clamp(w, h) if clamp else w
+    live = _walked(clamp)
     return (pl.BlockSpec((None, held, d), lambda b, h, w: (b, h, 0)),
             pl.BlockSpec((None, walked, d),
                          lambda b, h, w: (b, live(h, w), 0)),
@@ -368,8 +406,46 @@ def _specs(d: int, held: int, walked: int, clamp=None):
 
 def _row_bytes(qr, vr) -> int:
     """Bytes of the widest operand row a block holds: q's (and k's) or
-    v's (and o's)."""
+    v's (and o's); of keys in parts, where ``vr`` is the ``[k | v]``
+    product, q's or that product's."""
     return max(qr.shape[2], vr.shape[2]) * qr.dtype.itemsize
+
+
+def _value_width(qr, vr, shared) -> int:
+    """The width of v (o, dO): ``vr``'s, or of keys in parts, where ``vr``
+    is the ``[k | v]`` product, q's less the shared part's."""
+    return vr.shape[2] if shared is None else qr.shape[2] - shared[0].shape[2]
+
+
+def _keys(kr, vr, shared, rows: int, at, k_spec, v_spec):
+    """``(operands, block specs)`` of a launch's keys, ``rows`` of them a
+    block, the block ``at(h, w)``.  Whole keys: ``kr`` and ``vr`` under the
+    specs given.  Keys in parts (``shared``: ``(ksr, heads)``; ``kr`` and
+    ``vr`` are then both the one ``[k | v]`` product, its halves equally
+    wide): k and v are that product's column blocks 0 and 1, no slice
+    outside the kernel, and the shared part is one array a batch row whose
+    index map leaves the head out, so ``heads`` kernel rows read it and no
+    broadcast exists."""
+    if shared is None:
+        return (kr, vr), [k_spec, v_spec]
+    ksr, heads = shared
+    half = kr.shape[2] // 2
+    return (kr, vr, ksr), [
+        pl.BlockSpec((None, rows, half), lambda b, h, w: (b, at(h, w), 0)),
+        pl.BlockSpec((None, rows, half), lambda b, h, w: (b, at(h, w), 1)),
+        pl.BlockSpec((None, rows, ksr.shape[2]),
+                     lambda b, h, w: (b // heads, at(h, w), 0))]
+
+
+def _shared_fourth(kernel, shared):
+    """``kernel`` as a launch of keys in parts calls it: the shared part of
+    the key is its fourth operand."""
+    if shared is None:
+        return kernel
+
+    def body(q_ref, k_ref, v_ref, ks_ref, *refs):
+        return kernel(q_ref, k_ref, v_ref, *refs, ks_ref=ks_ref)
+    return body
 
 
 def _band(window, t_k, rows):
@@ -386,20 +462,23 @@ def _band(window, t_k, rows):
 
 
 def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
-                window=None):
+                window=None, shared=None):
     bh, t_q, d = qr.shape
-    t_k, d_v = kr.shape[1], vr.shape[2]
+    t_k, d_v = kr.shape[1], _value_width(qr, vr, shared)
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  _row_bytes(qr, vr))
     band, keys_at, _ = _band(window, t_k, q_rows)
     clamp = keys_at or (jnp.minimum if causal else None)
     q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
     o_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, _walked(clamp), k_spec,
+                            v_spec)
     out, lse = pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, **band),
+        _shared_fourth(functools.partial(
+            _flash_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, **band), shared),
         grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
-        in_specs=[q_spec, k_spec, v_spec],
+        in_specs=[q_spec] + key_specs,
         out_specs=[o_spec, row_spec],
         out_shape=[_like(qr, (bh, t_q, d_v)),
                    _like(qr, (bh, 1, t_q), jnp.float32)],
@@ -410,13 +489,14 @@ def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
         name="flash_win_fwd" if window else "flash_fwd",
-    )(qr, kr, vr)
+    )(qr, *keys)
     return out, lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                          dq_ref, dq_acc, *, scale, causal,
-                         block_q, block_k, window=None, n_walk=0):
+                         block_q, block_k, window=None, n_walk=0,
+                         ks_ref=None):
     """dq of one block of queries: replay P from the saved logsumexp, form
     dS = P∘(dP − D) (FlashAttention-2 bwd) and add dS·k over the keys;
     the scale meets the ``[rows, d]`` sum once at the end."""
@@ -430,7 +510,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     def step(q0, nq, k0, nkeys, cut, head=0, shift=0):
         rows, keys = pl.ds(q0, nq), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nq, d]
-        k = k_ref[keys, :]                           # [nkeys, d]
+        k = _key_tile(k_ref, ks_ref, keys)           # [nkeys, d]
         lse = _column(lse_ref[:, rows])              # [nq, 128]
         dd = _column(dd_ref[:, rows])
         s = _masked_scores(q, k, q0, k0, cut, head=head, shift=shift,
@@ -458,11 +538,15 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          block_q, block_k, window=None, n_walk=0):
+                          block_q, block_k, window=None, n_walk=0,
+                          ks_ref=None):
     """dk, dv of one block of keys, on the transposed score tile (keys
     down the rows, queries along the lanes): lse and D are used as the
     lane-major rows they are stored as, and Pᵀ·dO, dSᵀ·q are plain
-    products.  grid: (heads, blocks of keys, blocks of queries)."""
+    products.  grid: (heads, blocks of keys, blocks of queries).  Of keys
+    in parts (``ks_ref``) ``dk_ref`` is the block of ``[dk | dv]`` that
+    lies as the up-projection's product does, and ``dv_ref`` this head's
+    share of the shared part's gradient."""
     ki, qi = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -475,7 +559,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         rows, keys = pl.ds(q0, nqs), pl.ds(k0, nkeys)
         q = _scaled(q_ref[rows, :], scale)           # [nqs, d]
         do = do_ref[rows, :]
-        st = _masked_scores(q, k_ref[keys, :], q0, k0, cut,
+        st = _masked_scores(q, _key_tile(k_ref, ks_ref, keys), q0, k0, cut,
                             transposed=True, head=head, shift=shift,
                             window=window)           # [nkeys, nqs]
         pt = jnp.exp(st - lse_ref[:, rows])
@@ -500,8 +584,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
     @pl.when(qi == nq - 1)
     def _done():
-        dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+        if ks_ref is None:
+            dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+            return
+        d_k = k_ref.shape[1]
+        dk_ref[:, :d_k] = dk_acc[:, :d_k].astype(dk_ref.dtype)
+        dk_ref[:, d_k:] = dv_acc[:].astype(dk_ref.dtype)
+        dv_ref[...] = dk_acc[:, d_k:].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -527,26 +617,32 @@ def _flash_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
 
 
 def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
-                       block_k, interpret, window=None):
+                       block_k, interpret, window=None, shared=None):
+    """``(dq, dk, dv)``; of keys in parts (``shared``) ``(dq, the gradient
+    of the [k | v] product as that product lies, each head's share of the
+    shared part's gradient)``."""
     bh, t_q, d = qr.shape
-    t_k, d_v = kr.shape[1], vr.shape[2]
+    t_k, d_v = kr.shape[1], _value_width(qr, vr, shared)
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  _row_bytes(qr, vr))
     band, keys_at, queries_at = _band(window, t_k, q_rows)
     clamp = keys_at or (jnp.minimum if causal else None)
     q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
     do_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, _walked(clamp), k_spec,
+                            v_spec)
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, **band),
+        _shared_fourth(functools.partial(
+            _flash_bwd_dq_kernel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, **band), shared),
         grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        in_specs=[q_spec] + key_specs + [do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_like(qr),
         scratch_shapes=[pltpu.VMEM((q_rows, d), jnp.float32)],
         interpret=interpret,
         name="flash_win_bwd_dq" if window else "flash_bwd_dq",
-    )(qr, kr, vr, do, lse, dd)
+    )(qr, *keys, do, lse, dd)
 
     # swapped roles: a block of keys held, queries walked, so dk/dv carry
     # in scratch; a block of queries wholly above the diagonal repeats the
@@ -557,38 +653,90 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     clamp2 = queries_at or (jnp.maximum if causal else None)
     k_spec2, q_spec2, _, row_spec2 = _specs(d, k_rows, q_rows, clamp2)
     v_spec2, do_spec2, _, _ = _specs(d_v, k_rows, q_rows, clamp2)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, lambda h, w: h, k_spec2,
+                            v_spec2)
+    if shared is None:
+        out_specs, out_shape = [k_spec2, v_spec2], [_like(kr), _like(vr)]
+    else:
+        # [dk | dv] a held block, and this head's rows of the shared part's
+        out_specs = [_specs(w, k_rows, q_rows)[0]
+                     for w in (2 * d_v, d - d_v)]
+        out_shape = [_like(kr), _like(kr, (bh, t_k, d - d_v))]
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, **band),
+        _shared_fourth(functools.partial(
+            _flash_bwd_dkv_kernel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, **band), shared),
         grid=(bh, t_k // k_rows, band.get("n_walk", t_q // q_rows)),
-        in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, row_spec2, row_spec2],
-        out_specs=[k_spec2, v_spec2],
-        out_shape=[_like(kr), _like(vr)],
+        in_specs=[q_spec2] + key_specs + [do_spec2, row_spec2, row_spec2],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((k_rows, d), jnp.float32),
                         pltpu.VMEM((k_rows, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
-    )(qr, kr, vr, do, lse, dd)
+    )(qr, *keys, do, lse, dd)
     return dq, dk, dv
+
+
+def _row_dots(with_lse, out, do):
+    """``(dO, D)`` of a backward rule's cotangent: D = rowsum(dO ∘ O), one
+    elementwise+reduce pass, XLA-fused, ``(bh, 1, t_q)`` row form."""
+    if with_lse:
+        do, dlse = do
+    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                 axis=-1)[:, None, :]
+    if with_lse:
+        # d lse_i / d s_ij = p_ij, so a cotangent on the log-sum-exp adds
+        # dlse_i * p_ij to dS = P∘(dP − D): the kernels take it as D − dlse
+        dd = dd - dlse
+    return do, dd
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, with_lse,
                window, res, do):
     qr, kr, vr, out, lse = res
-    if with_lse:
-        do, dlse = do
-    # D = rowsum(dO ∘ O): one elementwise+reduce pass, XLA-fused
-    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                 axis=-1)[:, None, :]               # (bh, 1, t_q) row form
-    if with_lse:
-        # d lse_i / d s_ij = p_ij, so a cotangent on the log-sum-exp adds
-        # dlse_i * p_ij to dS = P∘(dP − D): the kernels take it as D − dlse
-        dd = dd - dlse
+    do, dd = _row_dots(with_lse, out, do)
     return _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
                        block_k, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_parts(qr, kvr, ksr, scale, causal, block_q, block_k, interpret,
+                 with_lse=False, heads=1):
+    """``_flash`` of keys in parts: ``kvr`` ``(b heads, t, d_k + d_v)`` the
+    ``[k | v]`` product, ``ksr`` ``(b, t, d_s)`` the part of the key every
+    head shares; ``qr``'s last ``d_s`` features meet it."""
+    out, lse = _launch_fwd(qr, kvr, kvr, scale, causal, block_q, block_k,
+                           interpret, shared=(ksr, heads))
+    return (out, lse) if with_lse else out
+
+
+def _flash_parts_fwd(qr, kvr, ksr, scale, causal, block_q, block_k,
+                     interpret, with_lse=False, heads=1):
+    out, lse = _launch_fwd(qr, kvr, kvr, scale, causal, block_q, block_k,
+                           interpret, shared=(ksr, heads))
+    out = checkpoint_name(out, "attn_out")           # as _flash_fwd
+    lse = checkpoint_name(lse, "attn_lse")
+    return ((out, lse) if with_lse else out), (qr, kvr, ksr, out, lse)
+
+
+def _flash_parts_bwd(scale, causal, block_q, block_k, interpret, with_lse,
+                     heads, res, do):
+    qr, kvr, ksr, out, lse = res
+    do, dd = _row_dots(with_lse, out, do)
+    dq, dkv, dks = _launch_bwd(qr, kvr, kvr, do, lse, dd, scale, causal,
+                               block_q, block_k, interpret,
+                               shared=(ksr, heads))
+    # the heads' shares of the shared part's gradient, summed in float32
+    dks = jnp.sum(dks.reshape(-1, heads, *dks.shape[1:]), axis=1,
+                  dtype=jnp.float32).astype(ksr.dtype)
+    return dq, dkv, dks
+
+
+_flash_parts.defvjp(_flash_parts_fwd, _flash_parts_bwd)
 
 
 #: kernel names as they appear in the lowered program's ``tpu_custom_call``
@@ -602,13 +750,16 @@ KERNEL_NAMES = FULL_KERNEL_NAMES + WINDOW_KERNEL_NAMES
 
 def flash_blocks(t_q: int, t_k: int, d: int,
                  block_q: Optional[int] = None,
-                 block_k: Optional[int] = None, d_v: Optional[int] = None):
+                 block_k: Optional[int] = None, d_v: Optional[int] = None,
+                 d_shared: Optional[int] = None):
     """``(block_q, block_k)``, the score tile the kernels run these shapes
     with: ``d`` the width of a head of q and k, ``d_v`` of v (default
-    ``d``).  Raises ``ValueError`` naming the reason when it cannot tile
-    them — the one support check, shared by ``flash_attention`` (which
-    raises) and ``attn_impl='auto'`` (which then chooses the reference
-    path)."""
+    ``d``); ``d_shared``, where the keys come in parts, the width of the
+    part every head shares, the last of ``d``.  Raises ``ValueError``
+    naming the reason when it cannot tile them — the one support check,
+    shared by ``flash_attention`` (which raises) and ``attn_impl='auto'``
+    (which then chooses the reference path; a caller whose keys it refuses
+    in parts assembles them and asks again)."""
     auto_q, auto_k = _auto_blocks(t_q, t_k, d)
     block_q = min(block_q, t_q) if block_q else auto_q
     block_k = min(block_k, t_k) if block_k else auto_k
@@ -617,6 +768,13 @@ def flash_blocks(t_q: int, t_k: int, d: int,
         # head_dim must fill whole MXU lanes for the kernel's tiling
         raise ValueError(f"flash attention needs head_dim % 64 == 0, "
                          f"got {d}" + (f" and {d_v}" if d_v != d else ""))
+    if d_shared is not None and (d - d_shared != d_v or d_v % _LANES
+                                 or d_shared <= 0):
+        # k and v are read as lane-tile-wide column blocks of one array
+        raise ValueError(
+            f"flash attention takes keys in parts where the head's own "
+            f"part is as wide as v and a multiple of {_LANES}: got "
+            f"{d - d_shared} (of {d}, {d_shared} shared) and {d_v}")
     if t_q % block_q or t_k % block_k:
         raise ValueError(
             f"flash attention needs sequence lengths divisible by its "
@@ -648,7 +806,8 @@ def _kernel_partitioning(q):
     return mesh, (P("data") if data and q.shape[0] % data == 0 else P())
 
 
-def flash_attention(q, k, v, *, causal: bool = False,
+def flash_attention(q, k=None, v=None, *, kv=None, k_shared=None,
+                    causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
@@ -660,6 +819,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``v`` may have a last dimension of its own (latent attention: q and k
     192 wide, v 128): the output is as wide as ``v``, the scale follows
     q's width, and nothing is padded.
+
+    **Keys in parts** (latent attention's, as its projections write them):
+    in place of ``k`` and ``v``, ``kv`` ``[b, h, t, d_k + d_v]``, each
+    head's own part of the key beside its value as one product left them,
+    and ``k_shared`` ``[b, 1, t, d_s]``, the part of the key every head
+    shares; ``q`` stays whole, ``[b, h, t, d_k + d_s]``, its last ``d_s``
+    features meeting ``k_shared``.  The kernels read ``k`` and ``v`` as
+    column blocks of ``kv`` and ``k_shared`` through an index map that
+    leaves the head out: no slice, broadcast or assembled key exists
+    outside them; ``kv``'s gradient comes back as ``kv`` lies, ``[dk |
+    dv]``, and ``k_shared``'s summed over the heads.  Taken where ``d_k ==
+    d_v`` is a multiple of 128 and without a window (``flash_blocks``
+    refuses the rest: assemble ``k`` and ``v`` then).
 
     ``return_lse`` gives ``(out, lse)``, ``lse`` the float32 ``[b, h, t]``
     log-sum-exp of each row's scaled scores, differentiable like ``out``
@@ -679,9 +851,24 @@ def flash_attention(q, k, v, *, causal: bool = False,
     supported here.  ``attn_impl='auto'`` is the caller that chooses
     between this and ``sdpa_reference``.
     """
+    given = [a is not None for a in (k, v, kv, k_shared)]
+    parts = given == [False, False, True, True]
+    if not parts and given != [True, True, False, False]:
+        raise ValueError("flash attention takes k and v, or kv and "
+                         "k_shared in their place")
     _, h, t_q, d = q.shape
-    t_k, d_v = k.shape[2], v.shape[3]
-    block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k, d_v)
+    if parts:
+        if window is not None:
+            raise ValueError("flash attention takes keys in parts without "
+                             f"a window, got window={window}")
+        d_s = k_shared.shape[3]
+        t_k, d_v = kv.shape[2], kv.shape[3] - (d - d_s)
+        keys = (kv, k_shared)
+    else:
+        d_s = None
+        t_k, d_v = k.shape[2], v.shape[3]
+        keys = (k, v)
+    block_q, block_k = flash_blocks(t_q, t_k, d, block_q, block_k, d_v, d_s)
     if scale is None:
         scale = d ** -0.5
     if window is not None:
@@ -691,12 +878,27 @@ def flash_attention(q, k, v, *, causal: bool = False,
                 f"and keys of one length: causal={causal}, t_q={t_q}, "
                 f"t_k={t_k}")
         window = int(window) if window < t_k else None
+    from ..observability.registry import default_registry
+    reg = default_registry()
+    if reg.enabled:
+        # trace-time, like mla_layers_traced_total
+        reg.counter("flash_calls_traced_total",
+                    "Calls of flash_attention traced into a program, by "
+                    "the form the keys came in: whole (k and v a head) or "
+                    "parts (the [k | v] product and the shared part)",
+                    ("keys",)).labels("parts" if parts else "whole").inc()
 
     def run(q, k, v):
+        # keys in parts: k is kv, v the shared part
         rows = q.shape[0] * h
-        out = _flash(q.reshape(rows, t_q, d), k.reshape(rows, t_k, d),
-                     v.reshape(rows, t_k, d_v), scale, causal, block_q,
-                     block_k, interpret, return_lse, window)
+        qr, kr = (a.reshape(rows, *a.shape[2:]) for a in (q, k))
+        if parts:
+            out = _flash_parts(qr, kr, v.reshape(-1, t_k, d_s), scale,
+                               causal, block_q, block_k, interpret,
+                               return_lse, h)
+        else:
+            out = _flash(qr, kr, v.reshape(rows, t_k, d_v), scale, causal,
+                         block_q, block_k, interpret, return_lse, window)
         o_shape = q.shape[:3] + (d_v,)
         if return_lse:
             return out[0].reshape(o_shape), out[1].reshape(q.shape[:3])
@@ -710,4 +912,4 @@ def flash_attention(q, k, v, *, causal: bool = False,
         run = jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec),
                             out_specs=(spec, spec) if return_lse else spec,
                             check_vma=not interpret)
-    return run(q, k, v)
+    return run(q, *keys)

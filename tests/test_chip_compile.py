@@ -90,6 +90,14 @@ def _head_chunks():
                                  for labels, child in c.samples()}
 
 
+def _flash_calls():
+    """``{form of the keys: calls of flash_attention traced so far}``."""
+    c = default_registry().get("flash_calls_traced_total")
+    return dict.fromkeys(("whole", "parts"), 0) | (
+        {} if c is None else {labels[0]: child.value
+                              for labels, child in c.samples()})
+
+
 def _while_stacks(compiled, shape: str) -> int:
     """The most arrays of ``shape`` that one ``while`` of the compiled
     program carries: what the forward scan stacks for the backward."""
@@ -424,9 +432,14 @@ def test_trinity_share_step_compiles_for_v5e_with_room(one_chip,
         jax.eval_shape(built))
     ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
     before = _head_chunks().get(("8192", "25024", "4"), 0)
+    calls_before = _flash_calls()
     lowered = held["net"]._get_jitted("train_step").audit_lower(
         (args + (ids, ids, None, None), {}))
     assert _head_chunks()[("8192", "25024", "4")] == before + 1
+    # five attention layers, each handing the kernels k and v whole
+    assert {form: n - calls_before[form]
+            for form, n in _flash_calls().items()} == {"whole": 5,
+                                                       "parts": 0}
     compiled = lowered.compile()
     assert _head_repeats(compiled) == []
     calls = [line for line in compiled.as_text().split("\n")
@@ -469,16 +482,146 @@ def test_flash_kernels_compile_at_192_wide_keys_and_128_wide_values(one_chip):
     assert _custom_calls(lowered.compile()) == 3
 
 
+def _kernel_operands(compiled) -> dict:
+    """``{kernel: [(operand's instruction, its shape), ...]}`` of the full
+    flash kernels' custom calls in a compiled program, the shapes as the
+    call constrains them."""
+    found = {}
+    for line in compiled.as_text().split("\n"):
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        # the kernel's name ends the call's scope, bare or inside jvp(...)
+        name = re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call"', line)
+        name = name and name.group(1)
+        if name not in F.FULL_KERNEL_NAMES:
+            continue
+        operands = re.sub(r"/\*index=\d+\*/", "", re.search(
+            r" custom-call\(([^)]*)\)", line).group(1))
+        shapes = re.findall(r"(\w+\[[\d,]*\])\{", re.search(
+            r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
+        found.setdefault(name, []).append(list(zip(
+            (o.strip().lstrip("%") for o in operands.split(",")), shapes)))
+    return found
+
+
+def _assembled_keys(compiled) -> list:
+    """What a compiled program still does to hand the full flash kernels
+    their keys: a kernel call that reads a 192-wide key a head (its second
+    operand as wide as q), or whose k and v are not one array read twice;
+    and every broadcast of a ``[t, 64]`` array to 32 heads."""
+    faults = []
+    for name, calls in _kernel_operands(compiled).items():
+        for (_, q), (k, k_shape), (v, _), *rest in calls:
+            shared = rest[0][1] if rest else ""     # the forward has 3 or 4
+            if k != v or k_shape == q or not shared.startswith("bf16[1,"):
+                faults.append((name, k, k_shape, v, shared))
+    faults += [line.split(" = ")[0].strip()
+               for line in compiled.as_text().split("\n")
+               if re.search(r"= bf16\[1,32,8192,64\]\S* broadcast\(", line)
+               and "dimensions={}" not in line]
+    return faults
+
+
+def test_flash_kernels_compile_with_latent_keys_in_parts(one_chip):
+    """The same call with the keys as latent attention's projections write
+    them: q ``[32, 8192, 192]``, the ``[k_nope | v]`` product ``[32, 8192,
+    256]`` read as two 128-wide column blocks, the one rotary key ``[1,
+    8192, 64]`` read by all 32 heads; the three kernels compile within
+    scoped VMEM, ``dkv`` comes back as the product lies and the shared
+    part's gradient as one array."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    q, kv, ks = spec(1, 32, 8192, 192), spec(1, 32, 8192, 256), \
+        spec(1, 1, 8192, 64)
+
+    def loss(q, kv, ks):
+        return jnp.sum(F.flash_attention(q, kv=kv, k_shared=ks, causal=True)
+                       .astype(jnp.float32))
+    before = _flash_calls()
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, ks)
+    assert _flash_calls() == {**before, "parts": before["parts"] + 1}
+    assert [o.shape for o in jax.tree_util.tree_leaves(
+        lowered.out_info)] == [q.shape, kv.shape, ks.shape]
+    text = lowered.as_text()
+    assert all(name in text for name in F.FULL_KERNEL_NAMES)
+    compiled = lowered.compile()
+    assert _custom_calls(compiled) == 3
+    operands = _kernel_operands(compiled)
+    assert sorted(operands) == sorted(F.FULL_KERNEL_NAMES)
+    for (call,) in operands.values():
+        assert [shape for _, shape in call[:4]] == [
+            "bf16[32,8192,192]", "bf16[32,8192,256]", "bf16[32,8192,256]",
+            "bf16[1,8192,64]"]
+    assert "bf16[32,8192,64]" in compiled.as_text()   # the heads' shares
+
+
+def test_latent_attention_hands_the_kernels_its_keys_as_projected(
+        one_chip, monkeypatch):
+    """One ``LatentAttention`` at JoyAI's widths, forward and backward at
+    ``[1, 8192, 2048]`` bfloat16 (float32 masters cast inside): three
+    custom calls, each reading the up-projection's ``[32, 8192, 256]``
+    product twice (its two column blocks: no slice, no assembled
+    ``[32, 8192, 192]`` key) and the one ``[1, 8192, 64]`` rotary key (no
+    broadcast to 32 heads); the dk/dv kernel writes ``[dk_nope | dv]`` as
+    the product lies.  The same layer told to assemble (the parts refused)
+    shows what the check finds."""
+    from deeplearning4j_tpu.nn.conf.input_type import InputType
+    from deeplearning4j_tpu.nn.layers.attention import LatentAttention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = LatentAttention(n_in=2048, n_out=2048, n_heads=32, head_dim=128,
+                            rope_dim=64, v_dim=128, q_rank=1536, kv_rank=512,
+                            rope_theta=32e6, attn_impl="auto")
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0),
+            InputType.recurrent(2048, 8192))["params"]))
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        p = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), p)
+        return jnp.sum(layer.attend(p, x).astype(jnp.float32))
+
+    def compiled():
+        before = _flash_calls()
+        c = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile()
+        return c, {form: n - before[form]
+                   for form, n in _flash_calls().items()}
+    c, calls = compiled()
+    assert calls == {"whole": 0, "parts": 1}
+    assert _custom_calls(c) == 3
+    assert _assembled_keys(c) == []
+    # [dk_nope | dv] as the product lies, and the heads' shares of dk_r
+    assert re.search(r"flash_bwd_dkv\S* = \(bf16\[32,8192,256\]\S*, "
+                     r"bf16\[32,8192,64\]", c.as_text())
+    for (call,) in _kernel_operands(c).values():
+        # k and v: one array, and a product wrote it
+        producer = call[1][0]
+        assert call[2][0] == producer and "convolution" in producer
+    # the control: with the parts refused the layer assembles, as it did
+    monkeypatch.setattr(LatentAttention, "_flash_in_parts",
+                        lambda self, t, mask: False)
+    c, calls = compiled()
+    assert calls == {"whole": 1, "parts": 0}
+    assert len(_assembled_keys(c)) >= 3
+
+
 def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
     """The benchmark's ``joyai-llm-flash-5l`` train step (1 dense + 4
     routed layers and the multi-token-prediction module at published
     widths, 16 of 256 experts, an eighth of the vocabulary, bfloat16,
     ``cache_mode`` none) lowered from shapes through the GRAPH container for
     one described v5e at one row of 8193 ids: each full kernel once a
-    latent-attention layer, six in all, no windowed one, the one head over
+    latent-attention layer, six in all, no windowed one, every call with
+    the keys in parts and nothing in the compiled step that assembles or
+    broadcasts a key for a kernel (PR 39), the one head over
     both streams walked in four chunks with nothing of it rematerialized,
     and arguments and program fit the compiler's limit with room (a peak of
-    13.70 GiB of 15.75, PR 36; 14.27 with the logits whole, PR 35)."""
+    13.66 GiB of 15.75, PR 39; 13.70, PR 36; 14.27 with the logits whole,
+    PR 35)."""
     import os
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -500,11 +643,17 @@ def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
     def batch(shape, dtype):
         return [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)]
     before = _head_chunks().get(("16384", "16160", "4"), 0)
+    calls_before = _flash_calls()
     lowered = held["net"]._get_jitted("train_step").audit_lower(
         (args + (batch((1, 8193), jnp.int32), batch((1, 16384), jnp.int32),
                  None, batch((1, 16384), jnp.float32)), {}))
     assert _head_chunks()[("16384", "16160", "4")] == before + 1
+    # six latent layers, each handing the kernels its keys in parts
+    assert {form: n - calls_before[form]
+            for form, n in _flash_calls().items()} == {"whole": 0,
+                                                       "parts": 6}
     compiled = lowered.compile()
+    assert _assembled_keys(compiled) == []
     assert _head_repeats(compiled) == []
     calls = [line for line in compiled.as_text().split("\n")
              if 'custom_call_target="tpu_custom_call"' in line]

@@ -105,7 +105,9 @@ def test_positions_turn_part_of_a_head_only(seeded):
     p = layer.init(jax.random.PRNGKey(0), InputType.recurrent(32, 16))[
         "params"]
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 32))
-    q, k, v = layer.project(p, x)
+    q, kv, k_r = layer.project(p, x)
+    assert kv.shape == (1, 4, 16, 16) and k_r.shape == (1, 1, 16, 4)
+    k, v = layer.whole_keys(kv, k_r)
     assert q.shape == k.shape == (1, 4, 16, 12) and v.shape == (1, 4, 16, 8)
     # the one rotary key is every head's
     np.testing.assert_array_equal(k[0, 0, :, 8:], k[0, 3, :, 8:])
@@ -183,6 +185,73 @@ def test_the_kernels_get_unpadded_widths(monkeypatch):
     assert seen["shapes"] == ((1, 4, 128, 128), (1, 4, 128, 128),
                               (1, 4, 128, 64))
     assert out.shape == (1, 128, 32)
+
+
+@pytest.mark.parametrize("t", [256, 512])
+def test_keys_in_parts_and_assembled_keys_are_one_layer(monkeypatch, t):
+    """At widths the kernels take in parts (a head's own part of the key
+    and its value both 128 wide, beside a 64-wide rotary part) the flash
+    path hands them ``project``'s three arrays as they are and the
+    reference path assembles ``k`` and ``v`` from the same three: the same
+    output and the same gradient of every parameter and of the input,
+    float32 on both sides, so the two ways of reading the keys cannot
+    drift.  At 512 positions the grid has blocks below, on and above the
+    diagonal."""
+    monkeypatch.setattr(F, "_BLOCK_ROWS", 256)
+    seen = []
+    real = F.flash_attention
+
+    def spy(q, k=None, v=None, **kw):
+        seen.append({n: a.shape for n, a in
+                     dict(q=q, k=k, v=v, kv=kw.get("kv"),
+                          k_shared=kw.get("k_shared")).items()
+                     if a is not None})
+        return real(q, k, v, interpret=True, **kw)
+    monkeypatch.setattr(F, "flash_attention", spy)
+    layers = {impl: LatentAttention(
+        n_in=32, n_out=32, n_heads=2, head_dim=128, rope_dim=64, v_dim=128,
+        q_rank=24, kv_rank=16, rope_theta=32e6, attn_impl=impl,
+        weight_init="xavier") for impl in ("flash", "reference")}
+    p = layers["flash"].init(jax.random.PRNGKey(0),
+                             InputType.recurrent(32, t))["params"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, t, 32))
+
+    def loss(impl):
+        return lambda p, x: jnp.sum(jnp.sin(layers[impl].attend(p, x)))
+    out = layers["flash"].attend(p, x)
+    assert seen == [{"q": (1, 2, t, 192), "kv": (1, 2, t, 256),
+                     "k_shared": (1, 1, t, 64)}]
+    want = layers["reference"].attend(p, x)
+    np.testing.assert_allclose(out, want, atol=5e-5 * float(
+        jnp.max(jnp.abs(want))))
+    got_p, got_x = jax.grad(loss("flash"), argnums=(0, 1))(p, x)
+    want_p, want_x = jax.grad(loss("reference"), argnums=(0, 1))(p, x)
+    assert len(seen) == 2                      # no call with whole keys
+    for name, g, w in [("x", got_x, want_x)] + [
+            (n, got_p[n], want_p[n]) for n in sorted(p)]:
+        assert float(jnp.max(jnp.abs(g - w))) <= 5e-5 * float(
+            jnp.max(jnp.abs(w))), name
+
+
+def test_widths_the_kernels_refuse_in_parts_reach_them_assembled(monkeypatch):
+    """A head's own part of the key 64 wide is no column block of a
+    128-lane tile: ``flash_blocks`` refuses the parts and the layer
+    assembles ``k`` and ``v`` as it always did."""
+    with pytest.raises(ValueError, match="in parts"):
+        F.flash_blocks(128, 128, 128, d_v=64, d_shared=64)
+    layer, _, mine, _ = _layer_and_theirs("flash")
+    assert not layer._flash_in_parts(128, None)
+    assert LatentAttention(n_heads=2, head_dim=128, rope_dim=64, v_dim=128,
+                           attn_impl="flash")._flash_in_parts(256, None)
+    # a scanned run of latent blocks keeps the keys in either form
+    names = TransformerBlock(attention="latent").SAVED_NAMES
+    assert names[:5] == ("attn_q", "attn_kv", "attn_k_shared", "attn_k",
+                         "attn_v")
+    assert "attn_kv" not in TransformerBlock().SAVED_NAMES
+    # a key mask is the reference's; 'auto' off the TPU too
+    assert not LatentAttention(
+        n_heads=2, head_dim=128, rope_dim=64, v_dim=128,
+        attn_impl="auto")._flash_in_parts(256, None)
 
 
 # ----------------------------------------- the graph against the reference
