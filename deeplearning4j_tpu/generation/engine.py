@@ -325,6 +325,11 @@ class GenerationEngine:
         were already migrated or failed by then."""
         sig = model._topology_sig()
         if self.ring is None or self._ring_sig != sig:
+            if getattr(model.conf, "looped", lambda: None)():
+                raise ValueError(
+                    "generation cannot run through a looped range: a K/V "
+                    "cache a pass, and the exit by threshold while "
+                    "decoding, are not written")
             for lc in model.conf.layers:
                 if getattr(lc, "AUX_LOSS", False):
                     raise ValueError(

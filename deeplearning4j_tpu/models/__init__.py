@@ -2,13 +2,14 @@
 import numpy as np
 
 from .zoo import (ALL_MODELS, AlexNet, EvaByteLM, FaceNetNN4Small2, GoogLeNet,
-                  InceptionResNetV1, JoyAIFlashLM, LeNet, ResNet50,
+                  InceptionResNetV1, JoyAIFlashLM, LeNet, OuroLM, ResNet50,
                   SimpleCNN, ModelSelector, TextGenerationLSTM, TransformerLM, TrinityLM,
                   VGG16, VGG19, ZooModel)
 
 __all__ = [
     "ALL_MODELS", "AlexNet", "EvaByteLM", "FaceNetNN4Small2", "GoogLeNet",
-    "InceptionResNetV1", "JoyAIFlashLM", "LeNet", "ResNet50", "SimpleCNN",
+    "InceptionResNetV1", "JoyAIFlashLM", "LeNet", "OuroLM", "ResNet50",
+    "SimpleCNN",
     "ModelSelector", "TextGenerationLSTM", "TransformerLM", "TrinityLM",
     "VGG16", "VGG19", "ZooModel",
     "available_bench_model", "flagship_entry_model", "generate_tokens",

@@ -914,6 +914,78 @@ class JoyAIFlashLM(ZooModel):
 ALL_MODELS.append(JoyAIFlashLM)
 
 
+@dataclass
+class OuroLM(ZooModel):
+    """Ouro's architecture (https://huggingface.co/ByteDance/Ouro-2.6B,
+    ``model_type`` ``ouro``; "Scaling Latent Reasoning via Looped Language
+    Models", arXiv:2510.25741): a dense decoder whose whole stack of layers
+    is walked ``passes`` times **on one set of weights**.  Bias-free blocks
+    with four gain-only RMSNorms each (before and after each half:
+    ``x + N2(Attn(N1 x))``, ``x + N4(MLP(N3 x))``), ``n_heads`` heads of
+    ``head_dim`` with as many K/V heads, rotary positions over the whole
+    head (the same positions in every pass), a gated SiLU MLP; the final
+    RMSNorm closes every pass and its output is what the next pass reads.
+    The embedding is not scaled and the head is untied.
+
+    The blocks and the final norm are one looped range of the list
+    (``ListBuilder.loop``: parameters, Adam state and checkpoints hold it
+    once, a weight's gradient is the sum over the passes, float32 under a
+    lower ``compute_dtype``); the head is an ``ExitGateOutputLayer``: every
+    pass's normed state is scored, a learned gate says how much of a
+    position's prediction exits after each pass, and the loss is the
+    exit-weighted cross-entropy less ``exit_beta`` times the entropy of the
+    exit distribution (the paper's stage I).  ``output()`` is the last
+    pass's distribution (an exit threshold of 1).  Integer targets ``[b,
+    t]``.  Trains and runs forward; generation through the loop (a K/V
+    cache a pass) is not written.  The defaults are the published 2.6B
+    model's."""
+    model_type = "rnn"
+    vocab_size: int = 49152
+    seq_len: int = 65536
+    embed: int = 2048
+    n_layers: int = 48
+    n_heads: int = 16
+    head_dim: int = 128
+    ffn_hidden: int = 5632
+    passes: int = 4
+    exit_beta: float = 0.1
+    rope_theta: float = 1e6
+    eps: float = 1e-6
+    attn_impl: str = "auto"
+    cache_mode: str = "none"   # 'remat': recompute each layer-pass
+
+    def init(self):
+        from ..nn.layers.attention import RMSNormLayer, TransformerBlock
+        from ..nn.layers.feedforward import EmbeddingSequenceLayer
+        from ..nn.layers.recurrent import ExitGateOutputLayer
+        b = (self._builder()
+             .updater(self.updater or Adam(learning_rate=3e-4))
+             .weight_init("xavier")
+             .cache_mode(self.cache_mode)
+             .list()
+             .layer(EmbeddingSequenceLayer(n_out=self.embed)))
+        for _ in range(self.n_layers):
+            b = b.layer(TransformerBlock(
+                n_heads=self.n_heads, head_dim=self.head_dim, causal=True,
+                attn_impl=self.attn_impl, eps=self.eps, norm="rms",
+                post_norm=True, gated=True, has_bias=False,
+                ffn_hidden=self.ffn_hidden, positions="rotary",
+                rope_theta=self.rope_theta))
+        conf = (b.layer(RMSNormLayer(eps=self.eps))
+                .loop(1, self.n_layers + 2, self.passes)
+                .layer(ExitGateOutputLayer(
+                    n_out=self.vocab_size, has_bias=False,
+                    exits=self.passes, exit_beta=self.exit_beta))
+                .set_input_type(InputType.recurrent(self.vocab_size,
+                                                    self.seq_len))
+                .build())
+        from ..nn.multilayer import MultiLayerNetwork
+        return MultiLayerNetwork(conf).init()
+
+
+ALL_MODELS.append(OuroLM)
+
+
 class ModelSelector:
     """Select zoo models by name/type (reference
     ``deeplearning4j-zoo/.../ModelSelector.java``: select(ZooType) returns a

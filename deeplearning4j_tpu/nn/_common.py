@@ -26,6 +26,7 @@ from . import sparse as _sparse
 from .dispatch import DispatchWindow
 from .layers.base import BaseLayerConf, LayerConf
 from .layers.moe import publish_expert_tokens
+from .layers.recurrent import publish_exit_mass
 from ..data.pipeline import ETL_BUCKETS as _ETL_BUCKETS
 from ..observability.clock import monotonic_s, wall_s
 from ..observability.registry import default_registry
@@ -740,6 +741,7 @@ def fit_batches(model, batches_factory, epochs: int, prepare, step, *,
             with span("dl4j.sync"):
                 model._score = float(model._score)
                 publish_expert_tokens(model)
+                publish_exit_mass(model)
             if prof is not None:
                 prof.materialized()
             for lst in model.listeners:
@@ -796,6 +798,7 @@ def fit_batches(model, batches_factory, epochs: int, prepare, step, *,
     with span("dl4j.sync"):
         model._score = float(model._score)
         publish_expert_tokens(model)
+        publish_exit_mass(model)
     if obs and steady_s > 0:
         # steady-state throughput: the compile-dominated first step
         # is excluded (same convention as utils/benchmarks.py)

@@ -228,6 +228,11 @@ class ComputationGraph:
     """DAG network: init → fit/output/score/evaluate."""
 
     def __init__(self, conf: ComputationGraphConfiguration):
+        if getattr(conf, "loop", None):
+            raise ValueError(
+                "ComputationGraph cannot walk a looped range: a vertex "
+                "fed by one that comes after it is not written; a looped "
+                "list runs as a MultiLayerNetwork")
         conf.resolve()
         self.conf = conf
         self.params: Dict[str, Any] = {}

@@ -97,6 +97,11 @@ class MultiLayerConfiguration:
     tbptt_back_length: int = 20
     defaults: Dict[str, Any] = field(default_factory=dict)
     seed: int = 12345
+    # ``[first, stop, passes]``: the layers ``first .. stop - 1`` are walked
+    # ``passes`` times on their one set of parameters, each pass reading the
+    # last one's output; the passes' outputs go on joined in time
+    # (``ListBuilder.loop``)
+    loop: Optional[List[int]] = None
     # resolved by build():
     layer_input_types: List[InputType] = field(default_factory=list)
 
@@ -121,6 +126,33 @@ class MultiLayerConfiguration:
     def preprocessor(self, i: int) -> Optional[InputPreProcessor]:
         return self.input_preprocessors.get(str(i))
 
+    def looped(self) -> Optional[tuple]:
+        """``(first, stop, passes)`` of the looped range, or ``None`` where
+        the list is walked once (one pass is no loop)."""
+        if not self.loop or int(self.loop[2]) == 1:
+            return None
+        return tuple(int(v) for v in self.loop)
+
+    def _check_loop(self) -> None:
+        """What a looped range cannot hold is refused here, by name."""
+        first, stop, passes = (int(v) for v in self.loop)
+        n = len(self.layers)
+        if not (0 <= first < stop < n and passes >= 1):
+            raise ValueError(
+                f"loop({first}, {stop}, {passes}): the range has to lie "
+                f"inside the {n} layers and before the last (the passes' "
+                "outputs need a layer to go to), with at least one pass")
+        if self.backprop_type == "tbptt":
+            raise ValueError(
+                "a looped range cannot train by truncated BPTT: a recurrent "
+                "carry a pass is not written; use backprop_type 'standard'")
+        for lc in self.layers[first:stop]:
+            if getattr(lc, "AUX_LOSS", False):
+                raise ValueError(
+                    f"layer '{lc.name}' threads an auxiliary loss "
+                    "(AUX_LOSS) and cannot lie in a looped range: one "
+                    "state entry cannot hold a term a pass")
+
     def resolve(self) -> None:
         """Apply defaults, insert preprocessors, infer n_in, record itypes."""
         for lc in self.layers:
@@ -129,9 +161,24 @@ class MultiLayerConfiguration:
             if hasattr(lc, "apply_global_defaults"):
                 lc.apply_global_defaults(self.defaults)
             validate_layer_names(lc)
+        if self.loop:
+            self._check_loop()
+        first, stop, passes = self.looped() or (None, None, 1)
         self.layer_input_types = []
         itype = self.input_type
         for i, lc in enumerate(self.layers):
+            if i == stop and itype is not None:
+                # the passes' outputs, joined in time
+                before = self.layer_input_types[first]
+                if before.kind != "rnn" or itype != before:
+                    raise ValueError(
+                        f"loop({first}, {stop}, {passes}): a pass has to "
+                        "hand the next what it took, [batch, time, "
+                        f"features]; the range takes {before} and gives "
+                        f"{itype}")
+                itype = InputType.recurrent(
+                    itype.size, itype.timesteps * passes
+                    if itype.timesteps > 0 else -1)
             if itype is not None:
                 if str(i) not in self.input_preprocessors:
                     pp = _auto_preprocessor(itype, lc)
@@ -151,6 +198,12 @@ class MultiLayerConfiguration:
                     itype = lc.output_type(itype)
                 except Exception:
                     itype = None
+        if stop is not None and any(self.preprocessor(i) is not None
+                                    for i in range(first, stop)):
+            raise ValueError(
+                f"loop({first}, {stop}, {passes}): a preprocessor inside "
+                "the range (or before its first layer) would run every "
+                "pass; start the range after it")
 
 
 class ListBuilder:
@@ -165,6 +218,7 @@ class ListBuilder:
         self._backprop_type = "standard"
         self._tbptt_fwd = 20
         self._tbptt_back = 20
+        self._loop: Optional[List[int]] = None
 
     def layer(self, conf: LayerConf, index: Optional[int] = None) -> "ListBuilder":
         """Append, or place at ``index`` (reference ListBuilder.layer(int, Layer)
@@ -194,6 +248,17 @@ class ListBuilder:
         self._tbptt_back = back
         return self
 
+    def loop(self, first: int, stop: int, passes: int) -> "ListBuilder":
+        """Walk the layers ``first .. stop - 1`` ``passes`` times on their
+        one set of parameters (a looped, weight-shared, recurrent-depth
+        stack): each pass reads the last one's output, and the passes'
+        outputs go on to layer ``stop`` joined in time, pass-major, ``[b,
+        passes * t, d]``.  The range's parameters, state and optimizer
+        state exist once; a weight's gradient is the sum over the passes
+        (``nn/multilayer._Walk.loop``)."""
+        self._loop = [int(first), int(stop), int(passes)]
+        return self
+
     def build(self) -> MultiLayerConfiguration:
         conf = MultiLayerConfiguration(
             layers=self._layers,
@@ -204,6 +269,7 @@ class ListBuilder:
             tbptt_back_length=self._tbptt_back,
             defaults=dict(self._defaults),
             seed=self._seed,
+            loop=self._loop,
         )
         conf.resolve()
         return conf

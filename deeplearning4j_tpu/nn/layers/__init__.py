@@ -15,14 +15,15 @@ from .normalization import BatchNormalization, LocalResponseNormalization
 from .objdetect import Yolo2OutputLayer
 from .pooling import GlobalPoolingLayer
 from .pretrain import AutoEncoder, RBM, VariationalAutoencoder
-from .recurrent import (Bidirectional, GravesBidirectionalLSTM, GravesLSTM,
-                        LastTimeStep, LSTM, RnnOutputLayer, SimpleRnn)
+from .recurrent import (Bidirectional, ExitGateOutputLayer,
+                        GravesBidirectionalLSTM, GravesLSTM, LastTimeStep,
+                        LSTM, RnnOutputLayer, SimpleRnn)
 
 __all__ = [
     "ActivationLayer", "AutoEncoder", "BaseLayerConf", "BatchNormalization",
     "Bidirectional", "CenterLossOutputLayer", "Convolution1DLayer",
     "ConvolutionLayer", "DenseLayer", "DropoutLayer", "EmbeddingLayer",
-    "EmbeddingSequenceLayer",
+    "EmbeddingSequenceLayer", "ExitGateOutputLayer",
     "FrozenLayer", "GlobalPoolingLayer", "GravesBidirectionalLSTM",
     "GravesLSTM", "LastTimeStep", "LatentAttention", "LayerConf",
     "LayerNormLayer",

@@ -970,6 +970,13 @@ class TransformerBlock(BaseLayerConf):
     each norm's output goes to the projections in their own type.  The
     defaults trace the program they always did.
 
+    A block may lie in a looped range of the list (``ListBuilder.loop``:
+    ``OuroLM`` walks its blocks four times on one set of weights): it is
+    then applied once a pass to the last pass's output, with the same
+    positions in every pass, its parameters exist once and their gradient
+    is the sum over the passes; ``AUX_LOSS`` blocks (``moe_experts`` on the
+    top-1 path) are refused there at build time.
+
     The KV-cache path (``apply_with_carry`` -> ``attend_cached``) serves the
     GPT-2 block alone: it raises ``NotImplementedError`` for rotary
     positions, sliding and EVA attention, grouped K/V heads, q/k norm, the

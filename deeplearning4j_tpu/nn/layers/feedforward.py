@@ -120,6 +120,19 @@ class OutputLayer(DenseLayer):
             return None
         return _losses.head_rows_per_chunk(*labels.shape, self.n_out)
 
+    def _count_chunks(self, rows: int, chunks: int) -> None:
+        """One head traced with its logits formed chunk by chunk:
+        trace-time, like ``mla_layers_traced_total``."""
+        from ...observability.registry import default_registry
+        reg = default_registry()
+        if reg.enabled:
+            reg.counter("head_chunks_traced_total",
+                        "Output layers traced with their logits formed "
+                        "chunk by chunk, by the logits' rows and "
+                        "classes and the number of chunks",
+                        ("rows", "classes", "chunks")).labels(
+                            str(rows), str(self.n_out), str(chunks)).inc()
+
     def compute_loss(self, variables, x, labels, *, train=False, key=None,
                      mask=None, average=True):
         """The head's score.  As a rule the whole ``[.., n_out]``
@@ -131,17 +144,7 @@ class OutputLayer(DenseLayer):
         act = self.resolved("activation", "identity")
         rows = self._chunk_rows(x, labels, mask, act)
         if rows is not None:
-            from ...observability.registry import default_registry
-            reg = default_registry()
-            if reg.enabled:
-                # trace-time, like mla_layers_traced_total
-                reg.counter("head_chunks_traced_total",
-                            "Output layers traced with their logits formed "
-                            "chunk by chunk, by the logits' rows and "
-                            "classes and the number of chunks",
-                            ("rows", "classes", "chunks")).labels(
-                                str(labels.size), str(self.n_out),
-                                str(labels.shape[1] // rows)).inc()
+            self._count_chunks(labels.size, labels.shape[1] // rows)
             x, params = self._operands(variables, x, train, key)
             return _losses.chunked_softmax_xent(
                 x, params["W"], params["b"] if self.has_bias else None, labels,

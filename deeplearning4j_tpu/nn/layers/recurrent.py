@@ -343,6 +343,142 @@ class RnnOutputLayer(OutputLayer):
 
 @register_serde
 @dataclass
+class ExitGateOutputLayer(RnnOutputLayer):
+    """The head of a looped stack with a learned exit after every pass
+    ("Scaling Latent Reasoning via Looped Language Models",
+    arXiv:2510.25741, section 3).  The input is the passes' normed states
+    joined in time, pass-major, ``[b, exits * t, d]`` (what a
+    ``ListBuilder.loop`` range hands on); labels are integer ids ``[b, t]``
+    and a label mask ``[b, t]``.
+
+    Beside the head ``W [d, n_out]`` (and ``b``) the layer holds a gate
+    ``w_g [d, 1]``, ``b_g [1]``: after pass ``r`` a position would exit
+    with ``lambda_r = sigmoid(h_r w_g + b_g)``, so it exits at ``r`` with
+    ``p_r = lambda_r prod_{j<r} (1 - lambda_j)`` and the last pass takes
+    what is left, ``p_R = prod_{j<R} (1 - lambda_j)``.  The loss of a
+    position is ``sum_r p_r CE(softmax(h_r W), y) - exit_beta H(p)``, every
+    exit scored every step and the gate trained through ``p``; positions
+    are summed and rows averaged as ``sparse_mcxent`` does.  The logits are
+    never whole: ``p`` times the label mask's weights is the ``weights`` of
+    ``losses.chunked_softmax_xent`` over the ``exits * t`` rows, whose
+    cotangent (each row's cross-entropy) is the gate's gradient.
+
+    ``apply`` gives the last pass's distribution, ``[b, t, n_out]``: what
+    inference with an exit threshold of 1 returns.  The state carries
+    ``exit_mass [exits]``, the mean of ``p_r`` over the last step's kept
+    positions (gauge ``loop_exit_mass{pass}``, :func:`publish_exit_mass`)."""
+    _BIAS_PARAMS = ("b", "b_g")
+    loss: str = "sparse_mcxent"
+    activation: Optional[str] = "softmax"
+    exits: int = 1
+    exit_beta: float = 0.0
+
+    def output_type(self, itype: InputType) -> InputType:
+        t = itype.timesteps
+        return InputType.recurrent(self.n_out,
+                                   t // self.exits if t > 0 else -1)
+
+    def init(self, key, itype):
+        if str(self.loss).lower() != "sparse_mcxent" or \
+                str(self.resolved("activation", "")).lower() != "softmax" \
+                or self.pred_heads != 1 or self.loss_weights is not None:
+            raise ValueError(
+                f"layer '{self.name}': the exit-gated head is a softmax "
+                "over integer labels (sparse_mcxent), one head, no column "
+                "weights")
+        out = super().init(key, itype)
+        out["params"]["w_g"] = self.make_weight(jax.random.fold_in(key, 1),
+                                                (self.n_in, 1))
+        out["params"]["b_g"] = self.make_bias((1,))
+        out["state"] = {"exit_mass": jnp.zeros((self.exits,), jnp.float32)}
+        return out
+
+    def pre_output(self, variables, x, *, train=False, key=None):
+        # the last pass's states
+        last = x[:, x.shape[1] - x.shape[1] // self.exits:]
+        return super().pre_output(variables, last, train=train, key=key)
+
+    def exit_distribution(self, params, x):
+        """``(p, log p)``, ``[b, exits, t]`` float32, from the states ``x
+        [b, exits * t, d]``: the gate's product in the operands' type,
+        summed in float32; everything after it float32, in logarithms
+        (``log(1 - sigmoid(g)) = log_sigmoid(-g)``)."""
+        b, rt, _ = x.shape
+        g = jnp.einsum("btd,do->bto", x, params["w_g"],
+                       preferred_element_type=jnp.float32)[..., 0]
+        g = (g + params["b_g"].astype(jnp.float32)).reshape(
+            b, self.exits, rt // self.exits)
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=1)
+        # exits before the last: log lambda_r + sum_{j<r} log(1 - lambda_j);
+        # the last: sum_{j<R} log(1 - lambda_j)
+        before = jnp.concatenate([jnp.zeros_like(stay[:, :1]),
+                                  stay[:, :-1]], axis=1)
+        logp = jnp.concatenate(
+            [jax.nn.log_sigmoid(g[:, :-1]) + before[:, :-1],
+             before[:, -1:]], axis=1)
+        return jnp.exp(logp), logp
+
+    def loss_and_state(self, variables, x, labels, *, train=False, key=None,
+                       mask=None):
+        from .. import losses as _losses
+        b, rt, _ = x.shape
+        t = rt // self.exits
+        if mask is not None and mask.shape == (b, rt):
+            # a feature mask that came through the loop, repeated a pass
+            mask = mask[:, :t]
+        if labels.shape != (b, t) or (mask is not None
+                                      and mask.shape != (b, t)):
+            raise ValueError(
+                f"layer '{self.name}': integer labels (and a label mask) "
+                f"[{b}, {t}] for {self.exits} exits over {rt} rows, got "
+                f"{labels.shape}" + ("" if mask is None
+                                     else f" and {mask.shape}"))
+        x, params = self._operands(variables, x, train, key)
+        w = _losses.position_weights(mask, (b, t))          # [b, t]
+        with jax.named_scope("exit_gate"):
+            p, logp = self.exit_distribution(params, x)
+            entropy = -jnp.sum(p * logp, axis=1)                # [b, t]
+            weights = (p * w[:, None, :]).reshape(b, rt)
+            kept = jnp.sum((w > 0).astype(jnp.float32))
+            mass = jnp.sum(p * (w > 0)[:, None, :], axis=(0, 2)) \
+                / jnp.maximum(kept, 1.0)
+        rows = _losses.head_rows_per_chunk(b, rt, self.n_out) or rt
+        if rows < rt:
+            self._count_chunks(b * rt, rt // rows)
+        xent = _losses.chunked_softmax_xent(
+            x, params["W"], params["b"] if self.has_bias else None,
+            jnp.tile(labels, (1, self.exits)), weights, rows)
+        with jax.named_scope("exit_gate"):
+            loss = xent - self.exit_beta * jnp.sum(w * entropy)
+        return loss, {"exit_mass": jax.lax.stop_gradient(mass)}
+
+    def compute_loss(self, variables, x, labels, *, train=False, key=None,
+                     mask=None, average=True):
+        return self.loss_and_state(variables, x, labels, train=train,
+                                   key=key, mask=mask)[0]
+
+
+def publish_exit_mass(model) -> None:
+    """Gauges ``loop_exit_mass{pass}`` from the state of a head that
+    threads ``exit_mass``: called where ``fit`` has just read the loss, as
+    ``moe.publish_expert_tokens`` is."""
+    from ...observability.registry import default_registry
+    reg = default_registry()
+    masses = [st["exit_mass"]
+              for st in (getattr(model, "state", None) or {}).values()
+              if isinstance(st, dict) and "exit_mass" in st]
+    if not masses or not reg.enabled:
+        return
+    gauge = reg.gauge("loop_exit_mass",
+                      "Mean over the last step's positions of the share of "
+                      "a token's prediction that exits after each pass of "
+                      "a looped range", ("pass",))
+    for r, v in enumerate(jax.device_get(masses[-1])):
+        gauge.labels(str(r + 1)).set(float(v))
+
+
+@register_serde
+@dataclass
 class LastTimeStep(LayerConf):
     """Wrapper: keep only the last (mask-aware) time step of a recurrent
     layer's output → FF (reference ``recurrent/LastTimeStep`` /

@@ -31,7 +31,8 @@ import optax
 
 from . import precision as _precision
 from . import scan_layers as _scan_layers
-from ._common import (_cast_act, _on_device, build_train_step, build_tx,
+from ._common import (_cast_act, _cast_floats, _on_device,
+                      build_train_step, build_tx,
                       compute_dtypes, finish_step, fit_batches,
                       fit_on_device_epochs, hyperparam_conf, placed)
 from .compile_cache import shared_jit, topology_signature
@@ -76,72 +77,176 @@ def _stack_forward(conf, params, state, x, *, train: bool, key, mask=None,
     body instead of N (``nn/scan_layers``); everything else walks
     unrolled, bit-identically to the pre-scan code.
     """
-    layers = conf.layers
-    n = len(layers) if to_layer is None else to_layer
-    remat = bool(train and conf.defaults.get("cache_mode") == "remat")
-    runs = dict(_scan_layers.scan_runs(
-        conf, n, mask_present=mask is not None,
-        carries_present=carries is not None, collect=collect,
-        policy=precision))
+    n = len(conf.layers) if to_layer is None else to_layer
+    walk = _Walk(conf, train=train, collect=collect, carries=carries,
+                 precision=precision)
     new_state = dict(state)
-    acts = []
-    h = x
-    i = 0
-    while i < n:
-        lc = layers[i]
-        pp = conf.preprocessor(i)
-        if pp is not None:
-            h = pp.pre_process(h, mask)
-            if mask is not None:
-                itype = conf.layer_input_types[i] if conf.layer_input_types \
-                    else None
-                mask = pp.feed_forward_mask(mask, itype)
-        if precision is not None:
-            h = _cast_act(h, precision.input_dtype(lc))
-        stop = runs.get(i)
-        if stop is not None:
-            # homogeneous run [i, stop): ONE traced body under lax.scan
-            h, run_states = _scan_layers.run_scan(
-                lc, [params.get(f"layer_{j}", {}) for j in range(i, stop)],
-                [state.get(f"layer_{j}", {}) for j in range(i, stop)],
-                h, key, i, train=train, mask=mask, remat=remat)
-            for off, ls in enumerate(run_states):
-                new_state[f"layer_{i + off}"] = ls
-            i = stop
-            continue
-        lkey = jax.random.fold_in(key, i) if key is not None else None
-        variables = {"params": params.get(f"layer_{i}", {}),
-                     "state": state.get(f"layer_{i}", {})}
-        lname = f"layer_{i}"
-        # one scope per layer, named by its conf's class: the profile of
-        # a step splits by layer kind (metadata only; the program is the
-        # same)
-        with jax.named_scope(type(lc).__name__):
-            if carries is not None and getattr(lc, "HAS_CARRY", False):
-                h, new_carry = lc.apply_with_carry(
-                    variables, h, carries.get(lname), train=train,
-                    key=lkey, mask=mask)
-                carries[lname] = new_carry
-                lstate = variables.get("state", {})
-            elif remat:
-                # rematerialize per-layer activations on the backward pass
-                # (the WorkspaceMode/CacheMode role: trade FLOPs for HBM)
-                def _apply(vv, hh, kk, mm, _lc=lc):
-                    return _lc.apply(vv, hh, train=True, key=kk, mask=mm)
-                h, lstate = jax.checkpoint(_apply)(variables, h, lkey, mask)
-            else:
-                h, lstate = lc.apply(variables, h, train=train, key=lkey,
-                                     mask=mask)
-        new_state[lname] = lstate
-        if mask is not None:
-            mask = lc.feed_forward_mask(mask, None)
-        if collect:
-            acts.append(h)
-        i += 1
-    out = acts if collect else h
+    loop = conf.looped()
+    if loop is None or loop[0] >= n:
+        h, mask = walk.layers(params, state, new_state, x, mask, key, 0, n)
+    else:
+        first, stop, _ = loop
+        refused = (
+            "collecting every layer's activations (feed_forward)"
+            if collect else
+            "with a recurrent carry (tBPTT, rnn_time_step, generation: a "
+            "K/V cache a pass is not written)" if carries is not None else
+            f"up to layer {n}, inside it" if n < stop else None)
+        if refused:
+            raise ValueError(f"a looped range cannot be walked {refused}: "
+                             "each layer of the range has an output a pass")
+        h, mask = walk.layers(params, state, new_state, x, mask, key, 0,
+                              first)
+        h, mask = walk.loop(params, state, new_state, h, mask, key)
+        h, mask = walk.layers(params, state, new_state, h, mask, key, stop,
+                              n)
+    out = walk.acts if collect else h
     if return_mask:
         return out, new_state, mask
     return out, new_state
+
+
+class _Walk:
+    """One trace of the layer stack: what every stretch of it shares (the
+    configuration and the mode), the walk of a stretch (``layers``) and
+    of the looped range (``loop``)."""
+
+    def __init__(self, conf, *, train, collect, carries, precision):
+        self.conf, self.train, self.collect = conf, train, collect
+        self.carries, self.precision = carries, precision
+        self.remat = bool(train
+                          and conf.defaults.get("cache_mode") == "remat")
+        self.acts = []
+
+    def layers(self, params, state, new_state, h, mask, key, lo: int,
+               hi: int, passes: int = 1):
+        """Walk ``conf.layers[lo:hi]`` from ``h``, reading ``state`` and
+        writing ``new_state``; returns ``(h, mask)``.  ``passes``: how
+        often the stretch is walked a step (the looped range's count, for
+        what a scanned run's stacks take)."""
+        conf, train = self.conf, self.train
+        layers, precision, carries = conf.layers, self.precision, self.carries
+        runs = dict(_scan_layers.scan_runs(
+            conf, hi, mask_present=mask is not None,
+            carries_present=carries is not None, collect=self.collect,
+            policy=precision, lo=lo))
+        i = lo
+        while i < hi:
+            lc = layers[i]
+            pp = conf.preprocessor(i)
+            if pp is not None:
+                h = pp.pre_process(h, mask)
+                if mask is not None:
+                    itype = conf.layer_input_types[i] \
+                        if conf.layer_input_types else None
+                    mask = pp.feed_forward_mask(mask, itype)
+            if precision is not None:
+                h = _cast_act(h, precision.input_dtype(lc))
+            stop = runs.get(i)
+            if stop is not None:
+                # homogeneous run [i, stop): ONE traced body under lax.scan
+                h, run_states = _scan_layers.run_scan(
+                    lc, [params.get(f"layer_{j}", {})
+                         for j in range(i, stop)],
+                    [state.get(f"layer_{j}", {}) for j in range(i, stop)],
+                    h, key, i, train=train, mask=mask, remat=self.remat,
+                    passes=passes)
+                for off, ls in enumerate(run_states):
+                    new_state[f"layer_{i + off}"] = ls
+                i = stop
+                continue
+            lkey = jax.random.fold_in(key, i) if key is not None else None
+            variables = {"params": params.get(f"layer_{i}", {}),
+                         "state": state.get(f"layer_{i}", {})}
+            lname = f"layer_{i}"
+            # one scope per layer, named by its conf's class: the profile
+            # of a step splits by layer kind (metadata only; the program
+            # is the same)
+            with jax.named_scope(type(lc).__name__):
+                if carries is not None and getattr(lc, "HAS_CARRY", False):
+                    h, new_carry = lc.apply_with_carry(
+                        variables, h, carries.get(lname), train=train,
+                        key=lkey, mask=mask)
+                    carries[lname] = new_carry
+                    lstate = variables.get("state", {})
+                elif self.remat:
+                    # rematerialize per-layer activations on the backward
+                    # pass (the WorkspaceMode/CacheMode role: trade FLOPs
+                    # for HBM)
+                    def _apply(vv, hh, kk, mm, _lc=lc):
+                        return _lc.apply(vv, hh, train=True, key=kk, mask=mm)
+                    h, lstate = jax.checkpoint(_apply)(variables, h, lkey,
+                                                       mask)
+                else:
+                    h, lstate = lc.apply(variables, h, train=train, key=lkey,
+                                         mask=mask)
+            new_state[lname] = lstate
+            if mask is not None:
+                mask = lc.feed_forward_mask(mask, None)
+            if self.collect:
+                self.acts.append(h)
+            i += 1
+        return h, mask
+
+    def loop(self, params, state, new_state, h, mask, key):
+        """The looped range ``[first, stop)``, ``passes`` times on its one
+        set of parameters: an outer ``lax.scan`` over the passes whose
+        body is the walk of the range (its scanned run of identical
+        blocks, then the layers that close a pass); each pass reads the
+        last one's output, and the outputs leave joined in time,
+        pass-major, ``[b, passes * t, d]`` (a mask is repeated to match).
+
+        The range's parameters come in as the step holds them (under a
+        lower-precision policy: the float32 masters, which
+        ``_build_train_step`` leaves uncast for this range) and are cast
+        inside the pass, so the loop's sum of a weight's gradient over the
+        passes is a float32 sum.  The range's state is carried from pass
+        to pass; the key of pass ``r`` is ``fold_in(key, n_layers + r)``.
+        With scanning off (``DL4J_TPU_SCAN_LAYERS=0``,
+        ``.scan_layers(False)``) the passes are a Python loop."""
+        conf, precision = self.conf, self.precision
+        first, stop, passes = conf.looped()
+        names = [f"layer_{i}" for i in range(first, stop)]
+        masters = {k: params[k] for k in names if k in params}
+        # as the step's own cast of the other layers (``build_train_step``)
+        casts = {} if precision is None else {
+            k: dt for k in masters if (dt := precision.layer_dtype(
+                conf.layers[int(k[6:])])) not in (None, "float32")}
+
+        def one_pass(h_in, range_state, pass_key):
+            p = {k: _cast_floats(v, casts[k]) if k in casts else v
+                 for k, v in masters.items()}
+            written = dict(range_state)
+            h_out, _ = self.layers(p, range_state, written, h_in, mask,
+                                   pass_key, first, stop, passes=passes)
+            return h_out.astype(h_in.dtype), written
+
+        n_all = len(conf.layers)
+        keys = None if key is None else [
+            jax.random.fold_in(key, n_all + r) for r in range(passes)]
+        range_state = {k: state.get(k, {}) for k in names}
+        with jax.named_scope("loop"):
+            if _scan_layers.scanning(conf):
+                def body(carry, pass_key):
+                    h_out, written = one_pass(*carry, pass_key)
+                    return (h_out, written), h_out
+                (_, range_state), hs = jax.lax.scan(
+                    body, (h, range_state),
+                    None if keys is None else jnp.stack(keys),
+                    length=passes)
+                out = jnp.moveaxis(hs, 0, 1).reshape(
+                    h.shape[0], passes * h.shape[1], *h.shape[2:])
+            else:
+                outs = []
+                for r in range(passes):
+                    h, range_state = one_pass(
+                        h, range_state, None if keys is None else keys[r])
+                    outs.append(h)
+                out = jnp.concatenate(outs, axis=1)
+        new_state.update(range_state)
+        if mask is not None:
+            mask = jnp.tile(mask, (1, passes))
+        return out, mask
 
 
 def _stack_loss(conf, params, state, x, y, mask=None, label_mask=None, *,
@@ -175,8 +280,13 @@ def _stack_loss(conf, params, state, x, y, mask=None, label_mask=None, *,
     # global-pooling layer consumes the time axis and nulls the mask)
     lm = label_mask if label_mask is not None else pmask
     with jax.named_scope(type(out_conf).__name__):
-        loss = out_conf.compute_loss(variables, h, y, train=train, key=lkey,
-                                     mask=lm)
+        if hasattr(out_conf, "loss_and_state"):
+            # a head that threads state of its own (the exits' shares)
+            loss, new_state[f"layer_{n-1}"] = out_conf.loss_and_state(
+                variables, h, y, train=train, key=lkey, mask=lm)
+        else:
+            loss = out_conf.compute_loss(variables, h, y, train=train,
+                                         key=lkey, mask=lm)
     # accumulator follows the LOSS dtype: a dtype-defaulted zeros(())
     # is f64 under x64 and silently promotes the whole loss output
     # (graftaudit AX001); f64 gradient-check runs still get f64 here
@@ -202,6 +312,11 @@ def _build_stack_fn(conf, tx, kind: str):
     safe to place in the process-global trace cache (and is exactly the
     hazard graftlint JX013 flags).
     """
+    if conf.looped() and kind in ("rnn_time_step", "train_step_carry",
+                                  "paged_prefill", "paged_decode"):
+        raise ValueError(
+            f"'{kind}' cannot run through a looped range: a recurrent "
+            "carry or a K/V cache a pass is not written")
     if kind == "output":
         def fn(params, state, x):
             return _stack_forward(conf, params, state, x, train=False,
@@ -300,9 +415,18 @@ def _build_train_step(conf, tx, with_carry: bool):
     for it and the recurrent carries for tBPTT."""
     confs = _layer_confs(conf)
     sparse_emb = _sparse_embedding_conf(conf)
+    cast_map = compute_dtypes(conf.defaults, confs)
+    loop = conf.looped()
+    if loop:
+        # a looped range's masters reach its walk uncast: it casts them
+        # inside each pass, so that their gradient sums over the passes in
+        # float32 (``_Walk.loop``)
+        first, stop, _ = loop
+        cast_map = {k: v for k, v in cast_map.items()
+                    if not first <= int(k[6:]) < stop}
     return build_train_step(
         functools.partial(_stack_loss, conf, train=True), conf.defaults,
-        confs, compute_dtypes(conf.defaults, confs), tx,
+        confs, cast_map, tx,
         sparse=None if sparse_emb is None else ("layer_0", sparse_emb),
         with_carry=with_carry)
 

@@ -72,6 +72,21 @@ the attention's log-sum-exp and output, the stream after the first add).
              collectives is never replayed), and under remat the bare
              ``jax.checkpoint``.
 
+A run inside a looped range (``ListBuilder.loop``: a stretch of the list
+walked ``passes`` times on one set of weights, ``nn/multilayer._Walk.loop``)
+is this scan under an outer ``lax.scan`` over the passes.  The body and
+what it may keep are the same; what the run's stacks take is counted for
+what the step then holds: every name and every layer's input ``passes``
+times (the outer scan stacks the inner one's residuals), each pass's
+output, the weights as the pass casts them and one pass's gradients once,
+and the loop's float32 sums of the weights' gradients, which unlike a
+plain run's gradients are live all through the backward pass.  Two levels
+of loops also cost the compiler's buffer assignment more than is live at
+once: ``LOOP_FRAGMENTATION`` of the run's own stacks, its reserve and each
+name's bytes is taken off the room (measured compile-only, as ``RESERVE``
+was).  Such a run counts into ``loop_runs_traced_total{layers, passes,
+saved}`` and sets ``loop_saved_stack_bytes`` beside the two below.
+
 Each scanned run traced into a training step counts into
 ``scan_runs_traced_total{layer, saved}``: ``all`` (nothing named, no
 remat), ``named`` (every name), ``some`` (remat, a part of them),
@@ -86,10 +101,13 @@ rnn_time_step walk unrolled); no AUX_LOSS (MoE) layers; no per-layer
 ``PrecisionPolicy`` override inside the run; mask propagation must be
 the identity (a layer overriding ``feed_forward_mask`` breaks the run
 only when a mask is actually present); not an activation-collecting walk
-(``feed_forward`` needs every layer's output).
+(``feed_forward`` needs every layer's output); a run never crosses the
+bounds of a looped range (the walk asks for the runs of each stretch:
+before the range, inside it, after it).
 
 Opt out with ``DL4J_TPU_SCAN_LAYERS=0`` or per-conf via the builder's
-``.scan_layers(False)``; ``.scan_layers(k)`` overrides the minimum run
+``.scan_layers(False)`` (a looped range's passes are then a Python loop
+too); ``.scan_layers(k)`` overrides the minimum run
 length.
 """
 from __future__ import annotations
@@ -161,18 +179,25 @@ def _layer_sig(lc, mask_present: bool, carries_present: bool,
     return payload
 
 
+def scanning(conf) -> bool:
+    """Does this conf's walk scan at all?  (Off: runs of layers, and a
+    looped range's passes, are walked unrolled.)"""
+    return _min_run(conf) > 0
+
+
 def scan_runs(conf, n: int, *, mask_present: bool, carries_present: bool,
-              collect: bool, policy=None) -> List[Tuple[int, int]]:
+              collect: bool, policy=None, lo: int = 0
+              ) -> List[Tuple[int, int]]:
     """Eligible homogeneous runs ``[(start, stop), ...]`` (half-open)
-    within ``conf.layers[:n]``.  Pure trace-time work — called once per
-    trace, never per step."""
+    within ``conf.layers[lo:n]``.  Pure trace-time work — called once per
+    stretch traced, never per step."""
     min_run = _min_run(conf)
-    if collect or min_run <= 0 or n < min_run:
+    if collect or min_run <= 0 or n - lo < min_run:
         return []
-    sigs = [_layer_sig(conf.layers[i], mask_present, carries_present,
-                       policy) for i in range(n)]
+    sigs = {i: _layer_sig(conf.layers[i], mask_present, carries_present,
+                          policy) for i in range(lo, n)}
     runs: List[Tuple[int, int]] = []
-    i = 0
+    i = lo
     while i < n:
         if sigs[i] is None:
             i += 1
@@ -203,6 +228,23 @@ def _count_run(layer: str, saved: str, stack_bytes: int) -> None:
                   "Bytes the last scanned run traced stacks for the "
                   "backward pass beyond its layers' inputs",
                   ("layer",)).labels(layer).set(stack_bytes)
+
+
+def _count_loop(n_run: int, passes: int, saved: str, all_bytes: int) -> None:
+    """The same for a run inside a looped range, counted as a loop too."""
+    from ..observability.registry import default_registry
+    reg = default_registry()
+    if reg.enabled:
+        reg.counter("loop_runs_traced_total",
+                    "Scanned runs of a looped range traced into a training "
+                    "step, by the run's layers, the passes over it and what "
+                    "a layer-pass saves for the backward pass",
+                    ("layers", "passes", "saved")).labels(
+                        str(n_run), str(passes), saved).inc()
+        reg.gauge("loop_saved_stack_bytes",
+                  "Bytes the last looped run traced stacks for the "
+                  "backward pass over all its passes: its layers' inputs "
+                  "and what it keeps beside them").set(all_bytes)
 
 
 # ---- what a training program holds while a run is traced -----------------
@@ -306,6 +348,16 @@ def refused_for_memory(error: BaseException) -> bool:
 RESERVE = 0.77
 
 
+# What a run inside a looped range (the scan over the layers under the
+# scan over the passes) needs beyond what is live at once, as a share of
+# it: the TPU's compiler was asked, compile-only, for eight blocks 2048
+# wide walked four times at 8192 tokens beside 7.35 GB of state, and its
+# buffer assignment read 4.81 G of fragmentation on 4.84 G live keeping q,
+# 5.90 on 5.13 keeping q and k, 6.22 on 6.01 keeping q, k and v; all three
+# refused, the inputs alone fit with 0.5 GiB to spare (PERF.md section 7).
+LOOP_FRAGMENTATION = 0.5
+
+
 def _named_bytes(jaxpr, into: Dict[str, int]) -> None:
     """Bytes by ``checkpoint_name`` over a jaxpr and the jaxprs inside its
     equations (a jitted helper, the forward rule of a kernel)."""
@@ -362,7 +414,8 @@ def fitting(sizes: Dict[str, int], room: int) -> Tuple[str, ...]:
 
 
 def run_scan(lc, params_slices, state_slices, h, key, start: int,
-             *, train: bool, mask, remat: bool, room: Optional[int] = None):
+             *, train: bool, mask, remat: bool, room: Optional[int] = None,
+             passes: int = 1):
     """Execute one homogeneous run under ``jax.lax.scan``.
 
     ``params_slices``/``state_slices``: the per-layer pytrees in stack
@@ -370,7 +423,9 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
     structure the unrolled walk would have produced.  ``room``: the bytes
     a remat run may spend on stacks of its own (the step's arguments
     already taken off); read from the device and the step being traced
-    where not given.
+    where not given.  ``passes``: how often a step walks this run (a run
+    inside a looped range, itself under the scan over the passes): what
+    the run saves a layer is stacked that many times.
     """
     import jax
     import jax.numpy as jnp
@@ -401,14 +456,28 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
         per_layer, reads = body_census(body, h, (
             params_slices[0], state_slices[0], None if keys is None
             else jax.ShapeDtypeStruct(keys.shape[1:], keys.dtype)))
-        sizes = {n: per_layer[n] * n_run for n in names if n in per_layer}
+        sizes = {n: per_layer[n] * n_run * passes
+                 for n in names if n in per_layer}
         if remat:
             # what the run stacks whatever it keeps: the weights as the
-            # walk hands them, their gradients, each layer's input
-            own = 2 * tree_bytes(stacked_p) + n_run * tree_bytes(h)
+            # walk hands them, their gradients, each layer's input; in a
+            # looped range the inputs once a pass, each pass's output, and
+            # the loop's float32 sums of the gradients
+            own = 2 * tree_bytes(stacked_p) + passes * n_run * tree_bytes(h)
+            if passes > 1:
+                own += passes * tree_bytes(h) + 4 * sum(
+                    int(np.prod(a.shape))
+                    for a in jax.tree_util.tree_leaves(stacked_p))
             reserve = int(RESERVE * reads)
             room = (free_bytes() if room is None else room) - own - reserve
-            kept = fitting(sizes, room)
+            costs = sizes
+            if passes > 1:
+                # two levels of loops: the compiler's buffer assignment
+                # wants half as much again as is live at once
+                room -= int(LOOP_FRAGMENTATION * (own + reserve))
+                costs = {n: int(v * (1 + LOOP_FRAGMENTATION))
+                         for n, v in sizes.items()}
+            kept = fitting(costs, room)
             log.info("scan of %d %s under remat keeps %s: %d bytes of %d "
                      "free (beside %d of its own stacks and %d reserved "
                      "for one layer's backward); passed over %s",
@@ -435,6 +504,9 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
         body = jax.checkpoint(body)
     if train:
         _count_run(type(lc).__name__, saved, stack_bytes)
+        if passes > 1:
+            _count_loop(n_run, passes, saved,
+                        passes * n_run * tree_bytes(h) + stack_bytes)
     # explicit length: a paramless/stateless run at inference (no keys)
     # has no xs leaves for scan to infer it from
     h, stacked_ns = jax.lax.scan(body, h, (stacked_p, stacked_s, keys),

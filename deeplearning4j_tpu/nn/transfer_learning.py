@@ -58,6 +58,10 @@ class TransferLearning:
 
         def __init__(self, net: MultiLayerNetwork):
             self._net = net
+            if net.conf.loop:
+                raise ValueError(
+                    "transfer learning cannot edit a list with a looped "
+                    "range: adding or removing layers moves its bounds")
             self._conf = copy.deepcopy(net.conf)
             # (new_layer_conf, old_index or None, needs_reinit)
             self._plan: List[List[Any]] = [

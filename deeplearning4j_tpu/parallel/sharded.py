@@ -146,6 +146,11 @@ class ShardedTrainer(ParallelWrapper):
     def __init__(self, model, mesh: Optional[Mesh] = None, *,
                  min_shard_size: int = DEFAULT_MIN_SHARD_SIZE):
         self.min_shard_size = int(min_shard_size)
+        if getattr(model.conf, "looped", lambda: None)():
+            raise ValueError(
+                "ShardedTrainer cannot train a looped range: its passes "
+                "over row-sharded weights (a pipeline whose last stage "
+                "feeds its first) are not written")
         super().__init__(model, mesh)
 
     # ------------------------------------------------------------------

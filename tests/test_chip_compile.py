@@ -664,3 +664,70 @@ def test_joyai_share_step_compiles_for_v5e_with_room(one_chip, monkeypatch):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(8.165e9, rel=1e-3)
     assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 10 * SPARE
+
+
+# ---- the looped run and the step that walks it (PR 40) --------------------
+def test_ouro_step_compiles_with_what_its_looped_run_keeps(one_chip,
+                                                           monkeypatch):
+    """The benchmark's ``ouro-2p6b-8l`` train step (eight blocks 2048 wide
+    walked four times on one set of weights, the final norm inside the
+    loop, four exits through the chunked head over 49152 classes,
+    bfloat16 under ``cache_mode="remat"``) lowered from shapes for one
+    described v5e at one row of 8192 tokens.  The remat rule counts the
+    looped run's stacks four times and half as much again for the two
+    levels of loops: of the block's names only the log-sum-exp (16.8 MB
+    over 32 layer-passes) fits beside the layers' inputs, q (1.07 GB) does
+    not.  Each of the three kernels is in the program once in the
+    backward's loop and the forward one once more in the forward's; the
+    head walks 32 chunks; arguments and program fit the compiler's limit
+    (15.24 GiB of 15.75; with q kept it refuses: 16.50)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import common
+    from deeplearning4j_tpu.nn import scan_layers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(scan_layers, "_device_limit",
+                        lambda: V5E_BYTES_LIMIT)
+    kept = []
+    choose = scan_layers.fitting
+
+    def spy(costs, room):
+        kept.append((choose(costs, room), dict(costs), room))
+        return kept[-1][0]
+    monkeypatch.setattr(scan_layers, "fitting", spy)
+    cfg = common.load_json("configs", "ouro-2p6b-8l.json")
+    traffic = common.load_module("traffic", "loop_lm_fit_stream")
+    held = {}
+
+    def built():
+        # 612 M parameters and Adam's moments: shapes alone
+        net = held["net"] = traffic.build(cfg)
+        return net.params, net.state, net.opt_state, net._rng
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(built))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    before = _head_chunks().get(("32768", "49152", "32"), 0)
+    lowered = held["net"]._get_jitted("train_step").audit_lower(
+        (args + (ids, ids, None, None), {}))
+    assert _head_chunks()[("32768", "49152", "32")] == before + 1
+    (names, costs, room), = kept
+    assert names == ("attn_lse",)
+    # a name's cost: its bytes over 8 layers and 4 passes, half again
+    assert costs["attn_q"] == int(1.5 * 32 * 8192 * 2048 * 2)
+    assert costs["attn_lse"] == int(1.5 * 32 * 16 * 8192 * 4)
+    assert costs["attn_lse"] < room < costs["attn_q"]
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert {n: sum(f"/{n}/pallas_call" in c for c in calls)
+            for n in F.FULL_KERNEL_NAMES} == {
+        "flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    # the loop's sums of the weights' gradients are float32: the backward
+    # scan over the passes carries no bfloat16 array of a weight's shape
+    # but the one stack of the weights it reads
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(7.35e9, rel=1e-3)
+    assert memory.peak_memory_in_bytes <= V5E_BYTES_LIMIT - 2 * SPARE

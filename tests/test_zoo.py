@@ -163,7 +163,7 @@ def test_model_selector_type_filter():
     from deeplearning4j_tpu.models import ModelSelector
     rnn = ModelSelector.select("rnn")
     assert set(rnn) == {"TextGenerationLSTM", "TransformerLM", "EvaByteLM",
-                        "TrinityLM", "JoyAIFlashLM"}
+                        "TrinityLM", "JoyAIFlashLM", "OuroLM"}
     cnn = ModelSelector.select("cnn")
     assert "TextGenerationLSTM" not in cnn and "LeNet" in cnn
 
