@@ -196,6 +196,15 @@ class _Walk:
         last one's output, and the outputs leave joined in time,
         pass-major, ``[b, passes * t, d]`` (a mask is repeated to match).
 
+        The outer scan is unrolled whole (``unroll=True``): the body is
+        traced once and the jaxpr keeps its ``scan``, but the compiled
+        step has one level of loops, each pass's run one ``while`` over
+        its layers.  Under a ``while`` over the passes around that one
+        the TPU's compiler, short of room in its own buffer assignment,
+        repeated the blocks' products in the backward (22 ``.remat``
+        instructions in Ouro's step where now 1, peak 15.24 -> 14.78 GiB;
+        compile-only, PR 41).
+
         The range's parameters come in as the step holds them (under a
         lower-precision policy: the float32 masters, which
         ``_build_train_step`` leaves uncast for this range) and are cast
@@ -233,7 +242,7 @@ class _Walk:
                 (_, range_state), hs = jax.lax.scan(
                     body, (h, range_state),
                     None if keys is None else jnp.stack(keys),
-                    length=passes)
+                    length=passes, unroll=True)
                 out = jnp.moveaxis(hs, 0, 1).reshape(
                     h.shape[0], passes * h.shape[1], *h.shape[2:])
             else:

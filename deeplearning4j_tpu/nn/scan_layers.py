@@ -74,18 +74,20 @@ the attention's log-sum-exp and output, the stream after the first add).
 
 A run inside a looped range (``ListBuilder.loop``: a stretch of the list
 walked ``passes`` times on one set of weights, ``nn/multilayer._Walk.loop``)
-is this scan under an outer ``lax.scan`` over the passes.  The body and
-what it may keep are the same; what the run's stacks take is counted for
-what the step then holds: every name and every layer's input ``passes``
-times (the outer scan stacks the inner one's residuals), each pass's
-output, the weights as the pass casts them and one pass's gradients once,
-and the loop's float32 sums of the weights' gradients, which unlike a
-plain run's gradients are live all through the backward pass.  Two levels
-of loops also cost the compiler's buffer assignment more than is live at
-once: ``LOOP_FRAGMENTATION`` of the run's own stacks, its reserve and each
-name's bytes is taken off the room (measured compile-only, as ``RESERVE``
-was).  Such a run counts into ``loop_runs_traced_total{layers, passes,
-saved}`` and sets ``loop_saved_stack_bytes`` beside the two below.
+is this scan under an outer ``lax.scan`` over the passes, unrolled whole,
+so the compiled step holds one ``while`` over the layers a pass and
+direction and no loop around them.  The body and what it may keep are the
+same; what the run's stacks take is counted for what the step then holds:
+every name and every layer's input ``passes`` times (each pass's run
+stacks its own residuals), each pass's output, the weights as the pass
+casts them and one pass's gradients once, and the loop's float32 sums of
+the weights' gradients, which unlike a plain run's gradients are live all
+through the backward pass.  The compiler's buffer assignment also needs
+more than is live at once: ``LOOP_FRAGMENTATION`` of the run's own
+stacks, its reserve and each name's bytes is taken off the room (measured
+compile-only, as ``RESERVE`` was).  Such a run counts into
+``loop_runs_traced_total{layers, passes, saved}`` and sets
+``loop_saved_stack_bytes`` beside the two below.
 
 Each scanned run traced into a training step counts into
 ``scan_runs_traced_total{layer, saved}``: ``all`` (nothing named, no
@@ -348,13 +350,16 @@ def refused_for_memory(error: BaseException) -> bool:
 RESERVE = 0.77
 
 
-# What a run inside a looped range (the scan over the layers under the
-# scan over the passes) needs beyond what is live at once, as a share of
+# What a run inside a looped range (the scan over the layers in each of
+# the unrolled passes) needs beyond what is live at once, as a share of
 # it: the TPU's compiler was asked, compile-only, for eight blocks 2048
-# wide walked four times at 8192 tokens beside 7.35 GB of state, and its
-# buffer assignment read 4.81 G of fragmentation on 4.84 G live keeping q,
-# 5.90 on 5.13 keeping q and k, 6.22 on 6.01 keeping q, k and v; all three
-# refused, the inputs alone fit with 0.5 GiB to spare (PERF.md section 7).
+# wide walked four times at 8192 tokens beside 7.35 GB of state.  Under a
+# loop over the passes (PR 40) its buffer assignment read 4.81 G of
+# fragmentation on 4.84 G live keeping q, 5.90 on 5.13 keeping q and k,
+# 6.22 on 6.01 keeping q, k and v; all three refused, the inputs alone fit
+# with 0.5 GiB to spare (PERF.md section 7).  With the passes unrolled
+# the constant stays: at 0 the rule keeps q, k and v and the compiler
+# refuses the step at 16.70 G of 15.75 (PR 41).
 LOOP_FRAGMENTATION = 0.5
 
 
@@ -424,8 +429,8 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
     a remat run may spend on stacks of its own (the step's arguments
     already taken off); read from the device and the step being traced
     where not given.  ``passes``: how often a step walks this run (a run
-    inside a looped range, itself under the scan over the passes): what
-    the run saves a layer is stacked that many times.
+    inside a looped range, one of the unrolled passes): what the run
+    saves a layer is stacked that many times.
     """
     import jax
     import jax.numpy as jnp
@@ -472,8 +477,8 @@ def run_scan(lc, params_slices, state_slices, h, key, start: int,
             room = (free_bytes() if room is None else room) - own - reserve
             costs = sizes
             if passes > 1:
-                # two levels of loops: the compiler's buffer assignment
-                # wants half as much again as is live at once
+                # a looped run: the compiler's buffer assignment wants
+                # half as much again as is live at once
                 room -= int(LOOP_FRAGMENTATION * (own + reserve))
                 costs = {n: int(v * (1 + LOOP_FRAGMENTATION))
                          for n, v in sizes.items()}

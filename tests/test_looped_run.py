@@ -203,6 +203,46 @@ def test_the_loop_is_counted_with_what_it_saved():
         set_default_registry(old)
 
 
+def _whiles(module):
+    """``(whiles around it, traced by _Walk.loop)`` for each
+    ``stablehlo.while`` of a lowered module, reached from ``main`` through
+    the calls of its private functions."""
+    funcs = {op.attributes["sym_name"].value: op
+             for op in (o.operation for o in module.body.operations)
+             if op.name == "func.func"}
+    found = []
+
+    def walk(op, depth):
+        if op.name == "func.call":
+            walk(funcs[op.attributes["callee"].value], depth)
+        if op.name == "stablehlo.while":
+            found.append((depth, "_Walk.loop" in str(op.location)))
+            depth += 1
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    walk(inner.operation, depth)
+    walk(funcs["main"], 0)
+    return found
+
+
+@pytest.mark.parametrize("passes", [2, 3])
+def test_each_pass_is_one_loop_and_no_loop_holds_another(passes):
+    """The scan over the passes is unrolled: the lowered step has no
+    ``while`` around the range's runs, each pass's run is one ``while``
+    forward and one backward, and one more holds the part of the run that
+    no pass changes (taken out of the passes by the differentiation)."""
+    net = MultiLayerNetwork(_conf(passes)).init()
+    x, y = _ids()
+    step = ml._build_train_step(net.conf, net._build_tx(), False)
+    module = jax.jit(step).lower(
+        net.params, net.state, net.opt_state, net._rng, x, y, None,
+        None).compiler_ir("stablehlo")
+    whiles = _whiles(module)
+    assert [depth for depth, _ in whiles] == [0] * len(whiles)
+    assert sum(looped for _, looped in whiles) == 2 * passes + 1
+
+
 def test_the_range_is_held_once_in_parameters_and_adam_state():
     conf = _conf(3, updater=Adam(learning_rate=1e-3))
     net = MultiLayerNetwork(conf).init()
