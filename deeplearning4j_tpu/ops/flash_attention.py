@@ -15,13 +15,25 @@ roles — a block of keys held, queries walked — and works on the transposed
 score tile, so the saved row statistics meet it in the lane-major layout
 they are stored in.  Under ``causal`` a block wholly above the diagonal is
 neither computed nor copied (its index map repeats a live block), a block
-wholly below it is one unmasked step, and the block the diagonal enters is
+wholly below it takes no mask (one step; in the forward one a group of
+queries, below), and the block the diagonal enters is
 cut, when the kernel is traced, into one step a ``block_q`` rows of
 queries: all their live keys in one product, the mask on the
 ``block_k``-wide tiles the diagonal crosses only, the tiles above it in no
 step.  Few large steps and not many small ones, in the body and not in the
 grid: a product of 256 x 256 scores costs about as much to start as to
 run, a grid step more (``PERF.md`` section 6, PR 28).
+
+The forward scores a full block's queries as independent groups of
+``_TILE`` rows (``_query_groups``), each a step over all the block's keys,
+written one after another in the body.  In one step of 1024 rows the
+softmax waits for each row's maximum over all its keys and the PV product
+waits for the softmax, so the MXU waits with them; with groups the
+compiler schedules one group's products while the vector unit forms
+another's softmax.  A row's arithmetic is the same either way, so ``o``
+and ``lse`` are bit for bit those of one group and the backward replays
+them exactly.  dq, with a third product a score, and dk/dv, keys down the
+rows, take a block whole.
 
 ``window=W`` (causal only) is a band that moves with the query: query
 ``i`` sees the keys ``i - W < j <= i``.  The grid then walks, for each held
@@ -68,7 +80,8 @@ from jax.sharding import AxisType, PartitionSpec as P
 from .attention import NEG_INF
 
 _LANES = 128
-# The tile that cuts the diagonal's block, swept at d=64 from t=128 to
+# The tile that cuts the diagonal's block (and in the forward a full
+# block's queries, _query_groups), swept at d=64 from t=128 to
 # t=8192, bfloat16 and float32, and at d=128, t=2048: 256 rows of queries
 # a step in the forward and dq kernels (128: 12 % slower a forward call;
 # 512: 14 % slower a dq call), 128 in the dk/dv kernel, whose tile has the
@@ -82,6 +95,18 @@ _DKV_TILE = 128
 # VMEM, and wider rows take fewer of them (compile-only; not timed).
 _BLOCK_ROWS = 1024
 _BLOCK_ROW_BYTES = 512
+
+
+def _query_groups(q_rows: int) -> int:
+    """How many groups of query rows the forward scores a full block of
+    ``q_rows`` as (module docstring): one ``_TILE`` of rows each, one
+    group where tiles do not divide it.  A forward call on a v5e, ms, for
+    1, 2 and 4 groups, causal, o and lse the same bits: [16, 8192, 128]
+    2.207, 2.059, 2.035; [32, 8192, 128 + 64 | 128], keys in parts, 5.789,
+    5.547, 5.473; [32, 8192, 128] under a window of 2048 2.413, 2.364,
+    2.331; [128, 2048, 128] 1.631, 1.576, 1.575; [48, 1024, 64], whose one
+    block is the diagonal's, 0.214 in all three (PERF.md section 6)."""
+    return q_rows // _TILE if q_rows % _TILE == 0 else 1
 
 
 def _auto_blocks(t_q: int, t_k: int, d: int):
@@ -165,19 +190,23 @@ def _diagonal_steps(rows: int, block_q: int, block_k: int):
 
 
 def _run_block(step, causal: bool, qi, ki, q_rows: int, k_rows: int,
-               block_q: int, block_k: int):
-    """One block of the grid: nothing where it is dead, one unmasked step
-    over all of it where it is full, the diagonal's steps where the
-    diagonal enters it.  Key blocks come in rising order and a step's keys
-    start at the block's first, so every row's first live step holds
-    key 0 and its running maximum is finite before a masked score meets
-    it."""
-    whole = (0, q_rows, 0, k_rows, 0)
+               block_q: int, block_k: int, groups: int = 1):
+    """One block of the grid: nothing where it is dead, where it is full
+    one unmasked step over all its keys for each of ``groups`` groups of
+    its query rows, the diagonal's steps where the diagonal enters it.
+    Key blocks come in rising order and a step's keys start at the block's
+    first, so every row's first live step holds key 0 and its running
+    maximum is finite before a masked score meets it."""
+    rows = q_rows // groups
+
+    def whole():
+        for g in range(groups):
+            step(g * rows, rows, 0, k_rows, 0)
     if not causal:
-        return step(*whole)
+        return whole()
     live = _block_live(causal, qi, ki, q_rows, k_rows)
     full = _block_full(causal, qi, ki, q_rows, k_rows)
-    pl.when(full)(lambda: step(*whole))
+    pl.when(full)(whole)
 
     @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
     def _diagonal():
@@ -193,14 +222,15 @@ def _band_walk(window: int, rows: int, n_blocks: int) -> int:
 
 
 def _band_steps(rows: int, block_q: int, block_k: int, shift: int,
-                window: int):
+                window: int, groups: int = 1):
     """The work of a block whose first query lies ``shift`` positions past
     its first key, under the causal band ``q - window < k <= q``, as
     static ``(q_start, q_size, k_start, k_size, cut, head)`` steps, one a
     query tile: the keys of every tile it can see in one product, of which
     the first ``head`` keys (the tiles the band's lower edge crosses) and
     the last ``cut`` (those the diagonal crosses) take the mask; a block
-    the band covers whole is one unmasked step."""
+    the band covers whole is ``groups`` unmasked steps, one a group of its
+    query rows."""
     steps, whole = [], True
     for r in range(rows // block_q):
         q_lo = shift + r * block_q
@@ -221,18 +251,21 @@ def _band_steps(rows: int, block_q: int, block_k: int, shift: int,
             raise AssertionError("a masked tile between two full ones")
         steps.append((r * block_q, block_q, first * block_k,
                       len(tiles) * block_k, cut * block_k, head * block_k))
-    return [(0, rows, 0, rows, 0, 0)] if whole else steps
+    n = rows // groups
+    return [(g * n, n, 0, rows, 0, 0) for g in range(groups)] if whole \
+        else steps
 
 
 def _run_band(step, window: int, walk, live, n_walk: int, rows: int,
-              block_q: int, block_k: int):
+              block_q: int, block_k: int, groups: int = 1):
     """One step of a banded grid: the ``walk``-th block the band reaches
     from the held one — the held block's own first (so a row's first live
     step holds its own key and its running maximum is finite), then the
     ones before it where keys are walked, after it where queries are.
     ``live(delta)`` says whether that block lies on the sequence at all."""
     for delta in range(n_walk):
-        steps = _band_steps(rows, block_q, block_k, delta * rows, window)
+        steps = _band_steps(rows, block_q, block_k, delta * rows, window,
+                            groups)
 
         def block(steps=steps, shift=delta * rows):
             for s in steps:
@@ -356,12 +389,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                 preferred_element_type=jnp.float32))
         m_ref[rows, :] = m_new
 
+    groups = _query_groups(q_rows)
     if window is None:
         _run_block(step, causal, qi, ki, q_rows, k_ref.shape[0],
-                   block_q, block_k)
+                   block_q, block_k, groups)
     else:
         _run_band(step, window, ki, lambda delta: qi >= delta, n_walk,
-                  q_rows, block_q, block_k)
+                  q_rows, block_q, block_k, groups)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -408,7 +442,7 @@ def _row_bytes(qr, vr) -> int:
     """Bytes of the widest operand row a block holds: q's (and k's) or
     v's (and o's); of keys in parts, where ``vr`` is the ``[k | v]``
     product, q's or that product's."""
-    return max(qr.shape[2], vr.shape[2]) * qr.dtype.itemsize
+    return max(qr.shape[-1], vr.shape[-1]) * qr.dtype.itemsize
 
 
 def _value_width(qr, vr, shared) -> int:
@@ -887,6 +921,13 @@ def flash_attention(q, k=None, v=None, *, kv=None, k_shared=None,
                     "the form the keys came in: whole (k and v a head) or "
                     "parts (the [k | v] product and the shared part)",
                     ("keys",)).labels("parts" if parts else "whole").inc()
+        q_rows, _ = _block_rows(t_q, t_k, block_q, block_k, causal,
+                                _row_bytes(q, kv if parts else v))
+        reg.counter("flash_fwd_groups_traced_total",
+                    "Calls of flash_attention traced into a program, by "
+                    "the groups of query rows its forward kernel scores a "
+                    "full block as", ("groups",)).labels(
+                        str(_query_groups(q_rows))).inc()
 
     def run(q, k, v):
         # keys in parts: k is kv, v the shared part

@@ -102,6 +102,13 @@ def _flash_calls():
                               for labels, child in c.samples()})
 
 
+def _fwd_groups():
+    """``{groups of query rows a full block: calls traced so far}``."""
+    c = default_registry().get("flash_fwd_groups_traced_total")
+    return {} if c is None else {labels[0]: child.value
+                                 for labels, child in c.samples()}
+
+
 def _while_stacks(compiled, shape: str) -> int:
     """The most arrays of ``shape`` that one ``while`` of the compiled
     program carries: what the forward scan stacks for the backward."""
@@ -148,6 +155,21 @@ def test_flash_backward_dq_and_dkv_compile_for_v5e(one_chip, t, rows):
     text = lowered.as_text()
     assert all(name in text for name in F.FULL_KERNEL_NAMES)
     assert _custom_calls(lowered.compile()) == 3
+
+
+def test_flash_forward_compiles_in_query_groups_at_ouro_width(one_chip):
+    """Ouro's call, [16, 8192, 128] bfloat16 causal: blocks of 1024 rows,
+    of which the forward scores a full one as four groups of 256 query
+    rows, each over all 1024 keys; the body fits scoped VMEM."""
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    before = _fwd_groups()
+    c = jax.jit(lambda q, k, v: F.flash_attention(q, k, v, causal=True)
+                ).lower(q, q, q).compile()
+    moved = {g: n - before.get(g, 0) for g, n in _fwd_groups().items()
+             if n != before.get(g, 0)}
+    assert moved == {"4": 1}
+    assert _custom_calls(c) == 1
 
 
 def test_flash_float32_operands_compile_for_v5e(one_chip):
