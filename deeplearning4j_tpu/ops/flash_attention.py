@@ -7,22 +7,31 @@ Here the builtin is ``ops.attention.sdpa_reference`` (chosen by the caller,
 never fallen back to from here) and the fast path is a tiled online-softmax
 kernel: O(t) memory instead of the O(t^2) score matrix.
 
-Grid: (heads, blocks of queries, blocks of keys), a block up to 1024 rows;
-the last dimension iterates innermost and sequentially on TPU, so scratch
-carries the running softmax state (forward) or the gradient sums (backward)
-across the key blocks of one query block.  The dk/dv kernel swaps the
-roles — a block of keys held, queries walked — and works on the transposed
-score tile, so the saved row statistics meet it in the lane-major layout
-they are stored in.  Under ``causal`` a block wholly above the diagonal is
-neither computed nor copied (its index map repeats a live block), a block
-wholly below it takes no mask (one step; in the forward one a group of
-queries, below), and the block the diagonal enters is
-cut, when the kernel is traced, into one step a ``block_q`` rows of
-queries: all their live keys in one product, the mask on the
-``block_k``-wide tiles the diagonal crosses only, the tiles above it in no
-step.  Few large steps and not many small ones, in the body and not in the
-grid: a product of 256 x 256 scores costs about as much to start as to
-run, a grid step more (``PERF.md`` section 6, PR 28).
+Grid: (heads, pairs of blocks), a block up to 1024 rows.  A launch walks
+a table of ``(held block, walked block)`` pairs (``_walk``), built with
+numpy when it is traced and scalar-prefetched, so every index map reads
+its block from the table and the kernels read from it whether a pair is
+its held block's first (the sums start) or last (they are written).  The
+table holds the live pairs alone: under ``causal`` no block wholly above
+the diagonal is in it, so no grid step idles, and a held block's pairs
+come in the order a walk of the whole square took them, so the products
+and their order, and with them the results, are that walk's.  The last
+grid dimension iterates sequentially on TPU, so scratch carries the
+running softmax state (forward) or the gradient sums (backward) across
+the key blocks of one query block.  The dk/dv kernel swaps the roles — a
+block of keys held, queries walked — and works on the transposed score
+tile, so the saved row statistics meet it in the lane-major layout they
+are stored in.  A block wholly below the diagonal takes no mask (one
+step; in the forward one a group of queries, below), and the block the
+diagonal enters is cut, when the kernel is traced, into one step a
+``block_q`` rows of queries: all their live keys in one product, the mask
+on the ``block_k``-wide tiles the diagonal crosses only, the tiles above
+it in no step.  Few large steps and not many small ones, in the body and
+not in the grid: a product of 256 x 256 scores costs about as much to
+start as to run, a grid step more (``PERF.md`` section 6).
+``flash_grid_pairs_traced_total{live, square}`` counts the calls traced
+by the pairs a head walks and the steps a walk of the whole square (or
+band) would take.
 
 The forward scores a full block's queries as independent groups of
 ``_TILE`` rows (``_query_groups``), each a step over all the block's keys,
@@ -36,13 +45,15 @@ them exactly.  dq, with a third product a score, and dk/dv, keys down the
 rows, take a block whole.
 
 ``window=W`` (causal only) is a band that moves with the query: query
-``i`` sees the keys ``i - W < j <= i``.  The grid then walks, for each held
-block, only the blocks the band can reach (``_band_walk``: the diagonal's
-block first, then the ones before it), so a call's work follows ``t x W``
-and not ``t^2``; inside a block the band cuts, the steps are again static,
-one a tile of queries over the key tiles it can see, the mask on the tiles
-an edge crosses only (``_band_steps``).  Such calls carry names of their
-own (``flash_win_*``), so a trace tells them from full calls.
+``i`` sees the keys ``i - W < j <= i``.  The table then holds, for each
+held block, only the blocks the band can reach that lie on the sequence
+(``_band_walk``: the diagonal's block first, then the ones before it), so
+a call's work follows ``t x W`` and not ``t^2``; inside a block the band
+cuts, the steps are again static, chosen by the pair's distance from the
+diagonal, one a tile of queries over the key tiles it can see, the mask
+on the tiles an edge crosses only (``_band_steps``).  Such calls carry
+names of their own (``flash_win_*``), so a trace tells them from full
+calls.
 
 The keys come whole, ``k`` and ``v`` a head, or **in parts, as latent
 attention's projections write them** (``flash_attention(q, kv=,
@@ -72,6 +83,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -155,11 +167,10 @@ def _block_rows(t_q: int, t_k: int, block_q: int, block_k: int,
 
 def _block_live(causal: bool, qi, ki, block_q: int, block_k: int):
     """False only for key blocks entirely above the causal diagonal —
-    shared by the forward and both backward kernels, by the blocks of the
-    grid and the tiles inside them, so the skip predicate cannot drift.
-    (Blocks of the grid are one size for queries and keys under
-    ``causal``: there it reads ``ki <= qi``, which is what the index maps
-    clamp to.)"""
+    shared by the three kernels' walk of blocks (``_walk``) and by the
+    tiles inside a block, so the skip predicate cannot drift.  (Blocks of
+    the grid are one size for queries and keys under ``causal``: there it
+    reads ``ki <= qi``.)"""
     if not causal:
         return True
     return qi * block_q + block_q - 1 >= ki * block_k
@@ -191,12 +202,12 @@ def _diagonal_steps(rows: int, block_q: int, block_k: int):
 
 def _run_block(step, causal: bool, qi, ki, q_rows: int, k_rows: int,
                block_q: int, block_k: int, groups: int = 1):
-    """One block of the grid: nothing where it is dead, where it is full
-    one unmasked step over all its keys for each of ``groups`` groups of
-    its query rows, the diagonal's steps where the diagonal enters it.
-    Key blocks come in rising order and a step's keys start at the block's
-    first, so every row's first live step holds key 0 and its running
-    maximum is finite before a masked score meets it."""
+    """One live block of the walk (``_walk``, which holds no dead one):
+    where it is full one unmasked step over all its keys for each of
+    ``groups`` groups of its query rows, the diagonal's steps where the
+    diagonal enters it.  Key blocks come in rising order and a step's keys
+    start at the block's first, so every row's first live step holds key
+    0 and its running maximum is finite before a masked score meets it."""
     rows = q_rows // groups
 
     def whole():
@@ -204,11 +215,10 @@ def _run_block(step, causal: bool, qi, ki, q_rows: int, k_rows: int,
             step(g * rows, rows, 0, k_rows, 0)
     if not causal:
         return whole()
-    live = _block_live(causal, qi, ki, q_rows, k_rows)
     full = _block_full(causal, qi, ki, q_rows, k_rows)
     pl.when(full)(whole)
 
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
+    @pl.when(jnp.logical_not(full))
     def _diagonal():
         for s in _diagonal_steps(q_rows, block_q, block_k):
             step(*s)
@@ -256,21 +266,20 @@ def _band_steps(rows: int, block_q: int, block_k: int, shift: int,
         else steps
 
 
-def _run_band(step, window: int, walk, live, n_walk: int, rows: int,
-              block_q: int, block_k: int, groups: int = 1):
-    """One step of a banded grid: the ``walk``-th block the band reaches
-    from the held one — the held block's own first (so a row's first live
-    step holds its own key and its running maximum is finite), then the
-    ones before it where keys are walked, after it where queries are.
-    ``live(delta)`` says whether that block lies on the sequence at all."""
-    for delta in range(n_walk):
-        steps = _band_steps(rows, block_q, block_k, delta * rows, window,
-                            groups)
+def _run_band(step, window: int, delta, n_walk: int, rows: int,
+               block_q: int, block_k: int, groups: int = 1):
+    """One live block of a banded walk: the block ``delta`` blocks from the
+    held one along the band, whose static steps (``_band_steps``) are
+    chosen by that distance.  The walk (``_walk``) takes the held block's
+    own first, so a row's first live step holds its own key and its
+    running maximum is finite, and holds no block off the sequence."""
+    for d in range(n_walk):
+        steps = _band_steps(rows, block_q, block_k, d * rows, window, groups)
 
-        def block(steps=steps, shift=delta * rows):
+        def block(steps=steps, shift=d * rows):
             for s in steps:
                 step(*s, shift=shift)
-        pl.when(jnp.logical_and(walk == delta, live(delta)))(block)
+        pl.when(delta == d)(block)
 
 
 def _scaled(x, scale: float):
@@ -354,16 +363,15 @@ def _key_tile(k_ref, ks_ref, keys):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                  *, scale: float, causal: bool, block_q: int, block_k: int,
-                  window: Optional[int] = None, n_walk: int = 0,
-                  ks_ref=None):
-    # grid: (heads, blocks of queries, blocks of keys); under a window the
-    # last is the walk along the band (_run_band)
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+                  *, pair, scale: float, causal: bool, block_q: int,
+                  block_k: int, window: Optional[int] = None,
+                  n_walk: int = 0, ks_ref=None):
+    # grid: (heads, pairs of _walk): a block of queries held, one of keys
+    # walked (_walker reads the pair)
+    qi, ki, first, last = pair
     q_rows, d_v = q_ref.shape[0], v_ref.shape[1]     # v's width, and o's
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -394,10 +402,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         _run_block(step, causal, qi, ki, q_rows, k_ref.shape[0],
                    block_q, block_k, groups)
     else:
-        _run_band(step, window, ki, lambda delta: qi >= delta, n_walk,
-                  q_rows, block_q, block_k, groups)
+        _run_band(step, window, qi - ki, n_walk, q_rows, block_q, block_k,
+                  groups)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
         l = l_ref[:]
         o_ref[...] = (acc_ref[:] / _lanes(l, d_v)).astype(o_ref.dtype)
@@ -413,29 +421,58 @@ def _like(x, shape=None, dtype=None):
                                 dtype or x.dtype, vma=jax.typeof(x).vma)
 
 
-def _walked(clamp):
-    """The block a walked operand is at, at step ``w`` beside held block
-    ``h``: ``w``, or under ``clamp`` the live block next to a dead one."""
-    def live(h, w):
-        return clamp(w, h) if clamp else w
-    return live
+def _walk(causal: bool, t_q: int, t_k: int, q_rows: int, k_rows: int,
+          keys_walked: bool, n_walk: int = 0):
+    """A head's ``(held block, walked block)`` pairs, in the order the grid
+    walks them: for each held block the walked ones ``_block_live`` keeps,
+    rising, so keys rise to the diagonal where keys are walked and queries
+    rise from it where queries are; under a band (``n_walk``,
+    ``_band_walk``) the held block's own first, then the next ones on the
+    band's side that lie on the sequence; not causal, the whole square.
+    No dead pair is in it, so no grid step idles."""
+    n_q, n_k = t_q // q_rows, t_k // k_rows
+    n_held, n_walked = (n_q, n_k) if keys_walked else (n_k, n_q)
+    pairs = []
+    for h in range(n_held):
+        if n_walk:
+            walked = [h - d if keys_walked else h + d for d in range(n_walk)]
+        else:
+            walked = range(n_walked)
+        pairs += [(h, w) for w in walked if 0 <= w < n_walked and _block_live(
+            causal, *((h, w) if keys_walked else (w, h)), q_rows, k_rows)]
+    return pairs
 
 
-def _specs(d: int, held: int, walked: int, clamp=None):
-    """Block specs of a kernel whose grid is (heads, held blocks, walked
-    blocks): the held operand, the walked operand — under ``causal`` a
-    walked block wholly above the diagonal repeats the live one next to
-    it (``clamp``: ``jnp.minimum`` where keys are walked, ``jnp.maximum``
-    where queries are), so it is not copied — and each one's slice of the
-    lane-major row statistics.  ``d`` is the operands' width: q's and k's
-    in one call, v's (o's, dO's) in another where the two differ."""
-    live = _walked(clamp)
-    return (pl.BlockSpec((None, held, d), lambda b, h, w: (b, h, 0)),
-            pl.BlockSpec((None, walked, d),
-                         lambda b, h, w: (b, live(h, w), 0)),
-            pl.BlockSpec((None, 1, held), lambda b, h, w: (b, 0, h)),
-            pl.BlockSpec((None, 1, walked),
-                         lambda b, h, w: (b, 0, live(h, w))))
+def _table(pairs):
+    """The walk as the columns the grid's index maps and the kernels read,
+    scalar-prefetched: held block, walked block, and whether the pair is
+    its held block's first (the sums start) and last (they are written)."""
+    held, walked = (np.array(c, np.int32) for c in zip(*pairs))
+    turn = held[1:] != held[:-1]
+    return tuple(jnp.asarray(c, jnp.int32) for c in (
+        held, walked, np.r_[True, turn], np.r_[turn, True]))
+
+
+_HELD, _WALKED = 0, 1           # the table's columns an index map reads
+
+
+def _spec(shape, column: int, index):
+    """A block spec of a launch on the walk's table: ``index(b, n)`` of the
+    block ``n`` that the table's ``column`` names at this step."""
+    return pl.BlockSpec(shape,
+                        lambda b, i, *table: index(b, table[column][i]))
+
+
+def _specs(d: int, held: int, walked: int):
+    """Block specs of a kernel whose grid is (heads, pairs of the walk):
+    the held operand, the walked operand and each one's slice of the
+    lane-major row statistics, at the blocks the pair names.  ``d`` is the
+    operands' width: q's and k's in one call, v's (o's, dO's) in another
+    where the two differ."""
+    return (_spec((None, held, d), _HELD, lambda b, n: (b, n, 0)),
+            _spec((None, walked, d), _WALKED, lambda b, n: (b, n, 0)),
+            _spec((None, 1, held), _HELD, lambda b, n: (b, 0, n)),
+            _spec((None, 1, walked), _WALKED, lambda b, n: (b, 0, n)))
 
 
 def _row_bytes(qr, vr) -> int:
@@ -451,48 +488,61 @@ def _value_width(qr, vr, shared) -> int:
     return vr.shape[2] if shared is None else qr.shape[2] - shared[0].shape[2]
 
 
-def _keys(kr, vr, shared, rows: int, at, k_spec, v_spec):
+def _keys(kr, vr, shared, rows: int, column: int, k_spec, v_spec):
     """``(operands, block specs)`` of a launch's keys, ``rows`` of them a
-    block, the block ``at(h, w)``.  Whole keys: ``kr`` and ``vr`` under the
-    specs given.  Keys in parts (``shared``: ``(ksr, heads)``; ``kr`` and
-    ``vr`` are then both the one ``[k | v]`` product, its halves equally
-    wide): k and v are that product's column blocks 0 and 1, no slice
-    outside the kernel, and the shared part is one array a batch row whose
-    index map leaves the head out, so ``heads`` kernel rows read it and no
-    broadcast exists."""
+    block, the block the table's ``column`` names.  Whole keys: ``kr`` and
+    ``vr`` under the specs given.  Keys in parts (``shared``: ``(ksr,
+    heads)``; ``kr`` and ``vr`` are then both the one ``[k | v]`` product,
+    its halves equally wide): k and v are that product's column blocks 0
+    and 1, no slice outside the kernel, and the shared part is one array a
+    batch row whose index map leaves the head out, so ``heads`` kernel rows
+    read it and no broadcast exists."""
     if shared is None:
         return (kr, vr), [k_spec, v_spec]
     ksr, heads = shared
     half = kr.shape[2] // 2
     return (kr, vr, ksr), [
-        pl.BlockSpec((None, rows, half), lambda b, h, w: (b, at(h, w), 0)),
-        pl.BlockSpec((None, rows, half), lambda b, h, w: (b, at(h, w), 1)),
-        pl.BlockSpec((None, rows, ksr.shape[2]),
-                     lambda b, h, w: (b // heads, at(h, w), 0))]
+        _spec((None, rows, half), column, lambda b, n: (b, n, 0)),
+        _spec((None, rows, half), column, lambda b, n: (b, n, 1)),
+        _spec((None, rows, ksr.shape[2]), column,
+              lambda b, n: (b // heads, n, 0))]
 
 
-def _shared_fourth(kernel, shared):
-    """``kernel`` as a launch of keys in parts calls it: the shared part of
-    the key is its fourth operand."""
-    if shared is None:
-        return kernel
-
-    def body(q_ref, k_ref, v_ref, ks_ref, *refs):
-        return kernel(q_ref, k_ref, v_ref, *refs, ks_ref=ks_ref)
+def _walker(kernel, shared):
+    """``kernel`` as a launch on the walk's table calls it: the table's
+    columns come first, and this step's pair reaches the kernel as
+    ``pair=(held block, walked block, first, last)``; of keys in parts the
+    shared part of the key is the fourth operand."""
+    def body(held, walked, first, last, q_ref, k_ref, v_ref, *refs):
+        i = pl.program_id(1)
+        pair = held[i], walked[i], first[i] != 0, last[i] != 0
+        if shared is None:
+            return kernel(q_ref, k_ref, v_ref, *refs, pair=pair)
+        return kernel(q_ref, k_ref, v_ref, *refs[1:], pair=pair,
+                      ks_ref=refs[0])
     return body
 
 
+def _pallas(kernel, shared, table, bh: int, *, in_specs, out_specs,
+            out_shape, scratch_shapes, interpret, name):
+    """A launch of ``kernel`` on the walk's ``table``: grid (heads, pairs),
+    the table's columns scalar-prefetched ahead of the operands."""
+    return functools.partial(pl.pallas_call(
+        _walker(kernel, shared),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table), grid=(bh, len(table[0])),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape, interpret=interpret, name=name), *table)
+
+
 def _band(window, t_k, rows):
-    """Of a windowed launch: the kernel's keyword arguments, and the block
-    walked at the ``w``-th step from held block ``h`` where keys are walked
-    and where queries are.  Of a full launch: nothing, ``None``, ``None``."""
+    """The kernels' keyword arguments of a windowed launch, of a full one
+    none."""
     if window is None:
-        return {}, None, None
-    n_blocks = t_k // rows
-    return ({"window": window,
-             "n_walk": _band_walk(window, rows, n_blocks)},
-            lambda w, h: jnp.maximum(h - w, 0),
-            lambda w, h: jnp.minimum(h + w, n_blocks - 1))
+        return {}
+    return {"window": window,
+            "n_walk": _band_walk(window, rows, t_k // rows)}
 
 
 def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
@@ -501,17 +551,16 @@ def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
     t_k, d_v = kr.shape[1], _value_width(qr, vr, shared)
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  _row_bytes(qr, vr))
-    band, keys_at, _ = _band(window, t_k, q_rows)
-    clamp = keys_at or (jnp.minimum if causal else None)
-    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
-    o_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
-    keys, key_specs = _keys(kr, vr, shared, k_rows, _walked(clamp), k_spec,
-                            v_spec)
-    out, lse = pl.pallas_call(
-        _shared_fourth(functools.partial(
-            _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, **band), shared),
-        grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
+    band = _band(window, t_k, q_rows)
+    table = _table(_walk(causal, t_q, t_k, q_rows, k_rows, True,
+                         band.get("n_walk", 0)))
+    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows)
+    o_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, _WALKED, k_spec, v_spec)
+    out, lse = _pallas(
+        functools.partial(_flash_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, **band),
+        shared, table, bh,
         in_specs=[q_spec] + key_specs,
         out_specs=[o_spec, row_spec],
         out_shape=[_like(qr, (bh, t_q, d_v)),
@@ -528,16 +577,15 @@ def _launch_fwd(qr, kr, vr, scale, causal, block_q, block_k, interpret,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                         dq_ref, dq_acc, *, scale, causal,
+                         dq_ref, dq_acc, *, pair, scale, causal,
                          block_q, block_k, window=None, n_walk=0,
                          ks_ref=None):
     """dq of one block of queries: replay P from the saved logsumexp, form
     dS = P∘(dP − D) (FlashAttention-2 bwd) and add dS·k over the keys;
     the scale meets the ``[rows, d]`` sum once at the end."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, ki, first, last = pair
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -562,29 +610,28 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
                    block_q, block_k)
     else:
-        _run_band(step, window, ki, lambda delta: qi >= delta, n_walk,
-                  q_ref.shape[0], block_q, block_k)
+        _run_band(step, window, qi - ki, n_walk, q_ref.shape[0], block_q,
+                  block_k)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _done():
         dq_ref[...] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          block_q, block_k, window=None, n_walk=0,
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, pair, scale,
+                          causal, block_q, block_k, window=None, n_walk=0,
                           ks_ref=None):
     """dk, dv of one block of keys, on the transposed score tile (keys
     down the rows, queries along the lanes): lse and D are used as the
     lane-major rows they are stored as, and Pᵀ·dO, dSᵀ·q are plain
-    products.  grid: (heads, blocks of keys, blocks of queries).  Of keys
-    in parts (``ks_ref``) ``dk_ref`` is the block of ``[dk | dv]`` that
-    lies as the up-projection's product does, and ``dv_ref`` this head's
-    share of the shared part's gradient."""
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    products.  grid: (heads, pairs of _walk), a block of keys held, one
+    of queries walked.  Of keys in parts (``ks_ref``) ``dk_ref`` is the
+    block of ``[dk | dv]`` that lies as the up-projection's product does,
+    and ``dv_ref`` this head's share of the shared part's gradient."""
+    ki, qi, first, last = pair
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -612,11 +659,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         _run_block(step, causal, qi, ki, q_ref.shape[0], k_ref.shape[0],
                    block_q, block_k)
     else:
-        _run_band(step, window, qi,
-                  lambda delta: ki + delta < pl.num_programs(1), n_walk,
-                  q_ref.shape[0], block_q, block_k)
+        _run_band(step, window, qi - ki, n_walk, q_ref.shape[0], block_q,
+                  block_k)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _done():
         if ks_ref is None:
             dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
@@ -659,17 +705,15 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     t_k, d_v = kr.shape[1], _value_width(qr, vr, shared)
     q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
                                  _row_bytes(qr, vr))
-    band, keys_at, queries_at = _band(window, t_k, q_rows)
-    clamp = keys_at or (jnp.minimum if causal else None)
-    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows, clamp)
-    do_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows, clamp)
-    keys, key_specs = _keys(kr, vr, shared, k_rows, _walked(clamp), k_spec,
-                            v_spec)
-    dq = pl.pallas_call(
-        _shared_fourth(functools.partial(
-            _flash_bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, **band), shared),
-        grid=(bh, t_q // q_rows, band.get("n_walk", t_k // k_rows)),
+    band = _band(window, t_k, q_rows)
+    q_spec, k_spec, row_spec, _ = _specs(d, q_rows, k_rows)
+    do_spec, v_spec, _, _ = _specs(d_v, q_rows, k_rows)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, _WALKED, k_spec, v_spec)
+    dq = _pallas(
+        functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, **band),
+        shared, _table(_walk(causal, t_q, t_k, q_rows, k_rows, True,
+                             band.get("n_walk", 0))), bh,
         in_specs=[q_spec] + key_specs + [do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_like(qr),
@@ -679,16 +723,13 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
     )(qr, *keys, do, lse, dd)
 
     # swapped roles: a block of keys held, queries walked, so dk/dv carry
-    # in scratch; a block of queries wholly above the diagonal repeats the
-    # first live one.  Its tile is the finer _DKV_TILE where that divides
-    # the caller's.
+    # in scratch.  Its tile is the finer _DKV_TILE where that divides the
+    # caller's.
     block_q, block_k = (_DKV_TILE if b % _DKV_TILE == 0 else b
                         for b in (block_q, block_k))
-    clamp2 = queries_at or (jnp.maximum if causal else None)
-    k_spec2, q_spec2, _, row_spec2 = _specs(d, k_rows, q_rows, clamp2)
-    v_spec2, do_spec2, _, _ = _specs(d_v, k_rows, q_rows, clamp2)
-    keys, key_specs = _keys(kr, vr, shared, k_rows, lambda h, w: h, k_spec2,
-                            v_spec2)
+    k_spec2, q_spec2, _, row_spec2 = _specs(d, k_rows, q_rows)
+    v_spec2, do_spec2, _, _ = _specs(d_v, k_rows, q_rows)
+    keys, key_specs = _keys(kr, vr, shared, k_rows, _HELD, k_spec2, v_spec2)
     if shared is None:
         out_specs, out_shape = [k_spec2, v_spec2], [_like(kr), _like(vr)]
     else:
@@ -696,11 +737,11 @@ def _launch_bwd(qr, kr, vr, do, lse, dd, scale, causal, block_q,
         out_specs = [_specs(w, k_rows, q_rows)[0]
                      for w in (2 * d_v, d - d_v)]
         out_shape = [_like(kr), _like(kr, (bh, t_k, d - d_v))]
-    dk, dv = pl.pallas_call(
-        _shared_fourth(functools.partial(
-            _flash_bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, **band), shared),
-        grid=(bh, t_k // k_rows, band.get("n_walk", t_q // q_rows)),
+    dk, dv = _pallas(
+        functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, **band),
+        shared, _table(_walk(causal, t_q, t_k, q_rows, k_rows, False,
+                             band.get("n_walk", 0))), bh,
         in_specs=[q_spec2] + key_specs + [do_spec2, row_spec2, row_spec2],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -921,13 +962,22 @@ def flash_attention(q, k=None, v=None, *, kv=None, k_shared=None,
                     "the form the keys came in: whole (k and v a head) or "
                     "parts (the [k | v] product and the shared part)",
                     ("keys",)).labels("parts" if parts else "whole").inc()
-        q_rows, _ = _block_rows(t_q, t_k, block_q, block_k, causal,
-                                _row_bytes(q, kv if parts else v))
+        q_rows, k_rows = _block_rows(t_q, t_k, block_q, block_k, causal,
+                                     _row_bytes(q, kv if parts else v))
         reg.counter("flash_fwd_groups_traced_total",
                     "Calls of flash_attention traced into a program, by "
                     "the groups of query rows its forward kernel scores a "
                     "full block as", ("groups",)).labels(
                         str(_query_groups(q_rows))).inc()
+        n_walk = _band(window, t_k, q_rows).get("n_walk", 0)
+        reg.counter("flash_grid_pairs_traced_total",
+                    "Calls of flash_attention traced into a program, by "
+                    "the (held, walked) block pairs a head's grid walks "
+                    "and the steps a grid over the whole square or band "
+                    "would take", ("live", "square")).labels(
+                        str(len(_walk(causal, t_q, t_k, q_rows, k_rows,
+                                      True, n_walk))),
+                        str(t_q // q_rows * (n_walk or t_k // k_rows))).inc()
 
     def run(q, k, v):
         # keys in parts: k is kv, v the shared part

@@ -172,6 +172,58 @@ def test_flash_forward_compiles_in_query_groups_at_ouro_width(one_chip):
     assert _custom_calls(c) == 1
 
 
+def _grid_pairs():
+    """``{(pairs a head walks, steps of the square): calls traced}``."""
+    c = default_registry().get("flash_grid_pairs_traced_total")
+    return {} if c is None else {labels: child.value
+                                 for labels, child in c.samples()}
+
+
+def _walk_tables(compiled) -> dict:
+    """``{kernel: [its calls' scalar-prefetched operand shapes]}``: the
+    int32 columns of the walk's table each flash kernel call reads."""
+    found = {}
+    for line in compiled.as_text().split("\n"):
+        name = re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call"', line)
+        if ('custom_call_target="tpu_custom_call"' in line and name
+                and name.group(1) in F.FULL_KERNEL_NAMES):
+            found.setdefault(name.group(1), []).append(re.findall(
+                r"s32\[\d+\]", re.search(
+                    r"operand_layout_constraints=\{(.*?\})\}",
+                    line).group(1)))
+    return found
+
+
+@pytest.mark.parametrize("keys", ["whole", "parts"])
+def test_flash_kernels_walk_only_live_pairs_at_ouro_and_joyai_width(
+        one_chip, keys):
+    """Ouro's call, [16, 8192, 128], and JoyAI's, [32, 8192, 128 + 64 |
+    128] with the keys in parts, causal in blocks of 1024 rows: each of
+    the three kernels walks a table of the 36 live pairs of a head's 8 x 8
+    blocks, not the 64 steps of the square, and compiles with it."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    if keys == "whole":
+        args = (spec(1, 16, 8192, 128),) * 3
+
+        def attend(q, k, v):
+            return F.flash_attention(q, k, v, causal=True)
+    else:
+        args = spec(1, 32, 8192, 192), spec(1, 32, 8192, 256), \
+            spec(1, 1, 8192, 64)
+
+        def attend(q, kv, ks):
+            return F.flash_attention(q, kv=kv, k_shared=ks, causal=True)
+    before = _grid_pairs()
+    c = jax.jit(jax.grad(lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
+                         argnums=(0, 1, 2))).lower(*args).compile()
+    moved = {k: n - before.get(k, 0) for k, n in _grid_pairs().items()
+             if n != before.get(k, 0)}
+    assert moved == {("36", "64"): 1}
+    assert _walk_tables(c) == {name: [["s32[36]"] * 4]
+                               for name in F.FULL_KERNEL_NAMES}
+
+
 def test_flash_float32_operands_compile_for_v5e(one_chip):
     """Serving runs on the f32 masters: the oracle forward of the serve
     phase hands the kernel float32 q/k/v."""
@@ -511,7 +563,8 @@ def test_flash_kernels_compile_at_192_wide_keys_and_128_wide_values(one_chip):
 def _kernel_operands(compiled) -> dict:
     """``{kernel: [(operand's instruction, its shape), ...]}`` of the full
     flash kernels' custom calls in a compiled program, the shapes as the
-    call constrains them."""
+    call constrains them; the walk's table, the int32 columns a launch
+    scalar-prefetches ahead of its operands, left out."""
     found = {}
     for line in compiled.as_text().split("\n"):
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -525,8 +578,10 @@ def _kernel_operands(compiled) -> dict:
             r" custom-call\(([^)]*)\)", line).group(1))
         shapes = re.findall(r"(\w+\[[\d,]*\])\{", re.search(
             r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
-        found.setdefault(name, []).append(list(zip(
-            (o.strip().lstrip("%") for o in operands.split(",")), shapes)))
+        found.setdefault(name, []).append([
+            (o, shape) for o, shape in zip(
+                (o.strip().lstrip("%") for o in operands.split(",")), shapes)
+            if not re.fullmatch(r"s32\[\d+\]", shape)])
     return found
 
 
