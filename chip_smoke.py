@@ -335,7 +335,8 @@ def phase_conv(devices):
     check_losses(losses, "conv fit")
     it0 = model.iteration
     t0 = time.perf_counter()
-    # the device-resident dataset in the compute dtype, as bench.py holds it
+    # the device-resident dataset in the compute dtype, as the benchmark's
+    # on-device traffic holds it
     model.fit_on_device(jnp.asarray(x, jnp.bfloat16), jnp.asarray(y),
                         batch_size=batch, epochs=1)
     loss = float(model.get_score())
@@ -415,8 +416,8 @@ def preamble(devices) -> None:
 
     import jax
     import jaxlib
+    from benchmark.common import peaks_for
     from deeplearning4j_tpu import persistent_cache_status
-    from deeplearning4j_tpu.observability.profiler import peak_device_flops
     from deeplearning4j_tpu.utils import native
     try:
         libtpu = md.version("libtpu")
@@ -426,12 +427,9 @@ def preamble(devices) -> None:
         f"libtpu {libtpu}")
     say(f"device: platform {devices[0].platform} kind "
         f"'{devices[0].device_kind}' count {len(devices)}")
-    peak = peak_device_flops()
-    say(f"profiler peaks table: {peak and peak / 1e12} bf16 TFLOP/s for "
-        "these devices")
-    if devices[0].platform == "tpu" and peak is None:
-        raise AssertionError(f"device kind '{devices[0].device_kind}' "
-                             "matches no row of the profiler's peaks table")
+    if devices[0].platform == "tpu":
+        # the benchmark's one peaks table; an unknown kind raises here
+        say(f"peaks: {peaks_for(devices[0].device_kind)}")
     say(f"compile cache at start: {persistent_cache_status()}")
     t0 = time.time()
     live, path = native.available(), native.library_path()

@@ -81,13 +81,6 @@ class FaultInjector:
         self._delay: Dict[Tuple[int, int], float] = {}
         self._drop: Dict[Tuple[int, int], int] = {}
         self.events: List[Tuple[str, int, int]] = []   # (kind, worker, rnd)
-        # recovery-time observability (bench.py recovery_time_ms): per
-        # faulted worker, the first fault-free on_batch afterwards marks
-        # the first post-recovery step — either the worker's own retry
-        # attempt, or (elastic degradation, rnd == -1) a survivor
-        # replaying the lost worker's chunk
-        self.last_fault_s: Dict[int, float] = {}
-        self.recoveries_s: List[float] = []
 
     # ------------------------------------------------------------- plans
     def fail(self, worker: int, rnd: int, times: int = 1) -> "FaultInjector":
@@ -113,7 +106,6 @@ class FaultInjector:
         """Master-side hook before each batch of a worker's round chunk.
         First-batch position carries the planned fault/delay."""
         if batch_index != 0:
-            self._mark_recovered(worker, rnd)
             return
         key = (worker, rnd)
         delay = self._delay.get(key)
@@ -128,28 +120,11 @@ class FaultInjector:
                 self._fail[key] = n - 1
             self.events.append(("fail", worker, rnd))
             _record_fault("injected_fail", worker=worker, round=rnd)
-            self.last_fault_s[worker] = monotonic_s()
             raise InjectedWorkerFault(worker, rnd, "failure")
         if self.fail_rate and self._rng.random() < self.fail_rate:
             self.events.append(("fail", worker, rnd))
             _record_fault("injected_fail", worker=worker, round=rnd)
-            self.last_fault_s[worker] = monotonic_s()
             raise InjectedWorkerFault(worker, rnd, "random failure")
-        self._mark_recovered(worker, rnd)
-
-    def _mark_recovered(self, worker: int, rnd: int) -> None:
-        """A fault-free batch hook after an injected failure = the first
-        post-recovery step; the gap is what bench.py's recovery_time_ms
-        reports.  The faulted worker's own clean attempt resolves its
-        fault (sync retry path); a replay batch (``rnd == -1``) run by a
-        survivor resolves the oldest pending fault (elastic path — the
-        lost worker never runs again)."""
-        t = self.last_fault_s.pop(worker, None)
-        if t is None and rnd == -1 and self.last_fault_s:
-            oldest = min(self.last_fault_s, key=self.last_fault_s.get)
-            t = self.last_fault_s.pop(oldest)
-        if t is not None:
-            self.recoveries_s.append(monotonic_s() - t)
 
     def should_drop(self, worker: int, rnd: int) -> bool:
         """Master-side hook after a worker finishes its round chunk."""

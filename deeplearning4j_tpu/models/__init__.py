@@ -18,12 +18,10 @@ __all__ = [
 
 def available_bench_model(batch: int = 32, image: int = 224,
                           compute_dtype: str = "bfloat16"):
-    """Flagship bench model: ResNet50-ImageNet (the BASELINE.md north-star
-    metric is ResNet50 examples/sec/chip).  bf16 compute is the TPU-native
-    default (f32 master params); DL4J_TPU_BENCH_DTYPE=float32 disables.
+    """ResNet50 at ImageNet widths with one random batch, for bring-up
+    and HLO dumps.  bf16 compute is the TPU-native default (f32 master
+    params); ``compute_dtype="float32"`` computes in f32.
     Returns (model, (x, y))."""
-    import os
-    compute_dtype = os.environ.get("DL4J_TPU_BENCH_DTYPE", compute_dtype)
     model = ResNet50(num_classes=1000,
                      compute_dtype=None if compute_dtype == "float32"
                      else compute_dtype,

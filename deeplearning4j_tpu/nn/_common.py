@@ -801,7 +801,7 @@ def fit_batches(model, batches_factory, epochs: int, prepare, step, *,
         publish_exit_mass(model)
     if obs and steady_s > 0:
         # steady-state throughput: the compile-dominated first step
-        # is excluded (same convention as utils/benchmarks.py)
+        # is excluded
         reg.gauge("training_examples_per_sec",
                   "Training examples/sec over the last fit() "
                   "(compile excluded where the path can tell)"

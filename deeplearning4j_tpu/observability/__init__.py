@@ -24,8 +24,8 @@ OpProfiler, UI stats storage — SURVEY §5):
 - :mod:`events` — structured JSONL event log for offline analysis;
 - :mod:`listener` — ``MetricsListener`` publishing score/throughput/
   grad-norm/device-memory from the ``TrainingListener`` hook points;
-- :mod:`clock` — the monotonic/wall helpers everything above (and the
-  benchmarks) source timings from, and ``process_age_s()``;
+- :mod:`clock` — the monotonic/wall helpers everything above sources
+  timings from, and ``process_age_s()``;
 - :mod:`startup` — ``startup_report()``: where the host's seconds went
   between the start of the process and the first steady step (the
   process's age at the package's import, the import, the containers'
@@ -41,14 +41,13 @@ OpProfiler, UI stats storage — SURVEY §5):
   checksummed JSON artifacts on crashes, preemptions, evictions, and
   SLO breaches (``/debug/flightrecorder`` on both HTTP servers);
 - :mod:`health` — streaming anomaly detection (NaN loss/grads, EWMA
-  spike, throughput regression, MFU regression, padding drift, serving
+  spike, throughput regression, padding drift, serving
   p99/shed-rate) that flips ``/health`` to ``degraded``, can trigger an
   immediate checkpoint save, and (opt-in) stops training;
 - :mod:`profiler` — the step profiler: per-step phase attribution
   (etl/h2d/dispatch/device/listener/forensics/checkpoint) with a
-  SAMPLED device fence, dispatch-depth gauge, card-derived MFU,
-  live-bytes watermarks vs the AX008 budgets, and Chrome-trace export
-  (``/debug/profile`` on both HTTP servers).
+  SAMPLED device fence, dispatch-depth and live-bytes gauges, and
+  Chrome-trace export (``/debug/profile`` on both HTTP servers).
 
 Cost model: METRICS are on by default (the registry is plain host
 arithmetic — serving ``/metrics`` and the training counters work out of

@@ -5,7 +5,7 @@ Writers are thread-safe (one lock around the write; lines stay atomic)
 and the module-level sink is a no-op until :func:`configure_event_log`
 points it somewhere — the same off-by-default posture as the registry
 and tracer.  Consumers are anything that reads JSONL: pandas, jq, or
-``tools/trace_categorize.py``-style scripts.
+:meth:`EventLog.read`.
 
 **Rotation**: long runs emit events forever, so an unbounded JSONL file
 is a disk-filler.  With ``max_bytes`` set, a write that pushes the

@@ -80,11 +80,6 @@ class HealthConfig:
     # throughput regression: steady EWMA below ratio * peak EWMA
     throughput_floor_ratio: float = 0.5
     throughput_warmup: int = 20
-    # MFU regression: sampled-fence MFU EWMA (StepProfiler) below ratio *
-    # peak EWMA; fences arrive 1-in-sample_every steps, so the warmup is
-    # counted in SAMPLES, not steps
-    mfu_floor_ratio: float = 0.5
-    mfu_warmup: int = 8
     # padding drift: |ewma - baseline| above this absolute ratio delta
     padding_drift: float = 0.25
     # serving detectors
@@ -181,8 +176,6 @@ class HealthMonitor:
         self._gnorm = _Ewma(self.config.ewma_alpha)
         self._eps = _Ewma(self.config.ewma_alpha)      # examples/sec
         self._eps_peak = 0.0
-        self._mfu = _Ewma(self.config.ewma_alpha)      # sampled-fence MFU
-        self._mfu_peak = 0.0
         self._pad = _Ewma(self.config.ewma_alpha)
         self._pad_baseline: Optional[float] = None
         self._steps = 0
@@ -341,41 +334,6 @@ class HealthMonitor:
                     f"padding ratio EWMA {ew.mean:.3f} drifted from "
                     f"its warmed baseline {self._pad_baseline:.3f}",
                     value=ew.mean, threshold=cfg.padding_drift, step=step)
-                if d is not None:
-                    out.append(d)
-        return out
-
-    def observe_mfu(self, mfu: Optional[float],
-                    program: Optional[str] = None,
-                    step: Optional[int] = None) -> List[Detection]:
-        """Feed one sampled-fence MFU reading (the StepProfiler's
-        roofline sample).  Same shape as the throughput detector: the
-        EWMA tracks its own peak, and a collapse below
-        ``mfu_floor_ratio`` x peak fires ``mfu_regression`` — the "same
-        step rate, emptier device" signal a pure examples/sec detector
-        cannot see (e.g. a padding blowup keeps steps/s flat while
-        useful FLOPs crater)."""
-        cfg = self.config
-        out: List[Detection] = []
-        if mfu is None:
-            return out
-        mfu = float(mfu)
-        if not math.isfinite(mfu) or mfu <= 0:
-            return out
-        ew = self._mfu
-        ew.update(mfu)
-        if ew.n >= cfg.mfu_warmup:
-            if ew.mean > self._mfu_peak:
-                self._mfu_peak = ew.mean
-            floor = cfg.mfu_floor_ratio * self._mfu_peak
-            if self._mfu_peak > 0 and ew.mean < floor:
-                prog = f" [{program}]" if program else ""
-                d = self._detect(
-                    "mfu_regression",
-                    f"sampled MFU EWMA{prog} {ew.mean:.4f} fell below "
-                    f"{cfg.mfu_floor_ratio:.0%} of peak "
-                    f"{self._mfu_peak:.4f}",
-                    value=ew.mean, threshold=floor, step=step)
                 if d is not None:
                     out.append(d)
         return out

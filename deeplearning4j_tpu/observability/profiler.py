@@ -1,5 +1,5 @@
-"""StepProfiler: per-step time attribution with MFU, dispatch-depth,
-and memory-watermark telemetry.
+"""StepProfiler: per-step time attribution with dispatch-depth and
+live-bytes telemetry.
 
 ``/metrics`` says *how fast* a step was; nothing in the stack said
 *where the time went* — host dispatch vs device compute vs ETL wait vs
@@ -11,10 +11,8 @@ training step's wall time into named phases::
 
 and exports the result through every existing observability surface: a
 bounded FlightRecorder ``profile`` channel (Chrome-trace dumpable,
-served live at ``GET /debug/profile``), registry gauges
-(``training_mfu{program}``, ``training_dispatch_depth``,
-``device_live_bytes``), and the HealthMonitor's MFU-regression
-detector.
+served live at ``GET /debug/profile``) and registry gauges
+(``training_dispatch_depth``, ``device_live_bytes``).
 
 Honesty model — the one thing this module must not lie about:
 
@@ -30,17 +28,12 @@ Honesty model — the one thing this module must not lie about:
   materializations the caller reports via :meth:`materialized`): it
   makes pipelining visible — depth pinned at 0 means some hidden sync
   is serializing every step.
-- **MFU** derives from the committed graftaudit card ``flops`` field
-  (``tools/graftaudit/cards/``) — cards are the single source of truth
-  for program FLOPs; no analytic formulas are duplicated here.  The
-  peak-FLOP/s denominator comes from ``DL4J_TPU_PEAK_FLOPS`` or a
-  per-chip table for known TPU kinds; with neither, achieved FLOP/s is
-  still exported and the MFU gauge is withheld rather than faked.
-- **Memory watermarks** sum live device bytes (``jax.live_arrays``) at
-  fences and compare the observed peak against the AX008
-  ``peak_live_bytes`` budget from ``tools/graftaudit/budgets.json``
-  (``device_live_bytes_budget_ratio{program}``) — an approaching OOM
-  pages before it happens.
+- **Live bytes** (``jax.live_arrays``) are summed at each fence into the
+  step record and the ``device_live_bytes`` gauge.
+
+The profiler computes no FLOP count and no MFU: a step's model FLOP/s
+utilization is the benchmark's ``step_mfu_pct.train``, read from a
+device trace against ``benchmark/peaks.json``.
 
 Enablement: ``DL4J_TPU_STEPPROF`` (default on; the per-step cost is a
 handful of ``perf_counter`` reads plus one buffered tuple append: on the
@@ -50,9 +43,7 @@ through ``fit``, each one opens a device gap of 14.8-16.6 ms (3.0-4.5 ms
 under the fence itself, the live-array walk after the device has run
 dry, and 8.6-11.2 ms under the next step's dispatch into the empty
 pipeline; ``PERF.md`` section 5), 12 of them in 200 steps.
-``DL4J_TPU_STEPPROF_SAMPLE`` sets the fence cadence (default 16);
-``DL4J_TPU_STEPPROF_PROGRAM`` overrides the program label the fit
-loops pass, mapping a run onto its canonical card/budget entry.
+``DL4J_TPU_STEPPROF_SAMPLE`` sets the fence cadence (default 16).
 
 This module is the ONE place a fence inside a loop is legal — the
 graftlint JX029 rule flags ``block_until_ready`` in loops everywhere
@@ -64,7 +55,6 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import re
 from typing import Any, Dict, List, Optional
 
 from .clock import monotonic_s, wall_s
@@ -73,8 +63,7 @@ from .registry import MetricsRegistry, default_registry
 from .tracer import get_tracer
 
 __all__ = ["StepProfiler", "step_profiler_for", "stepprof_enabled",
-           "record_slices", "resolve_card_flops", "resolve_budget_bytes",
-           "peak_device_flops", "live_device_bytes", "phase_summary",
+           "record_slices", "live_device_bytes", "phase_summary",
            "chrome_trace", "dump_chrome_trace", "load_chrome_trace",
            "CHANNEL", "PHASES", "TRACE_FORMAT", "TRACE_PREFIX"]
 
@@ -86,24 +75,6 @@ TRACE_PREFIX = "stepprof-"
 
 #: serve/decode slice keys in their temporal order (Chrome-trace layout)
 SLICE_KEYS = ("queue_wait_s", "batch_form_s", "execute_s")
-
-# repo root when running from a checkout: profiler.py lives at
-# <root>/deeplearning4j_tpu/observability/profiler.py
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-# bf16 peak FLOP/s per chip for known TPU generations (the roofline
-# denominator when DL4J_TPU_PEAK_FLOPS is not set); prefix-matched
-# against device_kind, most specific first
-_PEAK_FLOPS_BY_KIND = (
-    ("TPU v6", 918e12),
-    ("TPU v5p", 459e12),
-    ("TPU v5 lite", 197e12),
-    ("TPU v5e", 197e12),
-    ("TPU v4", 275e12),
-    ("TPU v3", 123e12),
-    ("TPU v2", 45e12),
-)
 
 
 def stepprof_enabled() -> bool:
@@ -118,69 +89,9 @@ def _default_sample_every() -> int:
         return 16
 
 
-# ---------------------------------------------------------------- cards
-def _card_path(program: str) -> str:
-    directory = os.environ.get("DL4J_TPU_CARDS_DIR") or os.path.join(
-        _REPO_ROOT, "tools", "graftaudit", "cards")
-    # mirrors tools/graftaudit/cards.card_filename (not imported: the
-    # audit toolchain must stay optional at runtime)
-    fname = re.sub(r"[^A-Za-z0-9_.-]+", "_", program) + ".json"
-    return os.path.join(directory, fname)
-
-
-def resolve_card_flops(program: str) -> Optional[float]:
-    """FLOPs of one execution of ``program`` from its committed
-    graftaudit card — the single source of truth for program cost; None
-    when no card exists (installed package, un-audited program)."""
-    try:
-        with open(_card_path(program), "r", encoding="utf-8") as fh:
-            flops = json.load(fh).get("flops")
-        flops = float(flops)
-        return flops if flops > 0 else None
-    except (OSError, ValueError, TypeError):
-        return None
-
-
-def resolve_budget_bytes(program: str) -> Optional[int]:
-    """The AX008 ``peak_live_bytes`` ceiling for ``program`` from
-    ``tools/graftaudit/budgets.json`` (or ``DL4J_TPU_BUDGETS``)."""
-    path = os.environ.get("DL4J_TPU_BUDGETS") or os.path.join(
-        _REPO_ROOT, "tools", "graftaudit", "budgets.json")
-    try:
-        with open(path, "r", encoding="utf-8") as fh:
-            row = json.load(fh)["programs"][program]
-        b = int(row["peak_live_bytes"])
-        return b if b > 0 else None
-    except (OSError, ValueError, KeyError, TypeError):
-        return None
-
-
-def peak_device_flops() -> Optional[float]:
-    """Aggregate peak FLOP/s across local devices: ``DL4J_TPU_PEAK_FLOPS``
-    (already aggregate) wins; else the per-chip table for known TPU
-    kinds x device count; else None — MFU is withheld, never faked."""
-    env = os.environ.get("DL4J_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            peak = float(env)
-            return peak if peak > 0 else None
-        except ValueError:
-            return None
-    try:
-        import jax
-        devices = jax.devices()
-    except Exception:
-        return None
-    kind = str(getattr(devices[0], "device_kind", "") or "")
-    for prefix, peak in _PEAK_FLOPS_BY_KIND:
-        if kind.startswith(prefix):
-            return peak * len(devices)
-    return None
-
-
 def live_device_bytes() -> Optional[int]:
-    """Sum of live device-array bytes (the observed-watermark sample
-    taken at fences); None when the runtime can't say."""
+    """Sum of live device-array bytes (the sample taken at fences);
+    None when the runtime can't say."""
     try:
         import jax
         arrays = jax.live_arrays()
@@ -207,7 +118,7 @@ class StepProfiler:
         prof.mark("listener", dt)
         prof.dispatched(loss)          # async dispatch returned; maybe
                                        #   fence (sampled): device slice,
-                                       #   live bytes, MFU
+                                       #   live bytes
         prof.lap("forensics")          # bookkeeping laps
         prof.lap("checkpoint")
         prof.end(iteration, compile_step)
@@ -220,17 +131,15 @@ class StepProfiler:
     FLUSH_EVERY = 16
     __slots__ = ("program", "enabled", "sample_every", "ring", "fences",
                  "steps", "dispatch_depth", "max_depth",
-                 "live_bytes_watermark", "card_flops", "budget_bytes",
-                 "peak_flops", "last_mfu", "last_achieved_flops",
-                 "_registry", "_monitor", "_wall0", "_buf", "_t0", "_last",
+                 "_registry", "_wall0", "_buf", "_t0", "_last",
                  "_etl", "_h2d", "_listener", "_dispatch", "_device",
                  "_forensics", "_checkpoint", "_sampled", "_drained_wait",
-                 "_live", "_ratio", "_mfu", "_ach")
+                 "_live")
 
     def __init__(self, program: str = "train_step", *,
                  sample_every: Optional[int] = None,
                  registry: Optional[MetricsRegistry] = None,
-                 recorder=None, monitor=None):
+                 recorder=None):
         self.program = program
         self.enabled = True
         self.sample_every = max(1, int(sample_every)) \
@@ -239,18 +148,10 @@ class StepProfiler:
         self.ring = rec.channel(CHANNEL) \
             if (rec is not None and rec.enabled) else None
         self._registry = registry
-        self._monitor = monitor
-        # cold, once per fit: committed card/budget lookups + roofline
-        self.card_flops = resolve_card_flops(program)
-        self.budget_bytes = resolve_budget_bytes(program)
-        self.peak_flops = peak_device_flops() if self.card_flops else None
         self.fences = 0
         self.steps = 0
         self.dispatch_depth = 0
         self.max_depth = 0
-        self.live_bytes_watermark = 0
-        self.last_mfu: Optional[float] = None
-        self.last_achieved_flops: Optional[float] = None
         # record timestamps derive from the monotonic reads the loop
         # already takes (the _StepForensics wall0 trick)
         self._wall0 = wall_s() - monotonic_s()
@@ -262,9 +163,6 @@ class StepProfiler:
         self._sampled = False
         self._drained_wait = 0.0
         self._live: Optional[int] = None
-        self._ratio: Optional[float] = None
-        self._mfu: Optional[float] = None
-        self._ach: Optional[float] = None
 
     def _reg(self) -> MetricsRegistry:
         return self._registry if self._registry is not None \
@@ -345,7 +243,7 @@ class StepProfiler:
             self._etl, self._h2d, self._dispatch, self._device,
             self._listener, self._forensics, self._checkpoint,
             self._sampled, compile_step, self.dispatch_depth,
-            self._live, self._ratio, self._mfu, self._ach])
+            self._live])
         if len(self._buf) >= self.FLUSH_EVERY:
             self.flush()
 
@@ -381,8 +279,7 @@ class StepProfiler:
             self._drained_wait = t_prev - t_disp
         jax.block_until_ready(handle)
         now = monotonic_s()
-        device = now - t_prev
-        self._device = device
+        self._device = now - t_prev
         self._last = now
         self._sampled = True
         self.fences += 1
@@ -399,27 +296,6 @@ class StepProfiler:
         # configured depth.
         live = live_device_bytes()
         self._live = live
-        if live is not None and live > self.live_bytes_watermark:
-            self.live_bytes_watermark = live
-        ratio = None
-        if self.budget_bytes and self.live_bytes_watermark:
-            ratio = self.live_bytes_watermark / self.budget_bytes
-        self._ratio = ratio
-        achieved = mfu = None
-        if self.card_flops and device > 0:
-            achieved = self.card_flops / device
-            self.last_achieved_flops = achieved
-            if self.peak_flops:
-                mfu = achieved / self.peak_flops
-                self.last_mfu = mfu
-        self._ach, self._mfu = achieved, mfu
-        if mfu is not None:
-            mon = self._monitor
-            if mon is None:
-                from .health import get_health_monitor
-                mon = get_health_monitor()
-            if mon is not None:
-                mon.observe_mfu(mfu, program=self.program, step=self.steps)
         reg = self._reg()
         if reg.enabled:
             p = self.program
@@ -431,24 +307,10 @@ class StepProfiler:
                       "points (max over the last sample window)"
                       ).set(self.max_depth)
             self.max_depth = 0
-            if achieved is not None:
-                reg.gauge("training_achieved_flops",
-                          "Achieved FLOP/s of the sampled device slice "
-                          "(card flops / fenced device time)",
-                          ("program",)).labels(p).set(achieved)
-            if mfu is not None:
-                reg.gauge("training_mfu",
-                          "Model FLOP/s utilization: achieved over peak "
-                          "device FLOP/s", ("program",)).labels(p).set(mfu)
             if live is not None:
                 reg.gauge("device_live_bytes",
                           "Live device bytes sampled at the last profiler "
                           "fence").set(live)
-            if ratio is not None:
-                reg.gauge("device_live_bytes_budget_ratio",
-                          "Observed live-bytes watermark over the AX008 "
-                          "peak_live_bytes budget",
-                          ("program",)).labels(p).set(ratio)
 
     # ------------------------------------------------------- flush (cold)
     def flush(self) -> None:
@@ -462,7 +324,7 @@ class StepProfiler:
             return
         prog = self.program
         for (ts, it, wall, etl, h2d, disp, dev, lst, fore, ckpt,
-             sampled, comp, depth, live, ratio, mfu, ach) in buf:
+             sampled, comp, depth, live) in buf:
             rec = {"ts": ts, "type": "step", "program": prog,
                    "iteration": it, "wall_s": round(wall, 7),
                    "sampled": sampled, "compile": comp, "depth": depth,
@@ -481,23 +343,15 @@ class StepProfiler:
                        "checkpoint": round(ckpt, 7)}}
             if live is not None:
                 rec["live_bytes"] = live
-            if ratio is not None:
-                rec["budget_ratio"] = round(ratio, 4)
-            if mfu is not None:
-                rec["mfu"] = mfu
-            if ach is not None:
-                rec["achieved_flops"] = ach
             ring.append(rec)
 
 
 def step_profiler_for(program: str, **kwargs) -> Optional[StepProfiler]:
     """The fit loop's entry point: a fresh profiler, or None when
     ``DL4J_TPU_STEPPROF=0`` — and never an exception, because telemetry
-    must not break training.  ``DL4J_TPU_STEPPROF_PROGRAM`` overrides
-    the label (mapping a run onto its canonical card/budget entry)."""
+    must not break training."""
     if not stepprof_enabled():
         return None
-    program = os.environ.get("DL4J_TPU_STEPPROF_PROGRAM", program)
     try:
         return StepProfiler(program, **kwargs)
     except Exception:
@@ -547,13 +401,6 @@ def phase_summary(records: List[Dict[str, Any]]) -> Dict[str, Any]:
                / r["wall_s"] for r in sampled if r.get("wall_s")]
         if cov:
             out["sampled_coverage"] = sum(cov) / len(cov)
-        mfus = [r["mfu"] for r in sampled if r.get("mfu") is not None]
-        if mfus:
-            out["mean_mfu"] = sum(mfus) / len(mfus)
-        ratios = [r["budget_ratio"] for r in sampled
-                  if r.get("budget_ratio") is not None]
-        if ratios:
-            out["max_budget_ratio"] = max(ratios)
     return out
 
 
@@ -579,9 +426,8 @@ def chrome_trace(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             args = {"iteration": r.get("iteration"),
                     "depth": r.get("depth"),
                     "sampled": bool(r.get("sampled"))}
-            for opt in ("mfu", "live_bytes", "budget_ratio"):
-                if r.get(opt) is not None:
-                    args[opt] = r[opt]
+            if r.get("live_bytes") is not None:
+                args["live_bytes"] = r["live_bytes"]
             cursor = ts
             ph = r.get("phases") or {}
             for name in ("etl_wait", "h2d", "dispatch"):

@@ -8,8 +8,10 @@ inference layers.  The gate test makes every future PR re-lint the whole
 package without separate CI wiring, and the wall-time budget test keeps
 the full run inside the developer loop.
 """
+import ast
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -2860,6 +2862,64 @@ def test_no_bare_except_in_package():
     """ISSUE 1 acceptance: zero bare `except:` clauses in the package."""
     found = [f for f in lint_paths([str(PKG)], select=["JX007"])]
     assert found == [], "\n".join(f.format() for f in found)
+
+
+def _docstrings(tree):
+    """The string constants that are docstrings (prose, not code)."""
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef)) and node.body:
+            first = node.body[0]
+            if isinstance(first, ast.Expr) and \
+                    isinstance(first.value, ast.Constant) and \
+                    isinstance(first.value.value, str):
+                found.add(id(first.value))
+    return found
+
+
+def _into_tools(tree, kind):
+    """Where a module's code reaches into the repo's ``tools/``: module
+    names it imports (``import``, ``from``, ``import_module("...")``),
+    or string constants outside docstrings that hold a ``tools`` path
+    segment (``os.path.join(root, "tools", ...)``, ``"tools/x.json"``)."""
+    hits = []
+    if kind == "imports":
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            elif isinstance(node, ast.Call) and node.args and \
+                    isinstance(node.args[0], ast.Constant) and \
+                    isinstance(node.args[0].value, str) and \
+                    getattr(node.func, "attr", getattr(node.func, "id", "")) \
+                    in ("import_module", "__import__"):
+                names = [node.args[0].value]
+            else:
+                continue
+            hits += [(node.lineno, n) for n in names
+                     if n == "tools" or n.startswith("tools.")]
+    else:
+        prose = _docstrings(tree)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Constant) and \
+                    isinstance(node.value, str) and id(node) not in prose \
+                    and "tools" in re.split(r"[\\/]", node.value):
+                hits.append((node.lineno, node.value))
+    return hits
+
+
+@pytest.mark.parametrize("kind", ["imports", "paths"])
+def test_the_package_reaches_nothing_under_tools(kind):
+    """The arrow points one way: ``tools/`` (lint, audit, CLIs) may import
+    the package, and the package runs without a checkout of ``tools/``."""
+    found = []
+    for path in sorted(PKG.rglob("*.py")):
+        tree = ast.parse(path.read_text(encoding="utf-8"))
+        found += [f"{path.relative_to(REPO_ROOT)}:{line}: {what!r}"
+                  for line, what in _into_tools(tree, kind)]
+    assert found == [], "\n".join(found)
 
 
 # ----------------------------------------------- review-hardening fixes

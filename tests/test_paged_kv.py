@@ -300,6 +300,40 @@ class TestPagedEngine:
         finally:
             eng.shutdown()
 
+    def test_four_times_the_slots_in_two_full_slots_of_blocks(self, lm):
+        """The paged cache's capacity claim: a pool holding no more blocks
+        than two full-length slots (the trash block included) serves
+        eight short sequences AT ONCE, each inside one block, on the
+        warmed program set."""
+        dense_slots, block = 2, 4
+        n_blocks = dense_slots * (32 // block)
+        full = GenerationEngine.for_model(
+            lm, GenerationConfig(max_slots=dense_slots, max_seq=32,
+                                 block_size=block))
+        eng = GenerationEngine.for_model(
+            lm, GenerationConfig(max_slots=4 * dense_slots, max_seq=32,
+                                 block_size=block, n_blocks=n_blocks,
+                                 queue_limit=64))
+        try:
+            full.warmup()
+            eng.warmup()
+            assert eng.ring.cache_bytes < full.ring.cache_bytes
+            rng = np.random.default_rng(7)
+            # ticks serialize on the step lock: holding it across the
+            # submits admits the whole fleet in one batch
+            with eng._step_lock:
+                handles = [eng.submit(
+                               rng.integers(0, VOCAB, block // 2).tolist(),
+                               max_new_tokens=block - block // 2)
+                           for _ in range(4 * dense_slots)]
+            results = [h.future.result(timeout=120) for h in handles]
+            assert all(r.finish == "length" for r in results)
+            assert eng.ring.peak_active == 4 * dense_slots
+            assert eng.steady_recompiles == 0
+        finally:
+            eng.shutdown()
+            full.shutdown()
+
     def test_retired_env_escape_hatch_is_ignored(self, lm, monkeypatch):
         """The ``DL4J_TPU_KV_PAGED=0`` hatch went with the dense ring:
         the env var does nothing and every engine builds the paged

@@ -1,7 +1,6 @@
-"""StepProfiler (ISSUE 17): per-step phase attribution with sampled
-device fences, MFU from committed graftaudit cards, memory watermarks vs
-the AX008 budgets, and the Chrome-trace / ``/debug/profile`` export
-surfaces.
+"""StepProfiler: per-step phase attribution with sampled device
+fences, live device bytes at each fence, and the Chrome-trace /
+``/debug/profile`` export surfaces.
 
 The honesty contracts under test:
 
@@ -9,8 +8,8 @@ The honesty contracts under test:
 * UNSAMPLED steps add ZERO host syncs — the PR 16 host-sync sweep
   invariant, asserted by counting ``jax.block_until_ready`` calls and
   pinning the compile counters;
-* MFU derives from the committed ``train_step[dense]`` card flops, not
-  an analytic formula;
+* the profiler reads no file and computes no FLOP count: the label a fit
+  loop passes is the label, and the one MFU is the benchmark's;
 * the trace artifact is checksummed — corruption raises, never loads
   quietly.
 """
@@ -32,8 +31,6 @@ from deeplearning4j_tpu.nn.conf.multi_layer import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.updaters import Adam
 from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-from deeplearning4j_tpu.observability.health import (HealthConfig,
-                                                     HealthMonitor)
 from deeplearning4j_tpu.observability.profiler import (CHANNEL, PHASES,
                                                        StepProfiler,
                                                        chrome_trace,
@@ -41,14 +38,11 @@ from deeplearning4j_tpu.observability.profiler import (CHANNEL, PHASES,
                                                        load_chrome_trace,
                                                        phase_summary,
                                                        record_slices,
-                                                       resolve_card_flops,
                                                        step_profiler_for,
                                                        stepprof_enabled)
 from deeplearning4j_tpu.observability.recorder import (FlightRecorder,
                                                        set_flight_recorder)
 from deeplearning4j_tpu.observability.registry import default_registry
-
-CARD_FLOPS = 43446.0          # committed tools/graftaudit/cards value
 
 
 def tiny_net(seed=42):
@@ -147,69 +141,74 @@ class TestPhaseAttribution:
                                                    monkeypatch):
         import jax
         monkeypatch.setenv("DL4J_TPU_STEPPROF_SAMPLE", "3")
-        monkeypatch.setenv("DL4J_TPU_STEPPROF_PROGRAM", "cadence_probe")
         net = tiny_net()
         net.fit(iter(make_batches(2)), epochs=1)      # compile + warm
+        reg = default_registry()
+        fam = reg.get("stepprof_fences_total")
+        before = fam.labels("train_step").value if fam is not None else 0.0
         fences = []
         real = jax.block_until_ready
         monkeypatch.setattr(jax, "block_until_ready",
                             lambda x: fences.append(1) or real(x))
         net.fit(iter(make_batches(9)), epochs=1)
         assert len(fences) == 3                       # steps 3, 6, 9 only
-        reg = default_registry()
         fam = reg.get("stepprof_fences_total")
         assert fam is not None
-        assert fam.labels("cadence_probe").value == 3.0
+        # the fit loop's own label: the counter the benchmark's
+        # profiler_fences.train reads
+        assert fam.labels("train_step").value - before == 3.0
         depth = reg.get("training_dispatch_depth")
         # async dispatch pipelines at least the fenced window's steps
         assert depth is not None and depth.value >= 1
 
-    def test_mfu_from_committed_card_flops(self, recorder, monkeypatch):
-        assert resolve_card_flops("train_step[dense]") == CARD_FLOPS
-        monkeypatch.setenv("DL4J_TPU_STEPPROF_PROGRAM", "train_step[dense]")
+    def test_the_fit_loops_label_is_the_label(self, recorder, monkeypatch):
+        # the label fit passes reaches every record and the trace's
+        # process name; nothing maps it onto another program
         monkeypatch.setenv("DL4J_TPU_STEPPROF_SAMPLE", "2")
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
         net = tiny_net()
-        net.fit(iter(make_batches(8)), epochs=1)
-        sampled = [r for r in step_records(recorder) if r["sampled"]]
-        assert sampled
-        for r in sampled:
-            # achieved = card flops / fenced device slice; MFU = achieved
-            # over the configured peak — no analytic formula anywhere
-            # (rel tolerance: the record's device slice is rounded to
-            # 7 decimals, the flops ratio used the raw value)
-            assert r["achieved_flops"] == pytest.approx(
-                CARD_FLOPS / r["phases"]["device"], rel=0.02)
-            assert r["mfu"] == pytest.approx(r["achieved_flops"] / 1e12)
-        reg = default_registry()
-        fam = reg.get("training_mfu")
-        assert fam is not None
-        assert fam.labels("train_step[dense]").value == pytest.approx(
-            sampled[-1]["mfu"])
+        net.fit(iter(make_batches(4)), epochs=1)
+        recs = step_records(recorder)
+        assert len(recs) == 4
+        assert {r["program"] for r in recs} == {"train_step"}
+        procs = {e["args"]["name"] for e in chrome_trace(recs)["traceEvents"]
+                 if e["ph"] == "M"}
+        assert procs == {"train [train_step]"}
+        assert step_profiler_for("serve_probe").program == "serve_probe"
 
-    def test_watermark_vs_budget_ratio(self, recorder, tmp_path,
-                                       monkeypatch):
-        budget = 4096
-        budgets = {"programs": {"wm_probe": {"peak_live_bytes": budget}}}
-        bpath = tmp_path / "budgets.json"
-        bpath.write_text(json.dumps(budgets))
-        monkeypatch.setenv("DL4J_TPU_BUDGETS", str(bpath))
-        monkeypatch.setenv("DL4J_TPU_STEPPROF_PROGRAM", "wm_probe")
+    def test_a_fence_samples_live_bytes_and_nothing_else(self, recorder,
+                                                         monkeypatch):
         monkeypatch.setenv("DL4J_TPU_STEPPROF_SAMPLE", "2")
         net = tiny_net()
         net.fit(iter(make_batches(6)), epochs=1)
-        sampled = [r for r in step_records(recorder) if r["sampled"]]
-        assert sampled
-        for r in sampled:
-            assert r["live_bytes"] > 0
-            # ratio is the observed WATERMARK (max so far) over budget
-            # (1e-3 slack: the recorded ratio rounds to 4 decimals)
-            assert r["budget_ratio"] >= r["live_bytes"] / budget - 1e-3
+        recs = step_records(recorder)
+        sampled = [r for r in recs if r["sampled"]]
+        assert len(sampled) == 3
+        # a fence sums the live device bytes into its record; no record
+        # carries a FLOP, MFU or budget figure
+        assert all(r["live_bytes"] > 0 for r in sampled)
+        for r in recs:
+            assert not {"mfu", "achieved_flops", "budget_ratio"} & set(r)
         reg = default_registry()
-        fam = reg.get("device_live_bytes_budget_ratio")
-        assert fam is not None
-        assert fam.labels("wm_probe").value >= \
-            max(r["live_bytes"] for r in sampled) / budget - 1e-6
+        assert reg.get("device_live_bytes").value == \
+            sampled[-1]["live_bytes"]
+        for gone in ("training_mfu", "training_achieved_flops",
+                     "device_live_bytes_budget_ratio"):
+            assert reg.get(gone) is None
+        summary = phase_summary(recs)
+        assert "mean_mfu" not in summary
+        assert "max_budget_ratio" not in summary
+
+    def test_a_profiler_opens_no_file(self, monkeypatch):
+        import builtins
+        rec = FlightRecorder()
+        opened = []
+        real = builtins.open
+        monkeypatch.setattr(builtins, "open",
+                            lambda *a, **k: opened.append(a[0])
+                            or real(*a, **k))
+        prof = StepProfiler("train_step", recorder=rec)
+        assert opened == []
+        assert prof.program == "train_step"
 
     def test_disabled_kills_every_hook(self, recorder, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_STEPPROF", "0")
@@ -235,7 +234,7 @@ class TestChromeTrace:
         return [
             {"ts": 10.0, "type": "step", "program": "train_step",
              "iteration": 1, "wall_s": 0.01, "sampled": True,
-             "compile": False, "depth": 2, "mfu": 0.41,
+             "compile": False, "depth": 2, "live_bytes": 4096,
              "phases": {"etl_wait": 0.001, "h2d": 0.002,
                         "dispatch": 0.003, "device": 0.002,
                         "listener": 0.001, "forensics": 0.001,
@@ -261,7 +260,7 @@ class TestChromeTrace:
         dev = [e for e in doc["traceEvents"] if e["name"] == "device"][0]
         assert dev["tid"] != [e for e in doc["traceEvents"]
                               if e["name"] == "dispatch"][0]["tid"]
-        assert dev["args"]["mfu"] == 0.41
+        assert dev["args"]["live_bytes"] == 4096
 
     def test_dump_load_roundtrip_and_corruption_detected(self, tmp_path):
         path = dump_chrome_trace(directory=str(tmp_path),
@@ -371,51 +370,6 @@ class TestDebugProfileEndpoint:
             srv.stop()
 
 
-class TestMfuRegressionDetector:
-    def test_observe_mfu_fires_below_floor_of_peak(self):
-        mon = HealthMonitor(HealthConfig(mfu_warmup=3, mfu_floor_ratio=0.5,
-                                         ewma_alpha=0.6))
-        dets = []
-        for step in range(8):
-            dets += mon.observe_mfu(0.40, program="p", step=step)
-        assert dets == []                       # steady at peak: silent
-        for step in range(8, 20):
-            dets += mon.observe_mfu(0.05, program="p", step=step)
-        kinds = {d.kind for d in dets}
-        assert kinds == {"mfu_regression"}
-        assert any("[p]" in d.reason for d in dets)
-
-    def test_observe_mfu_ignores_garbage(self):
-        mon = HealthMonitor(HealthConfig())
-        assert mon.observe_mfu(None) == []
-        assert mon.observe_mfu(float("nan")) == []
-        assert mon.observe_mfu(-1.0) == []
-
-    def test_fit_feeds_detector_through_fence(self, recorder, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_STEPPROF_PROGRAM", "train_step[dense]")
-        monkeypatch.setenv("DL4J_TPU_STEPPROF_SAMPLE", "2")
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-        seen = []
-        mon = HealthMonitor(HealthConfig(mfu_warmup=1))
-        real = mon.observe_mfu
-        mon.observe_mfu = lambda *a, **k: seen.append(a) or real(*a, **k)
-        prof = step_profiler_for("train_step", monitor=mon)
-        assert prof is not None
-        net = tiny_net()
-        net._stepprof = None
-        # drive the profiler through the real protocol with the injected
-        # monitor (fit() builds its own profiler, which would use the
-        # process-global monitor)
-        from deeplearning4j_tpu.observability.clock import monotonic_s
-        import jax.numpy as jnp
-        for i, (x, y) in enumerate(make_batches(4)):
-            prof.begin(monotonic_s())
-            prof.dispatched(jnp.asarray(x).sum())
-            prof.end(i)
-        assert len(seen) == 2                   # one per fence
-        assert all(v[0] > 0 for v in seen)
-
-
 class TestStepprofCli:
     def test_cli_emits_table_and_checksummed_trace(self, tmp_path,
                                                    monkeypatch, capsys):
@@ -427,7 +381,7 @@ class TestStepprofCli:
         assert "phase" in out and "dispatch" in out
         assert "sampled coverage" in out
         tail = json.loads(out.strip().splitlines()[-1])
-        assert tail["program"] == "train_step[dense]"
+        assert tail["program"] == "train_step"
         assert tail["steps"] == 7               # compile step excluded
         doc = load_chrome_trace(tail["trace"])  # checksum-verified
         assert doc["otherData"]["records"] == 8

@@ -7,6 +7,8 @@ step's text and compare the jaxpr with the scopes taken out.
 """
 import contextlib
 import gc
+import json
+import os
 import re
 
 import jax
@@ -20,6 +22,7 @@ from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.conf.multi_layer import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.updaters import Adam
 from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer, OutputLayer
+from deeplearning4j_tpu.nn.layers.recurrent import LSTM, RnnOutputLayer
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.observability.registry import default_registry
 from deeplearning4j_tpu.parallel import (ParallelWrapper, ShardedTrainer,
@@ -337,6 +340,106 @@ def test_a_stack_and_a_graph_of_the_same_layers_train_alike():
         for leaf, a in stack.params[layer].items():
             np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(graph.params[vertex][leaf]))
+
+
+# ------------------------- what the one loop walks: tBPTT, checkpoints
+CHUNKS = 3          # a tBPTT batch of 12 steps walked in chunks of 4
+
+
+def tbptt_net(seed=7, tbptt=True):
+    lb = (NeuralNetConfiguration.builder().seed(seed)
+          .updater(Adam(learning_rate=0.02)).list()
+          .layer(LSTM(n_out=4))
+          .layer(RnnOutputLayer(n_out=2, activation="softmax",
+                                loss="mcxent")))
+    if tbptt:
+        lb.backprop_type("tbptt", fwd=4, back=4)
+    conf = lb.set_input_type(InputType.recurrent(3, 4 * CHUNKS)).build()
+    return MultiLayerNetwork(conf).init()
+
+
+def sequences(n, batch=8, seed=0):
+    rng = np.random.default_rng(seed)
+    return [(rng.standard_normal((batch, 4 * CHUNKS, 3), dtype=np.float32),
+             np.eye(2, dtype=np.float32)[
+                 rng.integers(0, 2, (batch, 4 * CHUNKS))])
+            for _ in range(n)]
+
+
+TBPTT_FITS = {
+    "stack": tbptt_net,
+    "wrapper": lambda: ParallelWrapper(tbptt_net(), make_mesh(dp=2)),
+    "sharded": lambda: ShardedTrainer(tbptt_net(), make_mesh(dp=2),
+                                      min_shard_size=0),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(TBPTT_FITS))
+def test_a_tbptt_stack_through_each_loop(kind, recorded):
+    """The stack hands the loop its tBPTT walk: a batch longer than a
+    chunk trains chunk by chunk, one optimizer step a chunk.  The
+    wrappers hand it no walk, so there the same stack trains each batch
+    as one whole-sequence step, as a stack without tBPTT would.  Either
+    way the loop counts one step and one batch of examples a batch, and
+    writes one ``dl4j.input_wait`` a batch under ``dl4j.fit``."""
+    from deeplearning4j_tpu.observability.registry import (
+        MetricsRegistry, set_default_registry)
+    feed = sequences(3)
+    previous = set_default_registry(MetricsRegistry())
+    try:
+        net = TBPTT_FITS[kind]()
+        net.fit(feed)
+        reg = default_registry()
+        assert reg.get("training_steps_total").value == 3
+        assert reg.get("training_examples_total").value == 24
+    finally:
+        set_default_registry(previous)
+    assert recorded.names().count("dl4j.input_wait") == 4   # + the end
+    assert recorded.parents("dl4j.input_wait") == {"dl4j.fit"}
+    model = getattr(net, "model", net)
+    if kind == "stack":
+        assert model.iteration == 3 * CHUNKS
+        return
+    assert model.iteration == 3
+    whole = tbptt_net(tbptt=False)
+    whole.fit(feed)
+    np.testing.assert_allclose(model.params_flat(), whole.params_flat(),
+                               rtol=1e-5, atol=1e-6)
+
+
+CKPT_NETS = {"stack": (stack_net, lambda: batches(7), 3),
+             "graph": (graph_net, lambda: batches(7), 3),
+             # every CHUNKS-th iteration ends a batch: saved after two
+             "tbptt": (tbptt_net, lambda: sequences(5), 2 * CHUNKS)}
+
+
+@pytest.mark.parametrize("kind", sorted(CKPT_NETS))
+def test_a_checkpoint_boundary_resumes_at_its_cursor(kind, tmp_path):
+    """A save at a checkpoint boundary drains the dispatch window first,
+    so it holds finished steps and a cursor in batches: a run resumed
+    from it skips what was consumed and ends on the uninterrupted run's
+    parameters bit for bit, for the stack, the graph and a tBPTT walk."""
+    from deeplearning4j_tpu.faulttolerance import CheckpointConfig
+    build, feed_of, every = CKPT_NETS[kind]
+    feed = feed_of()
+    whole = build()
+    whole.fit(feed)
+    saved = build()
+    cfg = CheckpointConfig(directory=str(tmp_path),
+                           save_every_n_iterations=every, keep_last=10,
+                           background=False)
+    saved.fit(feed, checkpoint=cfg)
+    step, path, _ = cfg.resolve().checkpoints()[0]
+    assert step == every
+    with open(os.path.join(path, "training_state.json")) as f:
+        cursor = json.load(f)["cursor"]
+    assert cursor == {"fit_epoch": 0,
+                      "batch_seq": 2 if kind == "tbptt" else every}
+    resumed = build()
+    resumed.fit(feed, resume_from=path)
+    assert resumed.iteration == whole.iteration
+    for a, b in zip(leaves(whole), leaves(resumed)):
+        np.testing.assert_array_equal(a, b)
 
 
 # ------------------------------------------------- nothing else changes

@@ -1,4 +1,4 @@
-"""Dump the bench model's optimized train-step HLO + cost summary.
+"""Dump the ResNet50-224 optimized train-step HLO + cost summary.
 
 Run from the repo root: ``python -m tools.dump_hlo``.  Writes the HLO
 text to /tmp/hlo_opt.txt and prints the backend cost rows.

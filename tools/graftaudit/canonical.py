@@ -139,8 +139,8 @@ def _pick_largest_prefill(entry):
 class CanonicalSet:
     """The built canonical set, with its coverage made EXPLICIT: a
     wanted program this host could not build lands in ``skipped`` with
-    the reason — consumers (CLI card pruning, the ``audit_time_ms``
-    bench row) must never mistake reduced coverage for the full set."""
+    the reason — consumers (CLI card pruning, the tests) must never
+    mistake reduced coverage for the full set."""
     programs: List[AuditProgram]
     suppressions: List[Suppression]
     skipped: Dict[str, str] = field(default_factory=dict)

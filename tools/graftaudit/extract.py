@@ -2,9 +2,8 @@
 
 Every consumer of "the HLO of what the process actually compiled" goes
 through this module: the audit's HLO phase (collective census / flops /
-temp bytes in ``audit.analyze_program``), ``tools/dump_hlo.py`` (the
-bench train-step dump), and ``tools/trace_top_ops.py`` (profile-trace
-fusion attribution).  All of them used to re-spell the same pair —
+temp bytes in ``audit.analyze_program``) and ``tools/dump_hlo.py`` (the
+ResNet50-224 train-step dump).  They used to re-spell the same pair —
 ``iter_trace_cache()`` to find the entry, ``entry.audit_lower(spec)``
 to re-lower the recorded call — or worse, hand-rolled a ``.lower()``
 with a fresh RNG key that compiled a program subtly different from the

@@ -11,9 +11,7 @@ then emits:
    the same artifact ``GET /debug/profile?dump=1`` serves from a live
    trainer; and
 2. a text phase table — mean seconds + share of step wall per phase over
-   steady steps, with the sampled-fence coverage check, MFU (card flops
-   over the fenced device slice), and the live-bytes watermark vs the
-   AX008 budget.
+   steady steps, with the sampled-fence coverage check.
 
 Replaces the round-2 ``profile_capture.py`` Xprof-glob script: Xprof
 answers "which op is slow on the device"; this answers the prior
@@ -24,7 +22,6 @@ Options::
   --steps N     minibatches per epoch          (default 48)
   --epochs E    epochs                         (default 2)
   --sample N    fence cadence (1 = every step) (default 8)
-  --program P   program label for card/budget  (default train_step[dense])
   --out DIR     artifact directory             (default .)
 """
 from __future__ import annotations
@@ -60,12 +57,6 @@ def phase_table(summary: dict) -> str:
     if cov is not None:
         lines.append(f"sampled coverage {cov:.1%} of wall attributed "
                      f"({summary.get('sampled_steps', 0)} fenced steps)")
-    if summary.get("mean_mfu") is not None:
-        lines.append(f"MFU {summary['mean_mfu']:.2%} (card flops / fenced "
-                     "device slice / peak)")
-    if summary.get("max_budget_ratio") is not None:
-        lines.append(f"live-bytes watermark {summary['max_budget_ratio']:.1%} "
-                     "of AX008 peak_live_bytes budget")
     return "\n".join(lines)
 
 
@@ -76,7 +67,6 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=48)
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--sample", type=int, default=8)
-    ap.add_argument("--program", default="train_step[dense]")
     ap.add_argument("--out", default=".")
     args = ap.parse_args(argv)
 
@@ -84,7 +74,6 @@ def main(argv=None) -> int:
     # a production fit runs (fit() -> step_profiler_for -> env knobs)
     os.environ["DL4J_TPU_STEPPROF"] = "1"
     os.environ["DL4J_TPU_STEPPROF_SAMPLE"] = str(max(1, args.sample))
-    os.environ["DL4J_TPU_STEPPROF_PROGRAM"] = args.program
 
     from deeplearning4j_tpu.observability.profiler import (CHANNEL,
                                                            chrome_trace,
@@ -115,7 +104,7 @@ def main(argv=None) -> int:
     print(phase_table(summary))
     print(f"\ntrace: {path} ({len(doc['traceEvents'])} events — load in "
           "chrome://tracing or ui.perfetto.dev)")
-    print(json.dumps({"program": args.program,
+    print(json.dumps({"program": records[-1].get("program"),
                       "steps": summary.get("steps"),
                       "sampled_steps": summary.get("sampled_steps"),
                       "mean_wall_ms": round(
